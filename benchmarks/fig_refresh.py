@@ -405,12 +405,26 @@ def mega_ladder_spec(n_scen: int, reqs: int = 32) -> SweepSpec:
                      reqs=reqs, seed=0, mode="closed")
 
 
+def _refuse_on_tpu(bench: str) -> None:
+    """The megakernel benches time the kernel's interpreter and a CPU
+    child process; in a process that holds a TPU they would report CPU
+    times inside a chip run, so they refuse to start there."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{bench} times the megakernel in interpret mode and in a CPU "
+            "child process; it does not run in a process that holds a "
+            "TPU (the device path there is sweep(..., backend='jax'))")
+
+
 def _shard_probe(n_scen: int = 24) -> dict:
     """1/2/4-way `shard_map` over the cell-tile axis at one ladder rung,
     each way warmed then timed, 2- and 4-way outputs compared
     bit-for-bit against 1-way. Runs in a fresh subprocess spawned by
     `sweep_mega` because XLA_FLAGS=--xla_force_host_platform_device_count
     must be set before jax initialises."""
+    _refuse_on_tpu("_shard_probe")
     import jax
 
     from repro.core.sweep.engine import _Grid
@@ -450,6 +464,7 @@ def sweep_mega(fast: bool = False) -> dict:
     virtual host devices) and the regression guard: the warmed fused
     path must beat the batched backend on the 8x8x3 open reference
     grid."""
+    _refuse_on_tpu("sweep_mega")
     from repro.core.sweep.engine import _Grid
     from repro.kernels.sweep_megakernel import run_mega
 

@@ -66,6 +66,9 @@ def main() -> None:
 
     from benchmarks import fig_refresh as FR
     from benchmarks import bench_framework as BF
+    from repro.common.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     t0 = time.perf_counter()
     runs = FR.fig_grids(reqs=reqs)     # one sweep set feeds fig1 AND fig3

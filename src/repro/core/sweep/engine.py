@@ -119,6 +119,7 @@ Backends:
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from itertools import product
@@ -1927,9 +1928,12 @@ def _check_jax_guards(grid: _Grid, backend: str = "jax") -> None:
             "use backend='batched'")
 
 
+@functools.lru_cache(maxsize=None)
 def _jax_arbiter(arbiter: str):
     """The arbitration callable for the traced tick body: the jnp scoring
-    definitions, or the Pallas arbiter kernel (interpret mode off-TPU)."""
+    definitions, or the Pallas arbiter kernel (interpret mode off-TPU).
+    Cached, so one arbiter name is one static argument of
+    `jaxbody.run_loop` and its compiled loop is reused."""
     import jax
     import jax.numpy as jnp
 
@@ -1948,75 +1952,36 @@ def _jax_arbiter(arbiter: str):
 
 
 def _run_jax(grid: _Grid, arbiter: str = "jnp") -> list[CellResult]:
-    """The whole tick loop as one jitted `lax.while_loop`: state lives in
-    jnp int32 arrays, policies run through the same xp-generic
-    `select_batch`, and the arbitration step optionally routes through the
-    Pallas kernel. The traced tick body itself lives in `sweep.jaxbody`
-    and is shared verbatim with the fused Pallas megakernel. Integer
-    arithmetic keeps this bit-identical to the numpy backend and the
-    scalar oracle; custom (non-vectorizable) policy registrations are not
-    traceable and must use `backend="batched"`."""
+    """The whole tick loop as one jitted `lax.while_loop`
+    (`jaxbody.run_loop`, the body shared verbatim with the fused Pallas
+    megakernel), in either mode: state lives in jnp int32 arrays,
+    policies run through the same xp-generic `select_batch`, and the
+    arbitration step optionally routes through the Pallas kernel.
+    Closed grids add per-core MLP-window state and core-fed ring bank
+    queues. Integer arithmetic keeps this bit-identical to the numpy
+    backend and the scalar oracle; custom (non-vectorizable) policy
+    registrations are not traceable and must use `backend="batched"`."""
     _check_jax_guards(grid)
     import jax
-    from jax import lax
 
     from repro.core.sweep import jaxbody
 
-    scores = _jax_arbiter(arbiter)
-    cfg = jaxbody.open_cfg(grid)
-    cst = jaxbody.open_consts(grid)
-    st = jaxbody.open_state0(cfg, cst)
-
-    def run(c, s0):
-        return lax.while_loop(
-            lambda s: jaxbody.open_cond(c, s),
-            lambda s: jaxbody.open_body(cfg, c, scores, s), s0)
-
-    out = jax.device_get(jax.jit(run)(cst, st))
-    finished = out["n_served"].sum(axis=1) >= grid.n_tot
-    return [_finalize(grid, g, reads=out["reads"][g],
-                      writes=out["writes"][g], hits=out["hits"][g],
-                      misses=out["misses"][g], refpb=out["refpb"][g],
-                      refab=out["refab"][g], lat_sum=out["lat_sum"][g],
-                      hist=out["hist"][g], maxlag=out["maxlag"][g],
-                      last_done=out["last_done"][g], finished=finished[g])
-            for g in range(grid.G)]
-
-
-# ------------------------------------------------- jax fast path (closed)
-def _run_jax_closed(grid: _Grid, arbiter: str = "jnp") -> list[CellResult]:
-    """Closed-loop mode as one jitted `lax.while_loop`: the open-loop jax
-    backend plus per-core MLP-window state and core-fed ring bank queues
-    (the traced body in `sweep.jaxbody`, shared verbatim with the fused
-    Pallas megakernel). Same all-integer contract, bit-identical to numpy
-    and the scalar closed oracle."""
-    _check_jax_guards(grid)
-    import jax
-    from jax import lax
-
-    from repro.core.sweep import jaxbody
-
-    scores = _jax_arbiter(arbiter)
-    cfg = jaxbody.closed_cfg(grid)
-    cst = jaxbody.closed_consts(grid)
-    st = jaxbody.closed_state0(cfg, cst)
-
-    def run(c, s0):
-        return lax.while_loop(
-            lambda s: jaxbody.closed_cond(c, s),
-            lambda s: jaxbody.closed_body(cfg, c, scores, s), s0)
-
-    out = jax.device_get(jax.jit(run)(cst, st))
-    finished = (out["remaining"] <= 0).all(axis=1)
-    t_end = int(out["t"])
-    fin = np.where(out["finish"] < 0, t_end, out["finish"])
+    cfg, cst, st = jaxbody.program(grid)
+    out = jax.device_get(
+        jaxbody.run_loop(cfg, cst, _jax_arbiter(arbiter), st))
+    if grid.closed:
+        finished = (out["remaining"] <= 0).all(axis=1)
+        fin = np.where(out["finish"] < 0, int(out["t"]), out["finish"])
+    else:
+        finished = out["n_served"].sum(axis=1) >= grid.n_tot
+        fin = None
     return [_finalize(grid, g, reads=out["reads"][g],
                       writes=out["writes"][g], hits=out["hits"][g],
                       misses=out["misses"][g], refpb=out["refpb"][g],
                       refab=out["refab"][g], lat_sum=out["lat_sum"][g],
                       hist=out["hist"][g], maxlag=out["maxlag"][g],
                       last_done=out["last_done"][g], finished=finished[g],
-                      core_finish=fin[g])
+                      core_finish=None if fin is None else fin[g])
             for g in range(grid.G)]
 
 
@@ -2055,13 +2020,15 @@ def sweep(spec: SweepSpec, backend: str = "batched",
     backend="batched" : stacked-numpy lock-step (default; supports custom
                         policy registrations via per-cell fallback),
     backend="jax"     : the whole tick loop jitted (`lax.while_loop`),
-                        built-in policy classes only,
+                        built-in policy classes only; the device path
+                        on a TPU,
     backend="mega"    : the fused Pallas tick-loop megakernel
                         (`repro.kernels.sweep_megakernel`) — the same
                         traced body as "jax" run to completion inside a
-                        cell-tiled kernel, fastest; `n_shards` > 1
-                        additionally shards the cell-tile axis across
-                        devices with `shard_map`,
+                        cell-tiled kernel; interpret mode only, so on a
+                        TPU it raises `MegakernelRefused` before
+                        lowering; `n_shards` > 1 additionally shards the
+                        cell-tile axis across devices with `shard_map`,
     backend="scalar"  : plain-Python per-cell reference oracle.
 
     `arbiter` selects the availability/arbitration step implementation:
@@ -2119,8 +2086,7 @@ def sweep(spec: SweepSpec, backend: str = "batched",
         else:
             cells = _run_batched(grid, arbiter=arbiter or "numpy")
     elif backend == "jax":
-        run = _run_jax_closed if closed else _run_jax
-        cells = run(grid, arbiter=arbiter or "jnp")
+        cells = _run_jax(grid, arbiter=arbiter or "jnp")
     elif backend == "scalar":
         run_cell = _run_scalar_cell_closed if closed else _run_scalar_cell
         cells = [run_cell(grid, g) for g in range(grid.G)]

@@ -12,10 +12,11 @@ functions of three ingredients:
                    compiled loop serves many grids of the same shape),
   * ``s``        — the per-tick state dict.
 
-`engine` drives them through a host `jax.lax.while_loop`; the megakernel
-drives the identical functions inside a cell-tiled `pallas_call`. Both
-paths therefore stay bit-identical to the batched numpy backend and the
-scalar oracle by construction — there is exactly one traced tick body.
+`loop` runs either body to completion as one `lax.while_loop`. The engine's
+jax backend calls it jitted (`run_loop`, on the inputs `program` builds);
+the megakernel calls it inside a cell-tiled `pallas_call`. Both paths
+therefore stay bit-identical to the batched numpy backend and the scalar
+oracle by construction — there is exactly one traced tick body.
 
 Everything is int32/bool (tick-contract section 3). The ``*_state0``
 functions build the canonical initial state and each ``*_body`` returns a
@@ -27,6 +28,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -706,3 +708,29 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         hist=hist, maxlag=maxlag,
         last_done=last_done,
     )
+
+
+# -------------------------------------------------------------- the loop
+def loop(cfg: TickCfg, cst: dict, scores, s0: dict) -> dict:
+    """Run the tick body of `cfg`'s mode from `s0` until its condition
+    fails; returns the final state."""
+    cond, body = ((closed_cond, closed_body) if cfg.closed
+                  else (open_cond, open_body))
+    return lax.while_loop(lambda s: cond(cst, s),
+                          lambda s: body(cfg, cst, scores, s), s0)
+
+
+#: the jax backend's program: `loop` jitted, with the config and the
+#: arbitration callable static (pass the same `scores` object to reuse
+#: the compiled loop)
+run_loop = jax.jit(loop, static_argnums=(0, 2))
+
+
+def program(grid) -> tuple:
+    """The inputs of `run_loop` for one `engine._Grid`: ``(cfg, cst,
+    s0)``."""
+    if grid.closed:
+        cfg, cst = closed_cfg(grid), closed_consts(grid)
+        return cfg, cst, closed_state0(cfg, cst)
+    cfg, cst = open_cfg(grid), open_consts(grid)
+    return cfg, cst, open_state0(cfg, cst)

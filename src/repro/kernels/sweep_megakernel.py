@@ -32,8 +32,11 @@ Layout (see `docs/tick-contract.md`, "fused kernel"):
     splits each chunk's tile axis across devices with `shard_map`
     (logical axis ``cells`` in `repro/parallel/sharding.py`).
 
-Off-TPU the kernel runs in interpret mode (same traced graph, plain XLA
-ops), keeping CI and the conformance tier green on CPU.
+Mosaic, the TPU's Pallas compiler, refuses this kernel (see
+`MegakernelRefused`), so it runs only in interpret mode (same traced
+graph, plain XLA ops), which keeps CI and the conformance tier green on
+CPU. On a TPU the sweep's device path is `sweep(..., backend="jax")`,
+the same tick body under a plain XLA `lax.while_loop`.
 """
 from __future__ import annotations
 
@@ -42,10 +45,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.sweep import jaxbody
@@ -72,6 +73,23 @@ TILE = 64
 #: tiles per `pallas_call` per shard: bounds the dispatched program and
 #: result-buffer size so giga-grids stream through one compiled call
 CHUNK_TILES = 32
+
+#: why the compiled kernel is refused (checked ahead of time against a
+#: described v5e chip by tests/test_tpu_aot.py, which fails the day
+#: Mosaic accepts the kernel)
+REFUSAL = (
+    "Mosaic cannot lower the sweep megakernel for a TPU: the per-scenario "
+    "request-count block (1, C) is not (8, 128)-aligned, and the shared "
+    "tick body (repro.core.sweep.jaxbody) gathers with 2-D advanced "
+    "indexing and take_along_axis (first at `sw[flat_gc, sl]` in "
+    "closed_body and `qa[flat_gb, sl]` in open_body), which Pallas's "
+    "Mosaic gather lowering rejects. Use sweep(..., backend='jax') on a "
+    "TPU: the same tick body under an XLA lax.while_loop.")
+
+
+class MegakernelRefused(NotImplementedError):
+    """The megakernel was asked for on a TPU, where Mosaic does not
+    lower it (`REFUSAL`)."""
 
 
 # ------------------------------------------------------------ host layout
@@ -205,10 +223,8 @@ def _mega_closed_kernel(scn_ref, params_ref, sw_ref, sb_ref, sr_ref,
                sr=stream(sr_ref), ssub=stream(ssub_ref),
                sth=stream(sth_ref), n_req=n_req, mlp=p[:, MP_MLP],
                **_param_consts(p, cfg))
-    out = lax.while_loop(
-        lambda s: jaxbody.closed_cond(cst, s),
-        lambda s: jaxbody.closed_body(cfg, cst, _scores_jnp, s),
-        jaxbody.closed_state0(cfg, cst))
+    out = jaxbody.loop(cfg, cst, _scores_jnp,
+                       jaxbody.closed_state0(cfg, cst))
     stats_ref[...] = _pack_stats(out, (out["remaining"] <= 0).all(axis=1))
     cf_ref[...] = jnp.where(out["finish"] < 0, out["t"], out["finish"])
 
@@ -230,10 +246,7 @@ def _mega_open_kernel(scn_ref, params_ref, qa_ref, qr_ref, qs_ref,
     cst = dict(qa=stream(qa_ref), qr=stream(qr_ref), qs=stream(qs_ref),
                qw=stream(qw_ref) != 0, n_pb=n_pb,
                n_tot=n_pb.sum(axis=1), **_param_consts(p, cfg))
-    out = lax.while_loop(
-        lambda s: jaxbody.open_cond(cst, s),
-        lambda s: jaxbody.open_body(cfg, cst, _scores_jnp, s),
-        jaxbody.open_state0(cfg, cst))
+    out = jaxbody.loop(cfg, cst, _scores_jnp, jaxbody.open_state0(cfg, cst))
     stats_ref[...] = _pack_stats(
         out, out["n_served"].sum(axis=1) >= cst["n_tot"])
 
@@ -284,8 +297,7 @@ _closed_call_jit = _jit(_closed_call)
 _open_call_jit = _jit(_open_call)
 
 
-def run_mega(grid, *, interpret=None, n_shards=1, tile=None,
-             chunk_tiles=CHUNK_TILES):
+def run_mega(grid, *, n_shards=1, tile=None, chunk_tiles=CHUNK_TILES):
     """Run every cell of `grid` (an `engine._Grid` built with
     ``stack_streams=False``) through the fused tick-loop kernel.
 
@@ -293,11 +305,15 @@ def run_mega(grid, *, interpret=None, n_shards=1, tile=None,
     ``reads writes hits misses refpb refab lat_sum maxlag last_done p99
     finished``, plus ``core_finish`` [G, C] for closed grids) — exactly
     the inputs `engine._finalize` needs, so the engine never touches
-    MS_* columns."""
+    MS_* columns.
+
+    The kernel runs in interpret mode; on a TPU, where that would hide
+    the device behind the interpreter and Mosaic refuses the compiled
+    kernel, this raises `MegakernelRefused` before lowering."""
+    if jax.default_backend() == "tpu":
+        raise MegakernelRefused(REFUSAL)
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     closed = grid.closed
     cfg = jaxbody.closed_cfg(grid) if closed else jaxbody.open_cfg(grid)
     params = _pack_params(grid)
@@ -340,16 +356,16 @@ def run_mega(grid, *, interpret=None, n_shards=1, tile=None,
             tiles_p = logical_to_spec(("cells",))
             row_p = logical_to_spec(("cells", None))
         rep = [P(*([None] * a.ndim)) for a in streams]
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             functools.partial(raw, cfg=cfg, n_tiles=chunk, tile=tile,
-                              interpret=interpret),
+                              interpret=True),
             mesh=mesh, in_specs=(tiles_p, row_p, *rep),
             out_specs=(row_p, row_p) if closed else (row_p,),
-            check_rep=False))
+            check_vma=False))
     else:
         fn = functools.partial(
             _closed_call_jit if closed else _open_call_jit,
-            cfg=cfg, n_tiles=per_call, tile=tile, interpret=interpret)
+            cfg=cfg, n_tiles=per_call, tile=tile, interpret=True)
 
     n_chunks = -(-int(tile_scn.shape[0]) // per_call)
     stat_parts, cf_parts = [], []

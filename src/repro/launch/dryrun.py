@@ -115,8 +115,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis() or {}
-            if isinstance(cost, (list, tuple)):  # older jax: list per program
-                cost = cost[0] if cost else {}
             txt = compiled.as_text()
             hlo = analyze_hlo(txt)
         mf = model_flops(cfg, shape)

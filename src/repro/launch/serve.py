@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.common.compile_cache import use_compile_cache
 from repro.common.config import get_arch
 from repro.core.policy import list_policies
 from repro.kvcache import PagedKVConfig
@@ -25,13 +26,31 @@ from repro.models.dims import make_dims
 from repro.serving import EngineConfig, EngineCore
 
 
-def _prompts(n: int, mixed: bool, vocab: int):
+def prompts(n: int, mixed: bool, vocab: int):
     """Deterministic prompt set; --mixed varies lengths (3..32 tokens) the
     way a real arrival mix would."""
     lens = [3 + (11 * i) % 30 for i in range(n)] if mixed else [3] * n
     return [[1 + i] + [(5 * j + i) % max(2, vocab - 1) + 1
                        for j in range(l - 1)]
             for i, l in enumerate(lens)]
+
+
+def build_engine(cfg, *, policy: str = "darp", seed: int = 0,
+                 page_size: int = 4, chunk: int = 8, max_queue: int = 64,
+                 decode_fn=None) -> EngineCore:
+    """An `EngineCore` serving `cfg` with f32 weights initialised from
+    `seed` (no checkpoint is read). `decode_fn` replaces the decode
+    forward, with the same signature."""
+    dims = make_dims(cfg, tp=1, param_dtype=jnp.float32,
+                     compute_dtype=jnp.float32)
+    params = get_model(cfg).init(jax.random.PRNGKey(seed), cfg, dims)
+    kv_cfg = PagedKVConfig(
+        n_layers=cfg.n_layers, n_kv_heads=dims.n_kv,
+        head_dim=cfg.attention.head_dim, page_size=page_size,
+        n_pages=256, n_staging=24, n_groups=4, max_seqs=8)
+    return EngineCore(params, cfg, dims, kv_cfg, EngineConfig(
+        max_batch=4, policy=policy, max_queue=max_queue,
+        prefill_chunk=chunk), decode_fn=decode_fn)
 
 
 def main():
@@ -51,22 +70,15 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    dims = make_dims(cfg, tp=1, param_dtype=jnp.float32,
-                     compute_dtype=jnp.float32)
-    mod = get_model(cfg)
-    params = mod.init(jax.random.PRNGKey(args.seed), cfg, dims)
-    kv_cfg = PagedKVConfig(
-        n_layers=cfg.n_layers, n_kv_heads=dims.n_kv,
-        head_dim=cfg.attention.head_dim, page_size=args.page_size,
-        n_pages=256, n_staging=24, n_groups=4, max_seqs=8)
-    eng = EngineCore(params, cfg, dims, kv_cfg, EngineConfig(
-        max_batch=4, policy=args.policy, max_queue=args.max_queue,
-        prefill_chunk=args.chunk))
+    eng = build_engine(cfg, policy=args.policy, seed=args.seed,
+                       page_size=args.page_size, chunk=args.chunk,
+                       max_queue=args.max_queue)
     handles = [eng.submit(p, args.new, rid=i)
-               for i, p in enumerate(_prompts(args.requests, args.mixed,
+               for i, p in enumerate(prompts(args.requests, args.mixed,
                                               cfg.vocab_size))]
     t0 = time.perf_counter()
     eng.run_until_done(max_rounds=args.max_rounds)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointConfig
+from repro.common.compile_cache import use_compile_cache
 from repro.common.config import get_arch
 from repro.core.policy import list_policies
 from repro.data import Prefetcher, SyntheticLMData
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -1,7 +1,9 @@
 import os
 import sys
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+# src for the package; the checkout root for chip_smoke and benchmarks
+sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 
 # fixture corpora for the static-analysis suite mirror the repo layout
 # (including tests/test_*.py files with planted violations) — they are

@@ -34,6 +34,7 @@ from repro.optim import OptConfig
 from repro.train import make_state, make_train_step
 from repro.launch import specs as SP
 from repro.parallel import LOGICAL_RULES_SINGLE_POD, sharding_context, logical_to_spec
+from repro.launch.mesh import make_host_mesh
 from repro.data import SyntheticLMData
 
 cfg = get_arch('qwen2.5-3b').reduced()
@@ -47,7 +48,7 @@ state1 = make_state(jax.random.PRNGKey(0), cfg, dims1, ocfg)
 s1, m1 = jax.jit(make_train_step(cfg, dims1, ocfg))(state1, batch)
 
 # sharded on (data=2, model=4)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_host_mesh(model=4)
 with sharding_context(mesh, LOGICAL_RULES_SINGLE_POD, set()):
     dims4 = make_dims(cfg, tp=4, param_dtype=jnp.float32, compute_dtype=jnp.float32)
     state4 = make_state(jax.random.PRNGKey(0), cfg, dims4, ocfg)
@@ -102,8 +103,9 @@ from repro.train import make_state
 from repro.checkpoint import CheckpointConfig, CheckpointEngine
 from repro.launch import specs as SP
 from repro.parallel import LOGICAL_RULES_SINGLE_POD, sharding_context
+from repro.launch.mesh import make_host_mesh
 cfg = get_arch('qwen2-0.5b').reduced()
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_host_mesh(model=4)  # (data=2, model=4)
 with sharding_context(mesh, LOGICAL_RULES_SINGLE_POD, set()):
     # tp=4 so the spec tree marks the 1-kv-head dim replicated (not sharded);
     # shapes are unchanged vs the tp=1 save (4 q heads already align)
