@@ -1951,7 +1951,7 @@ def _jax_arbiter(arbiter: str):
     return scores
 
 
-def _run_jax(grid: _Grid, arbiter: str = "jnp") -> list[CellResult]:
+def _run_jax(spec: SweepSpec, arbiter: str = "jnp") -> list[CellResult]:
     """The whole tick loop as one jitted `lax.while_loop`
     (`jaxbody.run_loop`, the body shared verbatim with the fused Pallas
     megakernel), in either mode: state lives in jnp int32 arrays,
@@ -1960,29 +1960,48 @@ def _run_jax(grid: _Grid, arbiter: str = "jnp") -> list[CellResult]:
     Closed grids add per-core MLP-window state and core-fed ring bank
     queues. Integer arithmetic keeps this bit-identical to the numpy
     backend and the scalar oracle; custom (non-vectorizable) policy
-    registrations are not traceable and must use `backend="batched"`."""
-    _check_jax_guards(grid)
+    registrations are not traceable and must use `backend="batched"`.
+
+    Each host stage is a span on the profiler's clock, in this order:
+    ``sweep.grid_build`` (`_Grid`), ``sweep.stage`` (constant planes to
+    the device, initial state), ``sweep.tick_loop`` (the loop's dispatch
+    until it has finished on the device), ``sweep.readback``
+    (`jax.device_get`) and ``sweep.finalize`` (the `CellResult`s), the
+    last carrying the counters ``cells`` and ``loop_iterations`` (the
+    times the while loop ran). Without an active profiler a span costs
+    one inactive `TraceMe`."""
     import jax
+    from jax.profiler import TraceAnnotation
 
     from repro.core.sweep import jaxbody
 
-    cfg, cst, st = jaxbody.program(grid)
-    out = jax.device_get(
-        jaxbody.run_loop(cfg, cst, _jax_arbiter(arbiter), st))
-    if grid.closed:
-        finished = (out["remaining"] <= 0).all(axis=1)
-        fin = np.where(out["finish"] < 0, int(out["t"]), out["finish"])
-    else:
-        finished = out["n_served"].sum(axis=1) >= grid.n_tot
-        fin = None
-    return [_finalize(grid, g, reads=out["reads"][g],
-                      writes=out["writes"][g], hits=out["hits"][g],
-                      misses=out["misses"][g], refpb=out["refpb"][g],
-                      refab=out["refab"][g], lat_sum=out["lat_sum"][g],
-                      hist=out["hist"][g], maxlag=out["maxlag"][g],
-                      last_done=out["last_done"][g], finished=finished[g],
-                      core_finish=None if fin is None else fin[g])
-            for g in range(grid.G)]
+    with TraceAnnotation("sweep.grid_build"):
+        grid = _Grid(spec)
+    _check_jax_guards(grid)
+    with TraceAnnotation("sweep.stage"):
+        cfg, cst, st = jaxbody.program(grid)
+    with TraceAnnotation("sweep.tick_loop"):
+        out = jax.block_until_ready(
+            jaxbody.run_loop(cfg, cst, _jax_arbiter(arbiter), st))
+    with TraceAnnotation("sweep.readback"):
+        out = jax.device_get(out)
+    with TraceAnnotation("sweep.finalize", cells=grid.G,
+                         loop_iterations=int(out["t"])):
+        if grid.closed:
+            finished = (out["remaining"] <= 0).all(axis=1)
+            fin = np.where(out["finish"] < 0, int(out["t"]), out["finish"])
+        else:
+            finished = out["n_served"].sum(axis=1) >= grid.n_tot
+            fin = None
+        return [_finalize(grid, g, reads=out["reads"][g],
+                          writes=out["writes"][g], hits=out["hits"][g],
+                          misses=out["misses"][g], refpb=out["refpb"][g],
+                          refab=out["refab"][g], lat_sum=out["lat_sum"][g],
+                          hist=out["hist"][g], maxlag=out["maxlag"][g],
+                          last_done=out["last_done"][g],
+                          finished=finished[g],
+                          core_finish=None if fin is None else fin[g])
+                for g in range(grid.G)]
 
 
 # ----------------------------------------------------- megakernel backend
@@ -2074,6 +2093,9 @@ def sweep(spec: SweepSpec, backend: str = "batched",
                     f"{len(cells)}")
             res.commands = ref.commands
         return res
+    if backend == "jax":
+        return SweepResult(spec, _run_jax(spec, arbiter=arbiter or "jnp"),
+                           backend)
     grid = _Grid(spec)
     traces = None
     if backend == "batched":
@@ -2085,8 +2107,6 @@ def sweep(spec: SweepSpec, backend: str = "batched",
                 cells = _run_batched_closed(grid, arbiter=arbiter or "numpy")
         else:
             cells = _run_batched(grid, arbiter=arbiter or "numpy")
-    elif backend == "jax":
-        cells = _run_jax(grid, arbiter=arbiter or "jnp")
     elif backend == "scalar":
         run_cell = _run_scalar_cell_closed if closed else _run_scalar_cell
         cells = [run_cell(grid, g) for g in range(grid.G)]

@@ -23,6 +23,14 @@ functions build the canonical initial state and each ``*_body`` returns a
 dict with exactly the same keys; the `pallas-lint` analysis pass (PL505)
 checks the key sets statically, because a key dropped from the body's
 return dict would silently freeze that state plane.
+
+Both bodies name their phases with `jax.named_scope`, one set of names
+for both modes, so a profiler trace or the compiled HLO's ``op_name``
+says which phase an operation belongs to: ``tick.front_end`` (closed
+phases 0-2, open phase A), ``tick.refresh`` (3-4, B-C),
+``tick.arbitrate`` (the head gathers through `scores`) and
+``tick.serve`` (the per-channel serve loop with the histogram update).
+Scopes are metadata only: the traced program runs the same operations.
 """
 from __future__ import annotations
 
@@ -264,184 +272,188 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
 
     t = s["t"]
 
-    # ---- A: arrivals
-    def acond(a):
-        return (a["next_arrive"] <= t).any()
+    with jax.named_scope("tick.front_end"):
+        # ---- A: arrivals
+        def acond(a):
+            return (a["next_arrive"] <= t).any()
 
-    def abody(a):
-        can = a["next_arrive"] <= t
-        n_arrived = a["n_arrived"] + can
-        sl = jnp.minimum(n_arrived, L - 1)
-        na = qa[flat_gb, sl]
-        exhausted = n_arrived >= n_pb
-        return dict(
-            n_arrived=n_arrived,
-            wpend=a["wpend"] + (can & a["next_w"]).sum(axis=1),
-            next_arrive=jnp.where(
-                can, jnp.where(exhausted, _PAD_ARRIVE, na),
-                a["next_arrive"]),
-            next_w=jnp.where(can, qw[flat_gb, sl], a["next_w"]))
+        def abody(a):
+            can = a["next_arrive"] <= t
+            n_arrived = a["n_arrived"] + can
+            sl = jnp.minimum(n_arrived, L - 1)
+            na = qa[flat_gb, sl]
+            exhausted = n_arrived >= n_pb
+            return dict(
+                n_arrived=n_arrived,
+                wpend=a["wpend"] + (can & a["next_w"]).sum(axis=1),
+                next_arrive=jnp.where(
+                    can, jnp.where(exhausted, _PAD_ARRIVE, na),
+                    a["next_arrive"]),
+                next_w=jnp.where(can, qw[flat_gb, sl], a["next_w"]))
 
-    sub = lax.while_loop(acond, abody, dict(
-        n_arrived=s["n_arrived"], wpend=s["wpend"],
-        next_arrive=s["next_arrive"], next_w=s["next_w"]))
-    n_arrived, wpend = sub["n_arrived"], sub["wpend"]
-    drain = s["drain"] | (wpend >= HI)
-    n_served = s["n_served"]
-    active = n_served.sum(axis=1) < n_tot
+        sub = lax.while_loop(acond, abody, dict(
+            n_arrived=s["n_arrived"], wpend=s["wpend"],
+            next_arrive=s["next_arrive"], next_w=s["next_w"]))
+        n_arrived, wpend = sub["n_arrived"], sub["wpend"]
+        drain = s["drain"] | (wpend >= HI)
+        n_served = s["n_served"]
+        active = n_served.sum(axis=1) < n_tot
 
-    # ---- B: per-rank refresh debt (staggered tREFI/R apart)
-    acc = ((active & level_ab)[:, None] & (t > rank_phase)
-           & ((t - rank_phase) % REFI[:, None] == 0))
-    ab_pending = s["ab_pending"] + acc
-    rank_drain = s["rank_drain"] | acc
+    with jax.named_scope("tick.refresh"):
+        # ---- B: per-rank refresh debt (staggered tREFI/R apart)
+        acc = ((active & level_ab)[:, None] & (t > rank_phase)
+               & ((t - rank_phase) % REFI[:, None] == 0))
+        ab_pending = s["ab_pending"] + acc
+        rank_drain = s["rank_drain"] | acc
 
-    # ---- C: decisions
-    due = jnp.where(t >= phase, (t - phase) // REFI[:, None] + 1, 0)
-    issued = s["issued"]
-    lag = due - issued
-    bank_free, ref_until_s = s["bank_free"], s["ref_until_s"]
-    ready = (ref_until_s.reshape(G, B, S) <= t).all(axis=2)
-    idle = bank_free <= t
-    demand = n_arrived - n_served
-    picks, rr = select_batch(
-        jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
-        ready=ready, idle=idle, demand=demand, write_window=drain,
-        budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
-        nb=NB)
+        # ---- C: decisions
+        due = jnp.where(t >= phase, (t - phase) // REFI[:, None] + 1, 0)
+        issued = s["issued"]
+        lag = due - issued
+        bank_free, ref_until_s = s["bank_free"], s["ref_until_s"]
+        ready = (ref_until_s.reshape(G, B, S) <= t).all(axis=2)
+        idle = bank_free <= t
+        demand = n_arrived - n_served
+        picks, rr = select_batch(
+            jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
+            ready=ready, idle=idle, demand=demand, write_window=drain,
+            budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
+            nb=NB)
 
-    quiet_r = (idle.reshape(G, R, NB).all(axis=2)
-               & ready.reshape(G, R, NB).all(axis=2))
-    start_ab_r = ((active & (kind == KIND_AB))[:, None]
-                  & (ab_pending > 0) & quiet_r)
-    # staggered_ab: strict rank round-robin, channel-overlap-free
-    # (cfg.has_stag is static at trace time — grids without the policy
-    # keep this block out of the traced graph entirely)
-    if cfg.has_stag:
-        idx = s["ab_rr"] % R
-        chan_ready = ready.reshape(G, NC, RBC).all(axis=2)
-        st_elig = (active & (kind == KIND_STAG)
-                   & (ab_pending[arG, idx] > 0) & quiet_r[arG, idx]
-                   & chan_ready[arG, idx // cfg.NR])
-        start_ab_r = start_ab_r.at[arG, idx].set(
-            start_ab_r[arG, idx] | st_elig)
-        ab_rr = s["ab_rr"] + st_elig
-    else:
-        ab_rr = s["ab_rr"]
-    ctr = s["ctr"]
-    open_row_s, open_sub = s["open_row_s"], s["open_sub"]
-    sarp_c = sarp[:, None]
+        quiet_r = (idle.reshape(G, R, NB).all(axis=2)
+                   & ready.reshape(G, R, NB).all(axis=2))
+        start_ab_r = ((active & (kind == KIND_AB))[:, None]
+                      & (ab_pending > 0) & quiet_r)
+        # staggered_ab: strict rank round-robin, channel-overlap-free
+        # (cfg.has_stag is static at trace time — grids without the policy
+        # keep this block out of the traced graph entirely)
+        if cfg.has_stag:
+            idx = s["ab_rr"] % R
+            chan_ready = ready.reshape(G, NC, RBC).all(axis=2)
+            st_elig = (active & (kind == KIND_STAG)
+                       & (ab_pending[arG, idx] > 0) & quiet_r[arG, idx]
+                       & chan_ready[arG, idx // cfg.NR])
+            start_ab_r = start_ab_r.at[arG, idx].set(
+                start_ab_r[arG, idx] | st_elig)
+            ab_rr = s["ab_rr"] + st_elig
+        else:
+            ab_rr = s["ab_rr"]
+        ctr = s["ctr"]
+        open_row_s, open_sub = s["open_row_s"], s["open_sub"]
+        sarp_c = sarp[:, None]
 
-    # SARP marks (and closes) only the target subarray ctr % S; a
-    # non-SARP refresh occupies every subarray of the bank
-    m = jnp.repeat(start_ab_r, NB, axis=1)
-    new_sub = ctr % S
-    mark = (jnp.repeat(m, S, axis=1)
-            & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
-                        == sub_of_col, True))
-    ref_until_s = jnp.where(mark, (t + RFC_AB)[:, None], ref_until_s)
-    open_row_s = jnp.where(mark, -1, open_row_s)
-    ctr = ctr + (m & sarp_c)
-    ab_pending = ab_pending - start_ab_r
-    rank_drain = jnp.where(start_ab_r, ab_pending > 0, rank_drain)
-    refab = s["refab"] + start_ab_r.sum(axis=1)
+        # SARP marks (and closes) only the target subarray ctr % S; a
+        # non-SARP refresh occupies every subarray of the bank
+        m = jnp.repeat(start_ab_r, NB, axis=1)
+        new_sub = ctr % S
+        mark = (jnp.repeat(m, S, axis=1)
+                & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
+                            == sub_of_col, True))
+        ref_until_s = jnp.where(mark, (t + RFC_AB)[:, None], ref_until_s)
+        open_row_s = jnp.where(mark, -1, open_row_s)
+        ctr = ctr + (m & sarp_c)
+        ab_pending = ab_pending - start_ab_r
+        rank_drain = jnp.where(start_ab_r, ab_pending > 0, rank_drain)
+        refab = s["refab"] + start_ab_r.sum(axis=1)
 
-    new_sub = ctr % S
-    start = jnp.maximum(t, bank_free)
-    if cfg.has_hra:
-        # HiRA hidden row activation: refresh a subarray the in-flight
-        # access is NOT using starting at t (static at trace time —
-        # grids without the trait keep this out of the traced graph)
-        start = jnp.where(hra[:, None] & (new_sub != open_sub), t,
-                          start)
-    mark = (jnp.repeat(picks, S, axis=1)
-            & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
-                        == sub_of_col, True))
-    ref_until_s = jnp.where(
-        mark, jnp.repeat(start + RFC_PB[:, None], S, axis=1),
-        ref_until_s)
-    open_row_s = jnp.where(mark, -1, open_row_s)
-    ctr = ctr + picks
-    issued = issued + picks
-    refpb = s["refpb"] + picks.sum(axis=1)
-    maxlag = jnp.maximum(
-        s["maxlag"],
-        jnp.where(picks, jnp.abs(due - issued), 0).max(axis=1))
+        new_sub = ctr % S
+        start = jnp.maximum(t, bank_free)
+        if cfg.has_hra:
+            # HiRA hidden row activation: refresh a subarray the in-flight
+            # access is NOT using starting at t (static at trace time —
+            # grids without the trait keep this out of the traced graph)
+            start = jnp.where(hra[:, None] & (new_sub != open_sub), t,
+                              start)
+        mark = (jnp.repeat(picks, S, axis=1)
+                & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
+                            == sub_of_col, True))
+        ref_until_s = jnp.where(
+            mark, jnp.repeat(start + RFC_PB[:, None], S, axis=1),
+            ref_until_s)
+        open_row_s = jnp.where(mark, -1, open_row_s)
+        ctr = ctr + picks
+        issued = issued + picks
+        refpb = s["refpb"] + picks.sum(axis=1)
+        maxlag = jnp.maximum(
+            s["maxlag"],
+            jnp.where(picks, jnp.abs(due - issued), 0).max(axis=1))
 
-    # ---- D: arbitration + serve, one start per channel (scores —
-    # incl. the drain flag — snapshotted before any serve; the head
-    # request's own subarray's state is gathered from [G, B*S] planes)
-    ru3 = ref_until_s.reshape(G, B, S)
-    head_ru = jnp.take_along_axis(
-        ru3, s["h_sub"][:, :, None], axis=2)[:, :, 0]
-    head_or = jnp.take_along_axis(
-        open_row_s.reshape(G, B, S), s["h_sub"][:, :, None],
-        axis=2)[:, :, 0]
-    bank_mid = (ru3 > t).any(axis=2)
-    score = scores(t, has_req=demand > 0, head_row=s["h_row"],
-                   head_arrive=s["h_arr"], head_is_write=s["h_w"],
-                   bank_free=bank_free, head_ref_until=head_ru,
-                   bank_mid_ref=bank_mid, open_row=head_or,
-                   drain=drain,
-                   rank_drain=jnp.repeat(rank_drain, NB, axis=1))
-    h_arr_s, h_row_s = s["h_arr"], s["h_row"]
-    h_sub_s, h_w_s = s["h_sub"], s["h_w"]
-    last_op, last_rank = s["last_op"], s["last_rank"]
-    reads, writes = s["reads"], s["writes"]
-    hits_s, misses_s = s["hits"], s["misses"]
-    lat_sum, hist = s["lat_sum"], s["hist"]
-    last_done = s["last_done"]
-    for ch in range(NC):
-        sc_ch = score[:, ch * RBC:(ch + 1) * RBC]
-        bs = jnp.argmax(sc_ch, axis=1) + ch * RBC
-        ok = score[arG, bs] >= 0
-        row, sub_ = h_row_s[arG, bs], h_sub_s[arG, bs]
-        arr, isw = h_arr_s[arG, bs], h_w_s[arG, bs]
-        hit = row == head_or[arG, bs]
-        gr_b = bs // NB
-        lr = last_rank[:, ch]
-        lat = (jnp.where(hit, HIT, MISS)
-               + jnp.where(sarp & bank_mid[arG, bs],
-                           SARP_PEN, 0)
-               + jnp.where(isw != last_op[:, ch], TURN, 0)
-               + jnp.where((lr >= 0) & (lr != gr_b), RTR, 0))
-        done = t + lat
-        bank_free = bank_free.at[arG, bs].set(
-            jnp.where(ok, done + jnp.where(isw, WR, 0),
-                      bank_free[arG, bs]))
-        last_op = last_op.at[:, ch].set(
-            jnp.where(ok, isw, last_op[:, ch]))
-        last_rank = last_rank.at[:, ch].set(
-            jnp.where(ok, gr_b, last_rank[:, ch]))
-        gsub = bs * S + sub_
-        open_row_s = open_row_s.at[arG, gsub].set(
-            jnp.where(ok, row, open_row_s[arG, gsub]))
-        open_sub = open_sub.at[arG, bs].set(
-            jnp.where(ok, sub_, open_sub[arG, bs]))
-        n_served = n_served.at[arG, bs].add(ok)
-        served_w = ok & isw
-        wpend = wpend - served_w
-        drain = drain & ~(served_w & (wpend <= LO))
-        rmask = ok & ~isw
-        lrec = jnp.minimum(done - arr, MAX_LAT_TICKS)
-        hist = hist.at[arG, lrec].add(rmask)
-        lat_sum = lat_sum + jnp.where(rmask, lrec, 0)
-        reads = reads + rmask
-        writes = writes + served_w
-        hits_s = hits_s + (ok & hit)
-        misses_s = misses_s + (ok & ~hit)
-        last_done = jnp.where(ok, jnp.maximum(last_done, done),
-                              last_done)
-        flat = arG * B + bs
-        sl = jnp.minimum(n_served[arG, bs], L - 1)
-        h_arr_s = h_arr_s.at[arG, bs].set(
-            jnp.where(ok, qa[flat, sl], h_arr_s[arG, bs]))
-        h_row_s = h_row_s.at[arG, bs].set(
-            jnp.where(ok, qr[flat, sl], h_row_s[arG, bs]))
-        h_sub_s = h_sub_s.at[arG, bs].set(
-            jnp.where(ok, qs[flat, sl], h_sub_s[arG, bs]))
-        h_w_s = h_w_s.at[arG, bs].set(
-            jnp.where(ok, qw[flat, sl], h_w_s[arG, bs]))
+    with jax.named_scope("tick.arbitrate"):
+        # ---- D: arbitration + serve, one start per channel (scores —
+        # incl. the drain flag — snapshotted before any serve; the head
+        # request's own subarray's state is gathered from [G, B*S] planes)
+        ru3 = ref_until_s.reshape(G, B, S)
+        head_ru = jnp.take_along_axis(
+            ru3, s["h_sub"][:, :, None], axis=2)[:, :, 0]
+        head_or = jnp.take_along_axis(
+            open_row_s.reshape(G, B, S), s["h_sub"][:, :, None],
+            axis=2)[:, :, 0]
+        bank_mid = (ru3 > t).any(axis=2)
+        score = scores(t, has_req=demand > 0, head_row=s["h_row"],
+                       head_arrive=s["h_arr"], head_is_write=s["h_w"],
+                       bank_free=bank_free, head_ref_until=head_ru,
+                       bank_mid_ref=bank_mid, open_row=head_or,
+                       drain=drain,
+                       rank_drain=jnp.repeat(rank_drain, NB, axis=1))
+    with jax.named_scope("tick.serve"):
+        h_arr_s, h_row_s = s["h_arr"], s["h_row"]
+        h_sub_s, h_w_s = s["h_sub"], s["h_w"]
+        last_op, last_rank = s["last_op"], s["last_rank"]
+        reads, writes = s["reads"], s["writes"]
+        hits_s, misses_s = s["hits"], s["misses"]
+        lat_sum, hist = s["lat_sum"], s["hist"]
+        last_done = s["last_done"]
+        for ch in range(NC):
+            sc_ch = score[:, ch * RBC:(ch + 1) * RBC]
+            bs = jnp.argmax(sc_ch, axis=1) + ch * RBC
+            ok = score[arG, bs] >= 0
+            row, sub_ = h_row_s[arG, bs], h_sub_s[arG, bs]
+            arr, isw = h_arr_s[arG, bs], h_w_s[arG, bs]
+            hit = row == head_or[arG, bs]
+            gr_b = bs // NB
+            lr = last_rank[:, ch]
+            lat = (jnp.where(hit, HIT, MISS)
+                   + jnp.where(sarp & bank_mid[arG, bs],
+                               SARP_PEN, 0)
+                   + jnp.where(isw != last_op[:, ch], TURN, 0)
+                   + jnp.where((lr >= 0) & (lr != gr_b), RTR, 0))
+            done = t + lat
+            bank_free = bank_free.at[arG, bs].set(
+                jnp.where(ok, done + jnp.where(isw, WR, 0),
+                          bank_free[arG, bs]))
+            last_op = last_op.at[:, ch].set(
+                jnp.where(ok, isw, last_op[:, ch]))
+            last_rank = last_rank.at[:, ch].set(
+                jnp.where(ok, gr_b, last_rank[:, ch]))
+            gsub = bs * S + sub_
+            open_row_s = open_row_s.at[arG, gsub].set(
+                jnp.where(ok, row, open_row_s[arG, gsub]))
+            open_sub = open_sub.at[arG, bs].set(
+                jnp.where(ok, sub_, open_sub[arG, bs]))
+            n_served = n_served.at[arG, bs].add(ok)
+            served_w = ok & isw
+            wpend = wpend - served_w
+            drain = drain & ~(served_w & (wpend <= LO))
+            rmask = ok & ~isw
+            lrec = jnp.minimum(done - arr, MAX_LAT_TICKS)
+            hist = hist.at[arG, lrec].add(rmask)
+            lat_sum = lat_sum + jnp.where(rmask, lrec, 0)
+            reads = reads + rmask
+            writes = writes + served_w
+            hits_s = hits_s + (ok & hit)
+            misses_s = misses_s + (ok & ~hit)
+            last_done = jnp.where(ok, jnp.maximum(last_done, done),
+                                  last_done)
+            flat = arG * B + bs
+            sl = jnp.minimum(n_served[arG, bs], L - 1)
+            h_arr_s = h_arr_s.at[arG, bs].set(
+                jnp.where(ok, qa[flat, sl], h_arr_s[arG, bs]))
+            h_row_s = h_row_s.at[arG, bs].set(
+                jnp.where(ok, qr[flat, sl], h_row_s[arG, bs]))
+            h_sub_s = h_sub_s.at[arG, bs].set(
+                jnp.where(ok, qs[flat, sl], h_sub_s[arG, bs]))
+            h_w_s = h_w_s.at[arG, bs].set(
+                jnp.where(ok, qw[flat, sl], h_w_s[arG, bs]))
 
     return dict(
         t=t + 1, bank_free=bank_free, ref_until_s=ref_until_s,
@@ -493,202 +505,206 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
 
     t = s["t"]
 
-    # ---- 0: outstanding-read completions
-    exp = s["comp_t"] <= t
-    n_exp = exp.sum(axis=2).astype(jnp.int32)
-    out_reads = s["out_reads"] - n_exp
-    remaining = s["remaining"] - n_exp
-    comp_t = jnp.where(exp, _PAD_ARRIVE, s["comp_t"])
+    with jax.named_scope("tick.front_end"):
+        # ---- 0: outstanding-read completions
+        exp = s["comp_t"] <= t
+        n_exp = exp.sum(axis=2).astype(jnp.int32)
+        out_reads = s["out_reads"] - n_exp
+        remaining = s["remaining"] - n_exp
+        comp_t = jnp.where(exp, _PAD_ARRIVE, s["comp_t"])
 
-    # ---- 1: core issue (at most one per core per tick, core order)
-    next_idx = s["next_idx"]
-    sl = jnp.minimum(next_idx, N - 1)
-    head_w = sw[flat_gc, sl]
-    can = (next_idx < n_req) & (s["next_issue"] <= t)
-    want_w = can & head_w
-    want_r = can & ~head_w & (out_reads < mlp_col)
-    rank_w = jnp.cumsum(want_w, axis=1) - want_w
-    ok_w = want_w & (rank_w < (CAP - s["wpend"])[:, None])
-    issue = ok_w | want_r
-    hb = sb[flat_gc, sl]
-    oh = issue[:, :, None] & (hb[:, :, None] == arB[None, None, :])
-    pref = jnp.cumsum(oh, axis=1) - oh
-    pos_in = jnp.take_along_axis(pref, hb[:, :, None], axis=2)[:, :, 0]
-    tail_b = jnp.take_along_axis(s["q_tail"], hb, axis=1)
-    slot = (tail_b + pos_in) & QM
-    tgt = jnp.where(issue, (arG[:, None] * B + hb) * LQ + slot, OOB)
-    tgtf = tgt.ravel()
-    qa = s["qa"].at[tgtf].set(jnp.full(G * C, t, jnp.int32),
-                              mode="drop")
-    qr = s["qr"].at[tgtf].set(sr[flat_gc, sl].ravel(), mode="drop")
-    qs_ = s["qs"].at[tgtf].set(ssub[flat_gc, sl].ravel(), mode="drop")
-    qw = s["qw"].at[tgtf].set(head_w.ravel(), mode="drop")
-    qc = s["qc"].at[tgtf].set(jnp.broadcast_to(
-        arC[None, :], (G, C)).ravel(), mode="drop")
-    q_tail = s["q_tail"] + oh.sum(axis=1)
-    wpend = s["wpend"] + ok_w.sum(axis=1)
-    out_reads = out_reads + want_r
-    remaining = remaining - ok_w          # writes retire at issue
-    next_issue = jnp.where(issue, t + sth[flat_gc, sl],
-                           s["next_issue"])
-    next_idx = next_idx + issue
-    finish = jnp.where((remaining == 0) & (s["finish"] < 0), t,
-                       s["finish"])
-    active = (remaining > 0).any(axis=1)
+        # ---- 1: core issue (at most one per core per tick, core order)
+        next_idx = s["next_idx"]
+        sl = jnp.minimum(next_idx, N - 1)
+        head_w = sw[flat_gc, sl]
+        can = (next_idx < n_req) & (s["next_issue"] <= t)
+        want_w = can & head_w
+        want_r = can & ~head_w & (out_reads < mlp_col)
+        rank_w = jnp.cumsum(want_w, axis=1) - want_w
+        ok_w = want_w & (rank_w < (CAP - s["wpend"])[:, None])
+        issue = ok_w | want_r
+        hb = sb[flat_gc, sl]
+        oh = issue[:, :, None] & (hb[:, :, None] == arB[None, None, :])
+        pref = jnp.cumsum(oh, axis=1) - oh
+        pos_in = jnp.take_along_axis(pref, hb[:, :, None], axis=2)[:, :, 0]
+        tail_b = jnp.take_along_axis(s["q_tail"], hb, axis=1)
+        slot = (tail_b + pos_in) & QM
+        tgt = jnp.where(issue, (arG[:, None] * B + hb) * LQ + slot, OOB)
+        tgtf = tgt.ravel()
+        qa = s["qa"].at[tgtf].set(jnp.full(G * C, t, jnp.int32),
+                                  mode="drop")
+        qr = s["qr"].at[tgtf].set(sr[flat_gc, sl].ravel(), mode="drop")
+        qs_ = s["qs"].at[tgtf].set(ssub[flat_gc, sl].ravel(), mode="drop")
+        qw = s["qw"].at[tgtf].set(head_w.ravel(), mode="drop")
+        qc = s["qc"].at[tgtf].set(jnp.broadcast_to(
+            arC[None, :], (G, C)).ravel(), mode="drop")
+        q_tail = s["q_tail"] + oh.sum(axis=1)
+        wpend = s["wpend"] + ok_w.sum(axis=1)
+        out_reads = out_reads + want_r
+        remaining = remaining - ok_w          # writes retire at issue
+        next_issue = jnp.where(issue, t + sth[flat_gc, sl],
+                               s["next_issue"])
+        next_idx = next_idx + issue
+        finish = jnp.where((remaining == 0) & (s["finish"] < 0), t,
+                           s["finish"])
+        active = (remaining > 0).any(axis=1)
 
-    # ---- 2: write-drain watermark
-    drain = s["drain"] | (wpend >= HI)
+        # ---- 2: write-drain watermark
+        drain = s["drain"] | (wpend >= HI)
 
-    # ---- 3: per-rank refresh debt (staggered tREFI/R apart)
-    acc = ((active & level_ab)[:, None] & (t > rank_phase)
-           & ((t - rank_phase) % REFI[:, None] == 0))
-    ab_pending = s["ab_pending"] + acc
-    rank_drain = s["rank_drain"] | acc
+    with jax.named_scope("tick.refresh"):
+        # ---- 3: per-rank refresh debt (staggered tREFI/R apart)
+        acc = ((active & level_ab)[:, None] & (t > rank_phase)
+               & ((t - rank_phase) % REFI[:, None] == 0))
+        ab_pending = s["ab_pending"] + acc
+        rank_drain = s["rank_drain"] | acc
 
-    # ---- 4: decisions
-    due = jnp.where(t >= phase, (t - phase) // REFI[:, None] + 1, 0)
-    issued = s["issued"]
-    lag = due - issued
-    bank_free, ref_until_s = s["bank_free"], s["ref_until_s"]
-    ready = (ref_until_s.reshape(G, B, S) <= t).all(axis=2)
-    idle = bank_free <= t
-    demand = q_tail - s["q_head"]
-    picks, rr = select_batch(
-        jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
-        ready=ready, idle=idle, demand=demand, write_window=drain,
-        budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
-        nb=NB)
+        # ---- 4: decisions
+        due = jnp.where(t >= phase, (t - phase) // REFI[:, None] + 1, 0)
+        issued = s["issued"]
+        lag = due - issued
+        bank_free, ref_until_s = s["bank_free"], s["ref_until_s"]
+        ready = (ref_until_s.reshape(G, B, S) <= t).all(axis=2)
+        idle = bank_free <= t
+        demand = q_tail - s["q_head"]
+        picks, rr = select_batch(
+            jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
+            ready=ready, idle=idle, demand=demand, write_window=drain,
+            budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
+            nb=NB)
 
-    quiet_r = (idle.reshape(G, R, NB).all(axis=2)
-               & ready.reshape(G, R, NB).all(axis=2))
-    start_ab_r = ((active & (kind == KIND_AB))[:, None]
-                  & (ab_pending > 0) & quiet_r)
-    # staggered_ab: strict rank round-robin, channel-overlap-free
-    # (cfg.has_stag is static at trace time — grids without the policy
-    # keep this block out of the traced graph entirely)
-    if cfg.has_stag:
-        idx = s["ab_rr"] % R
-        chan_ready = ready.reshape(G, NC, RBC).all(axis=2)
-        st_elig = (active & (kind == KIND_STAG)
-                   & (ab_pending[arG, idx] > 0) & quiet_r[arG, idx]
-                   & chan_ready[arG, idx // cfg.NR])
-        start_ab_r = start_ab_r.at[arG, idx].set(
-            start_ab_r[arG, idx] | st_elig)
-        ab_rr = s["ab_rr"] + st_elig
-    else:
-        ab_rr = s["ab_rr"]
-    ctr = s["ctr"]
-    open_row_s, open_sub = s["open_row_s"], s["open_sub"]
-    sarp_c = sarp[:, None]
+        quiet_r = (idle.reshape(G, R, NB).all(axis=2)
+                   & ready.reshape(G, R, NB).all(axis=2))
+        start_ab_r = ((active & (kind == KIND_AB))[:, None]
+                      & (ab_pending > 0) & quiet_r)
+        # staggered_ab: strict rank round-robin, channel-overlap-free
+        # (cfg.has_stag is static at trace time — grids without the policy
+        # keep this block out of the traced graph entirely)
+        if cfg.has_stag:
+            idx = s["ab_rr"] % R
+            chan_ready = ready.reshape(G, NC, RBC).all(axis=2)
+            st_elig = (active & (kind == KIND_STAG)
+                       & (ab_pending[arG, idx] > 0) & quiet_r[arG, idx]
+                       & chan_ready[arG, idx // cfg.NR])
+            start_ab_r = start_ab_r.at[arG, idx].set(
+                start_ab_r[arG, idx] | st_elig)
+            ab_rr = s["ab_rr"] + st_elig
+        else:
+            ab_rr = s["ab_rr"]
+        ctr = s["ctr"]
+        open_row_s, open_sub = s["open_row_s"], s["open_sub"]
+        sarp_c = sarp[:, None]
 
-    # SARP marks (and closes) only the target subarray ctr % S; a
-    # non-SARP refresh occupies every subarray of the bank
-    m = jnp.repeat(start_ab_r, NB, axis=1)
-    new_sub = ctr % S
-    mark = (jnp.repeat(m, S, axis=1)
-            & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
-                        == sub_of_col, True))
-    ref_until_s = jnp.where(mark, (t + RFC_AB)[:, None], ref_until_s)
-    open_row_s = jnp.where(mark, -1, open_row_s)
-    ctr = ctr + (m & sarp_c)
-    ab_pending = ab_pending - start_ab_r
-    rank_drain = jnp.where(start_ab_r, ab_pending > 0, rank_drain)
-    refab = s["refab"] + start_ab_r.sum(axis=1)
+        # SARP marks (and closes) only the target subarray ctr % S; a
+        # non-SARP refresh occupies every subarray of the bank
+        m = jnp.repeat(start_ab_r, NB, axis=1)
+        new_sub = ctr % S
+        mark = (jnp.repeat(m, S, axis=1)
+                & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
+                            == sub_of_col, True))
+        ref_until_s = jnp.where(mark, (t + RFC_AB)[:, None], ref_until_s)
+        open_row_s = jnp.where(mark, -1, open_row_s)
+        ctr = ctr + (m & sarp_c)
+        ab_pending = ab_pending - start_ab_r
+        rank_drain = jnp.where(start_ab_r, ab_pending > 0, rank_drain)
+        refab = s["refab"] + start_ab_r.sum(axis=1)
 
-    new_sub = ctr % S
-    start = jnp.maximum(t, bank_free)
-    if cfg.has_hra:
-        # HiRA hidden row activation: refresh a subarray the in-flight
-        # access is NOT using starting at t (static at trace time —
-        # grids without the trait keep this out of the traced graph)
-        start = jnp.where(hra[:, None] & (new_sub != open_sub), t,
-                          start)
-    mark = (jnp.repeat(picks, S, axis=1)
-            & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
-                        == sub_of_col, True))
-    ref_until_s = jnp.where(
-        mark, jnp.repeat(start + RFC_PB[:, None], S, axis=1),
-        ref_until_s)
-    open_row_s = jnp.where(mark, -1, open_row_s)
-    ctr = ctr + picks
-    issued = issued + picks
-    refpb = s["refpb"] + picks.sum(axis=1)
-    maxlag = jnp.maximum(
-        s["maxlag"],
-        jnp.where(picks, jnp.abs(due - issued), 0).max(axis=1))
+        new_sub = ctr % S
+        start = jnp.maximum(t, bank_free)
+        if cfg.has_hra:
+            # HiRA hidden row activation: refresh a subarray the in-flight
+            # access is NOT using starting at t (static at trace time —
+            # grids without the trait keep this out of the traced graph)
+            start = jnp.where(hra[:, None] & (new_sub != open_sub), t,
+                              start)
+        mark = (jnp.repeat(picks, S, axis=1)
+                & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
+                            == sub_of_col, True))
+        ref_until_s = jnp.where(
+            mark, jnp.repeat(start + RFC_PB[:, None], S, axis=1),
+            ref_until_s)
+        open_row_s = jnp.where(mark, -1, open_row_s)
+        ctr = ctr + picks
+        issued = issued + picks
+        refpb = s["refpb"] + picks.sum(axis=1)
+        maxlag = jnp.maximum(
+            s["maxlag"],
+            jnp.where(picks, jnp.abs(due - issued), 0).max(axis=1))
 
-    # ---- 5: occupancy-aware arbitration + serve, one start per
-    # channel (scores — incl. drain — snapshotted before any serve)
-    hslot = s["q_head"] & QM
-    flat_h = flat_gb * LQ + hslot
-    h_row, h_sub = qr[flat_h], qs_[flat_h]
-    h_arr, h_w = qa[flat_h], qw[flat_h]
-    has_req = (demand > 0) & active[:, None]
-    ru3 = ref_until_s.reshape(G, B, S)
-    head_ru = jnp.take_along_axis(
-        ru3, h_sub[:, :, None], axis=2)[:, :, 0]
-    head_or = jnp.take_along_axis(
-        open_row_s.reshape(G, B, S), h_sub[:, :, None],
-        axis=2)[:, :, 0]
-    bank_mid = (ru3 > t).any(axis=2)
-    score = scores(t, has_req=has_req, head_row=h_row,
-                   head_arrive=h_arr, head_is_write=h_w,
-                   bank_free=bank_free, head_ref_until=head_ru,
-                   bank_mid_ref=bank_mid, open_row=head_or,
-                   drain=drain, occ=demand,
-                   rank_drain=jnp.repeat(rank_drain, NB, axis=1))
-    last_op, last_rank = s["last_op"], s["last_rank"]
-    q_head = s["q_head"]
-    reads, writes = s["reads"], s["writes"]
-    hits_s, misses_s = s["hits"], s["misses"]
-    lat_sum, hist = s["lat_sum"], s["hist"]
-    last_done = s["last_done"]
-    for ch in range(NC):
-        sc_ch = score[:, ch * RBC:(ch + 1) * RBC]
-        bs = jnp.argmax(sc_ch, axis=1) + ch * RBC
-        ok = score[arG, bs] >= 0
-        row, sub_ = h_row[arG, bs], h_sub[arG, bs]
-        arr, isw = h_arr[arG, bs], h_w[arG, bs]
-        core = qc[flat_gb * LQ + hslot][arG, bs]
-        hit = row == head_or[arG, bs]
-        gr_b = bs // NB
-        lr = last_rank[:, ch]
-        lat = (jnp.where(hit, HIT, MISS)
-               + jnp.where(sarp & bank_mid[arG, bs],
-                           SARP_PEN, 0)
-               + jnp.where(isw != last_op[:, ch], TURN, 0)
-               + jnp.where((lr >= 0) & (lr != gr_b), RTR, 0))
-        done = t + lat
-        bank_free = bank_free.at[arG, bs].set(
-            jnp.where(ok, done + jnp.where(isw, WR, 0),
-                      bank_free[arG, bs]))
-        last_op = last_op.at[:, ch].set(
-            jnp.where(ok, isw, last_op[:, ch]))
-        last_rank = last_rank.at[:, ch].set(
-            jnp.where(ok, gr_b, last_rank[:, ch]))
-        gsub = bs * S + sub_
-        open_row_s = open_row_s.at[arG, gsub].set(
-            jnp.where(ok, row, open_row_s[arG, gsub]))
-        open_sub = open_sub.at[arG, bs].set(
-            jnp.where(ok, sub_, open_sub[arG, bs]))
-        q_head = q_head.at[arG, bs].add(ok)
-        served_w = ok & isw
-        wpend = wpend - served_w
-        drain = drain & ~(served_w & (wpend <= LO))
-        rmask = ok & ~isw
-        lrec = jnp.minimum(done - arr, MAX_LAT_TICKS)
-        hist = hist.at[arG, lrec].add(rmask)
-        lat_sum = lat_sum + jnp.where(rmask, lrec, 0)
-        reads = reads + rmask
-        writes = writes + served_w
-        hits_s = hits_s + (ok & hit)
-        misses_s = misses_s + (ok & ~hit)
-        last_done = jnp.where(ok, jnp.maximum(last_done, done),
-                              last_done)
-        # reads: park the data return in the core's MLP window slot
-        free_k = jnp.argmax(comp_t[arG, core] == _PAD_ARRIVE, axis=1)
-        comp_t = comp_t.at[arG, core, free_k].set(
-            jnp.where(rmask, done, comp_t[arG, core, free_k]))
+    with jax.named_scope("tick.arbitrate"):
+        # ---- 5: occupancy-aware arbitration + serve, one start per
+        # channel (scores — incl. drain — snapshotted before any serve)
+        hslot = s["q_head"] & QM
+        flat_h = flat_gb * LQ + hslot
+        h_row, h_sub = qr[flat_h], qs_[flat_h]
+        h_arr, h_w = qa[flat_h], qw[flat_h]
+        has_req = (demand > 0) & active[:, None]
+        ru3 = ref_until_s.reshape(G, B, S)
+        head_ru = jnp.take_along_axis(
+            ru3, h_sub[:, :, None], axis=2)[:, :, 0]
+        head_or = jnp.take_along_axis(
+            open_row_s.reshape(G, B, S), h_sub[:, :, None],
+            axis=2)[:, :, 0]
+        bank_mid = (ru3 > t).any(axis=2)
+        score = scores(t, has_req=has_req, head_row=h_row,
+                       head_arrive=h_arr, head_is_write=h_w,
+                       bank_free=bank_free, head_ref_until=head_ru,
+                       bank_mid_ref=bank_mid, open_row=head_or,
+                       drain=drain, occ=demand,
+                       rank_drain=jnp.repeat(rank_drain, NB, axis=1))
+    with jax.named_scope("tick.serve"):
+        last_op, last_rank = s["last_op"], s["last_rank"]
+        q_head = s["q_head"]
+        reads, writes = s["reads"], s["writes"]
+        hits_s, misses_s = s["hits"], s["misses"]
+        lat_sum, hist = s["lat_sum"], s["hist"]
+        last_done = s["last_done"]
+        for ch in range(NC):
+            sc_ch = score[:, ch * RBC:(ch + 1) * RBC]
+            bs = jnp.argmax(sc_ch, axis=1) + ch * RBC
+            ok = score[arG, bs] >= 0
+            row, sub_ = h_row[arG, bs], h_sub[arG, bs]
+            arr, isw = h_arr[arG, bs], h_w[arG, bs]
+            core = qc[flat_gb * LQ + hslot][arG, bs]
+            hit = row == head_or[arG, bs]
+            gr_b = bs // NB
+            lr = last_rank[:, ch]
+            lat = (jnp.where(hit, HIT, MISS)
+                   + jnp.where(sarp & bank_mid[arG, bs],
+                               SARP_PEN, 0)
+                   + jnp.where(isw != last_op[:, ch], TURN, 0)
+                   + jnp.where((lr >= 0) & (lr != gr_b), RTR, 0))
+            done = t + lat
+            bank_free = bank_free.at[arG, bs].set(
+                jnp.where(ok, done + jnp.where(isw, WR, 0),
+                          bank_free[arG, bs]))
+            last_op = last_op.at[:, ch].set(
+                jnp.where(ok, isw, last_op[:, ch]))
+            last_rank = last_rank.at[:, ch].set(
+                jnp.where(ok, gr_b, last_rank[:, ch]))
+            gsub = bs * S + sub_
+            open_row_s = open_row_s.at[arG, gsub].set(
+                jnp.where(ok, row, open_row_s[arG, gsub]))
+            open_sub = open_sub.at[arG, bs].set(
+                jnp.where(ok, sub_, open_sub[arG, bs]))
+            q_head = q_head.at[arG, bs].add(ok)
+            served_w = ok & isw
+            wpend = wpend - served_w
+            drain = drain & ~(served_w & (wpend <= LO))
+            rmask = ok & ~isw
+            lrec = jnp.minimum(done - arr, MAX_LAT_TICKS)
+            hist = hist.at[arG, lrec].add(rmask)
+            lat_sum = lat_sum + jnp.where(rmask, lrec, 0)
+            reads = reads + rmask
+            writes = writes + served_w
+            hits_s = hits_s + (ok & hit)
+            misses_s = misses_s + (ok & ~hit)
+            last_done = jnp.where(ok, jnp.maximum(last_done, done),
+                                  last_done)
+            # reads: park the data return in the core's MLP window slot
+            free_k = jnp.argmax(comp_t[arG, core] == _PAD_ARRIVE, axis=1)
+            comp_t = comp_t.at[arG, core, free_k].set(
+                jnp.where(rmask, done, comp_t[arG, core, free_k]))
 
     return dict(
         t=t + 1, qa=qa, qr=qr, qs=qs_, qw=qw, qc=qc,

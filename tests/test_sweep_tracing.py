@@ -1,0 +1,96 @@
+"""The sweep's device path on the profiler's clock: the host spans of
+`sweep(..., backend="jax")`, the counters they carry, and the tick-phase
+scopes in the compiled loop, for both modes.
+
+A small grid is swept once warm, then once more inside a caller's span
+under `jax.profiler.trace`; the trace is read back with `ProfileData`.
+"""
+import glob
+import re
+
+import jax
+import pytest
+
+from repro.core.sweep import SweepSpec, jaxbody, sweep
+from repro.core.sweep.engine import _Grid, _jax_arbiter
+
+SPANS = ("sweep.grid_build", "sweep.stage", "sweep.tick_loop",
+         "sweep.readback", "sweep.finalize")
+SCOPES = ("tick.front_end", "tick.refresh", "tick.arbitrate", "tick.serve")
+CALLER = "caller"
+
+SPECS = {
+    "closed": SweepSpec(policies=("ref_ab", "dsarp", "hira"),
+                        scenarios=("closed_mixed",), densities=(8, 32),
+                        reqs=160, seed=3, mode="closed"),
+    "open": SweepSpec(policies=("ref_pb", "darp", "elastic"),
+                      scenarios=("mixed", "write_burst_draining"),
+                      densities=(32,), reqs=120, seed=3),
+}
+
+
+def _final_t(spec) -> int:
+    """The times the loop runs on `spec`, read from its final state."""
+    cfg, cst, s0 = jaxbody.program(_Grid(spec))
+    return int(jaxbody.run_loop(cfg, cst, _jax_arbiter("jnp"), s0)["t"])
+
+
+@pytest.fixture(scope="module", params=sorted(SPECS))
+def traced(request, tmp_path_factory):
+    """(mode, results, [(name, start, end, stats)] of the caller's span
+    and every `sweep.*` span, in start order)."""
+    spec = SPECS[request.param]
+    sweep(spec, backend="jax")                    # compile outside the trace
+    logdir = str(tmp_path_factory.mktemp(f"trace_{request.param}"))
+    with jax.profiler.trace(logdir):
+        with jax.profiler.TraceAnnotation(CALLER):
+            res = sweep(spec, backend="jax")
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    events = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for pl in jax.profiler.ProfileData.from_file(path).planes
+        for ln in pl.lines for e in ln.events
+        if e.name == CALLER or e.name.startswith("sweep.")]
+    events.sort(key=lambda ev: (ev[1], -ev[2]))
+    return request.param, res, events
+
+
+def test_spans_once_each_in_order_disjoint_inside_caller(traced):
+    _, _, events = traced
+    (caller, c0, c1, _), *spans = events
+    assert caller == CALLER
+    assert tuple(n for n, *_ in spans) == SPANS
+    for _, s, e, _ in spans:
+        assert c0 <= s <= e <= c1
+    for (_, _, end, _), (_, start, _, _) in zip(spans, spans[1:]):
+        assert end <= start
+
+
+def test_finalize_carries_cells_and_loop_iterations(traced):
+    mode, res, events = traced
+    stats = {n: st for n, _, _, st in events}
+    assert all(not stats[n] for n in SPANS[:-1])
+    counters = stats["sweep.finalize"]
+    assert set(counters) == {"cells", "loop_iterations"}
+    assert counters["cells"] == len(res.cells) == len(SPECS[mode].cells())
+    assert counters["loop_iterations"] == _final_t(SPECS[mode])
+    ticks = [round(c.makespan / SPECS[mode].dt_ns) for c in res.cells]
+    if mode == "closed":
+        # a core finishes at the tick its last request retires: the loop
+        # runs until the slowest cell's last core has finished
+        assert counters["loop_iterations"] > max(ticks)
+    else:
+        # the loop stops once the last request has started; an open
+        # cell's makespan also holds that request's latency
+        assert counters["loop_iterations"] <= max(ticks)
+        assert counters["loop_iterations"] + 4096 > max(ticks)
+
+
+@pytest.mark.parametrize("mode", sorted(SPECS))
+def test_compiled_loop_carries_every_tick_scope(mode):
+    cfg, cst, s0 = jaxbody.program(_Grid(SPECS[mode]))
+    hlo = jaxbody.run_loop.lower(cfg, cst, _jax_arbiter("jnp"),
+                                 s0).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope

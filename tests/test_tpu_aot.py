@@ -66,6 +66,11 @@ def test_jax_backend_loop_compiles_for_v5e(one_chip, mode):
     assert mem.argument_size_in_bytes > 0
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+    # the tick-phase scopes survive the chip's compiler in op_name
+    hlo = compiled.as_text()
+    for scope in ("tick.front_end", "tick.refresh", "tick.arbitrate",
+                  "tick.serve"):
+        assert f"/{scope}/" in hlo, scope
 
 
 @pytest.mark.parametrize("mode", ["closed", "open"])
