@@ -28,7 +28,7 @@ Both bodies name their phases with `jax.named_scope`, one set of names
 for both modes, so a profiler trace or the compiled HLO's ``op_name``
 says which phase an operation belongs to: ``tick.front_end`` (closed
 phases 0-2, open phase A), ``tick.refresh`` (3-4, B-C),
-``tick.arbitrate`` (the head gathers through `scores`) and
+``tick.arbitrate`` (the head reads through `scores`) and
 ``tick.serve`` (the per-channel serve loop with the histogram update).
 Scopes are metadata only: the traced program runs the same operations.
 """
@@ -133,6 +133,21 @@ def closed_consts(grid) -> dict:
         n_req=_j32(grid.n_req_c),
         mlp=_j32(grid.mlp_g),
         **_shared_consts(grid))
+
+
+# ------------------------------------------------------------ lane picks
+def pick(plane, idx):
+    """``plane[..., idx]``: for each index of `idx`, the one entry of
+    `plane`'s last axis it names, as a one-hot compare and a reduction
+    along that axis. On a TPU this is a dense pass over `plane`, where a
+    gather serialises its indices. Exactly one lane matches an index in
+    ``[0, plane.shape[-1])``, so the result equals the gather bit for bit;
+    an index out of that range reads 0 (False)."""
+    hot = (lax.broadcasted_iota(jnp.int32, plane.shape, plane.ndim - 1)
+           == idx[..., None])
+    if plane.dtype == jnp.bool_:
+        return (hot & plane).any(axis=-1)
+    return jnp.where(hot, plane, 0).sum(axis=-1, dtype=plane.dtype)
 
 
 # ------------------------------------------------------------- state zero
@@ -381,13 +396,10 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
     with jax.named_scope("tick.arbitrate"):
         # ---- D: arbitration + serve, one start per channel (scores —
         # incl. the drain flag — snapshotted before any serve; the head
-        # request's own subarray's state is gathered from [G, B*S] planes)
+        # request's own subarray's state is picked from [G, B*S] planes)
         ru3 = ref_until_s.reshape(G, B, S)
-        head_ru = jnp.take_along_axis(
-            ru3, s["h_sub"][:, :, None], axis=2)[:, :, 0]
-        head_or = jnp.take_along_axis(
-            open_row_s.reshape(G, B, S), s["h_sub"][:, :, None],
-            axis=2)[:, :, 0]
+        head_ru = pick(ru3, s["h_sub"])
+        head_or = pick(open_row_s.reshape(G, B, S), s["h_sub"])
         bank_mid = (ru3 > t).any(axis=2)
         score = scores(t, has_req=demand > 0, head_row=s["h_row"],
                        head_arrive=s["h_arr"], head_is_write=s["h_w"],
@@ -499,7 +511,6 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
     arB = jnp.arange(B)
     arC = jnp.arange(C)
     flat_gc = arG[:, None] * C + arC[None, :]
-    flat_gb = arG[:, None] * B + arB[None, :]
     sub_of_col = jnp.tile(jnp.arange(S, dtype=jnp.int32), B)[None, :]
     OOB = G * B * LQ                       # scatter target for non-issues
 
@@ -635,16 +646,16 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         # ---- 5: occupancy-aware arbitration + serve, one start per
         # channel (scores — incl. drain — snapshotted before any serve)
         hslot = s["q_head"] & QM
-        flat_h = flat_gb * LQ + hslot
-        h_row, h_sub = qr[flat_h], qs_[flat_h]
-        h_arr, h_w = qa[flat_h], qw[flat_h]
+
+        def head(q):                       # each bank's ring-queue head
+            return pick(q.reshape(G, B, LQ), hslot)
+
+        h_row, h_sub, h_arr, h_w = head(qr), head(qs_), head(qa), head(qw)
+        h_core = head(qc)
         has_req = (demand > 0) & active[:, None]
         ru3 = ref_until_s.reshape(G, B, S)
-        head_ru = jnp.take_along_axis(
-            ru3, h_sub[:, :, None], axis=2)[:, :, 0]
-        head_or = jnp.take_along_axis(
-            open_row_s.reshape(G, B, S), h_sub[:, :, None],
-            axis=2)[:, :, 0]
+        head_ru = pick(ru3, h_sub)
+        head_or = pick(open_row_s.reshape(G, B, S), h_sub)
         bank_mid = (ru3 > t).any(axis=2)
         score = scores(t, has_req=has_req, head_row=h_row,
                        head_arrive=h_arr, head_is_write=h_w,
@@ -665,7 +676,7 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
             ok = score[arG, bs] >= 0
             row, sub_ = h_row[arG, bs], h_sub[arG, bs]
             arr, isw = h_arr[arG, bs], h_w[arG, bs]
-            core = qc[flat_gb * LQ + hslot][arG, bs]
+            core = h_core[arG, bs]
             hit = row == head_or[arG, bs]
             gr_b = bs // NB
             lr = last_rank[:, ch]

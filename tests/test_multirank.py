@@ -15,6 +15,7 @@ from repro.core.policy import (ALL_BANKS, Decision, MaintenanceView,
 from repro.core.refresh import DramSim, make_closed_workload
 from repro.core.refresh.timing import timing_for_density
 from repro.core.sweep import CellResult, SweepSpec, sweep
+from repro.core.sweep.engine import _Grid
 
 REQS, SEED, DENSITY = 96, 2, 32
 #: policy axis for the multirank grids: the paper family's representatives
@@ -72,6 +73,33 @@ def test_multirank_all_backends_bit_identical_to_run_ticks(n_ranks,
     for p in POLICIES:
         cell = batched.get(p, "closed_multirank", DENSITY)
         assert cell.finished, (p, n_ranks, n_channels)
+        _assert_cell_equals_sim(cell, DramSim(T, wl, p).run_ticks())
+
+
+def test_multirank_wrapped_ring_queues_bit_identical_to_run_ticks():
+    """Two channels x two ranks, closed, long enough that some bank's ring
+    queue takes more requests than it has slots, so its head slot wraps
+    past the end of the ring: every backend stays bit-identical to
+    `DramSim.run_ticks`."""
+    reqs = 3600
+    spec = SweepSpec(policies=POLICIES, scenarios=("closed_multirank",),
+                     densities=(DENSITY,), reqs=reqs, seed=SEED,
+                     mode="closed", n_ranks=2, n_channels=2)
+    grid = _Grid(spec)
+    per_bank = np.zeros((grid.G, grid.B), np.int64)
+    for g, c in np.ndindex(grid.G, grid.C):
+        np.add.at(per_bank[g], grid.s_bank[g, c, :grid.n_req_c[g, c]], 1)
+    assert per_bank.max() > grid.LQ, (per_bank.max(), grid.LQ)
+    batched = sweep(spec, "batched")
+    for backend, kw in (("scalar", {}), ("jax", {}), ("mega", {}),
+                        ("batched", {"arbiter": "pallas"})):
+        _cells_equal(sweep(spec, backend, **kw), batched,
+                     f"{backend}{kw}/batched wrapped rings")
+    wl = make_closed_workload("closed_multirank", reqs, SEED)
+    T = timing_for_density(DENSITY, n_ranks=2, n_channels=2)
+    for p in POLICIES:
+        cell = batched.get(p, "closed_multirank", DENSITY)
+        assert cell.finished, p
         _assert_cell_equals_sim(cell, DramSim(T, wl, p).run_ticks())
 
 

@@ -71,6 +71,11 @@ def test_jax_backend_loop_compiles_for_v5e(one_chip, mode):
     for scope in ("tick.front_end", "tick.refresh", "tick.arbitrate",
                   "tick.serve"):
         assert f"/{scope}/" in hlo, scope
+    # each bank's queue head and head subarray are one-hot lane selects
+    # (`jaxbody.pick`): the chip's compiler serialises a gather's indices
+    arb_gathers = [ln for ln in hlo.splitlines()
+                   if " gather(" in ln and "/tick.arbitrate/" in ln]
+    assert not arb_gathers, arb_gathers[:3]
 
 
 @pytest.mark.parametrize("mode", ["closed", "open"])
