@@ -9,10 +9,10 @@ that seed would check:
   * program: the device path's sweep against the reference (int32);
     sound runs read 0, and the largest over the seeds is the lower
     reading;
-  * control: the reference itself put in the program's place, with every
-    state plane and counter in int16, the next integer width below the
-    contract's int32; its smallest reading over the seeds is the upper
-    one.
+  * control: the configuration's reference itself put in the program's
+    place, with every state plane and counter in int16, the next integer
+    width below the contract's int32; its smallest reading over the seeds
+    is the upper one.
 
 The benchmark's runs never call this. Prints one line per seed and a
 JSON summary as the last line.
@@ -29,21 +29,21 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 
-from bench import harness, reference  # noqa: E402
+from bench import harness  # noqa: E402
 from bench.traffic import build  # noqa: E402
 
 #: the control's integer type: the width below the contract's int32
 CONTROL_ITYPE = np.int16
 
 
-def _diff(a: list, b: list) -> int:
-    return sum(any(x[f] != y[f] for f in reference.FIELDS)
-               for x, y in zip(a, b))
+def _diff(a: list, b: list, fields) -> int:
+    return sum(any(x[f] != y[f] for f in fields) for x, y in zip(a, b))
 
 
 def readings(cell: harness.Cell, seed: int, system) -> dict:
     """Program and control readings of `mismatched_cells` at `seed`."""
-    traffic = build(cell.mix, cell.config, seed)
+    reference = cell.reference
+    traffic = build(cell.mix, cell.config, seed, reference)
     cells = system.device_sweep(system.make_spec(traffic, cell.config))
     dt = cell.config["dt_ns"]
     sample = harness.sample_cells(
@@ -54,8 +54,9 @@ def readings(cell: harness.Cell, seed: int, system) -> dict:
     control = reference.simulate(traffic, cell.config, picked,
                                  itype=CONTROL_ITYPE)
     return {"seed": seed, "cells": len(sample),
-            "program": harness.mismatched([cells], sample, ref),
-            "control": _diff(control, ref)}
+            "program": harness.mismatched([cells], sample, ref,
+                                          reference.FIELDS),
+            "control": _diff(control, ref, reference.FIELDS)}
 
 
 def main(argv=None) -> int:

@@ -5,8 +5,10 @@
 The cell's entry in `BENCHMARK.json` names its configuration and its
 traffic; both are data files the harness finds by name
 (`bench/configs/`, `bench/workloads/<traffic>.json`), and so is each
-per-layer metric's reader (`bench/metrics/<metric>.py`). A new cell, or a
-new metric, is a new file and an entry in `BENCHMARK.json`.
+per-layer metric's reader (`bench/metrics/<metric>.py`). The
+configuration names its plain reference (``"reference"``, a module under
+`bench/`). A new cell, a new metric or a new reference is a new file and
+an entry in `BENCHMARK.json` or the configuration.
 
 A run:
 
@@ -19,9 +21,9 @@ A run:
   5. with `--trace 0`, calls the device path back to back on that one
      spec until `--seconds` have passed (the window); with `--trace 1`,
      profiles one sweep instead and reduces its trace;
-  6. checks the sweeps of the window against the plain reference
-     (`bench.reference`) on cells drawn from the seed, and prints the
-     result as the last line of standard output.
+  6. checks the sweeps of the window against the configuration's plain
+     reference on cells drawn from the seed, and prints the result as the
+     last line of standard output.
 """
 from __future__ import annotations
 
@@ -33,11 +35,11 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass
-from types import SimpleNamespace
+from types import ModuleType, SimpleNamespace
 
 import numpy as np
 
-from bench import reference, roofline, trace
+from bench import roofline, trace
 from bench.traffic import build
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,6 +56,7 @@ SPAN = "sweep"
 class Cell:
     workload: dict              # the cell's entry in BENCHMARK.json
     config: dict                # its configuration file
+    reference: ModuleType       # the plain reference the config names
     mix: dict                   # its traffic file
     end_to_end: list            # its end-to-end metric entries
     per_layer: list             # its per-layer metric entries
@@ -80,17 +83,38 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     per_layer = [m for m in manifest["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in moved)]
-    return Cell(wl, config, mix, e2e, per_layer)
+    return Cell(wl, config, load_reference(config, root), mix, e2e,
+                per_layer)
+
+
+def _load_module(name: str, path: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, root: str = ROOT):
     """`read(ctx)` of `<root>/bench/metrics/<name>.py`."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(f"bench_metric_{name}", os.path.join(
+        root, "bench", "metrics", name + ".py")).read
+
+
+def load_reference(config: dict, root: str = ROOT):
+    """The plain reference `config` names: the module at
+    ``<root>/<config["reference"]>``, under `bench/`, with
+    ``simulate(traffic, config, cells, itype=np.int32, record=False)``
+    and ``FIELDS``."""
+    root = os.path.normpath(root)
+    rel = config.get("reference")
+    path = os.path.normpath(os.path.join(root, rel or ""))
+    if not (rel and path.startswith(os.path.join(root, "bench", ""))
+            and path.endswith(".py")):
+        raise ValueError(f"{config.get('name')}: reference {rel!r} is no "
+                         f"module under bench/")
+    stem = os.path.splitext(os.path.relpath(path, root))[0]
+    return _load_module("bench_reference_" + stem.replace(os.sep, "_"),
+                        path)
 
 
 class CompileCounter:
@@ -137,15 +161,15 @@ def sample_cells(n_cells: int, k: int, longest: int, seed: int) -> list:
     return sorted(pick)
 
 
-def mismatched(sweeps: list, sample: list, ref: list) -> int:
+def mismatched(sweeps: list, sample: list, ref: list, fields) -> int:
     """Sampled cells, over all `sweeps`, whose result differs from the
-    reference in any field."""
+    reference in any of `fields`."""
     bad = 0
     for cells in sweeps:
         for g, r in zip(sample, ref):
             c = cells[g] if g < len(cells) else None
             if c is None or any(getattr(c, f, None) != r[f]
-                                for f in reference.FIELDS):
+                                for f in fields):
                 bad += 1
     return bad
 
@@ -195,8 +219,8 @@ def run(args, *, t0: float, root: str = ROOT, require_chip: bool = True,
     if system is None:
         from bench import system
     print(f"bench: compile cache {system.compile_cache()}", file=sys.stderr)
-    config = cell.config
-    traffic = build(cell.mix, config, args.seed)
+    config, reference = cell.config, cell.reference
+    traffic = build(cell.mix, config, args.seed, reference)
     spec = system.make_spec(traffic, config)
     with CompileCounter() as compiles:
         first = system.device_sweep(spec)
@@ -221,7 +245,7 @@ def run(args, *, t0: float, root: str = ROOT, require_chip: bool = True,
     sample = sample_cells(n_cells, traffic.check_cells, longest, args.seed)
     ref = reference.simulate(traffic, config,
                              [traffic.cells()[g] for g in sample])
-    bad = mismatched(sweeps, sample, ref)
+    bad = mismatched(sweeps, sample, ref, reference.FIELDS)
     print(f"bench: reference checked {len(sample)} cells x {len(sweeps)} "
           f"sweeps in {time.perf_counter() - t_check} s", file=sys.stderr)
 

@@ -91,7 +91,7 @@ def profile(workload: str, seed: int, root: str = ROOT,
     if system is None:
         from bench import system
     system.compile_cache()
-    traffic = build(cell.mix, cell.config, seed)
+    traffic = build(cell.mix, cell.config, seed, cell.reference)
     spec = system.make_spec(traffic, cell.config)
     first = system.device_sweep(spec)
     untraced = []

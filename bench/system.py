@@ -4,19 +4,32 @@ through this module.
 
 `device_sweep` is the one call of the device path. The window calls it
 back to back on the one spec `make_spec` builds in set-up.
+
+A configuration file reaches the program whole: its layout goes into
+`SweepSpec` key by key, and its timing, at each density of the grid,
+into the program's `DramTiming`. A key the program's types do not know
+raises at set-up, so nothing the file states is dropped on the way.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
 
 from repro.common.compile_cache import ENV, use_compile_cache
 from repro.core.refresh.scenarios import ClosedDemand, Trace
+from repro.core.refresh.timing import DramTiming, timing_for_density
 from repro.core.refresh.workload import Workload
 from repro.core.sweep import SweepSpec, sweep
 
-__all__ = ["compile_cache", "make_spec", "device_sweep"]
+__all__ = ["compile_cache", "dram_timing", "takes_timing", "make_spec",
+           "device_sweep"]
+
+#: the `SweepSpec` field that takes a grid's timing, ``{density_gb:
+#: DramTiming}``, where the program has it; without it the program
+#: simulates its own table (`timing_for_density`)
+TIMING_FIELD = "timing"
 
 #: the benchmark's compile cache: a fixed directory inside the checkout
 CACHE_DIR = os.path.join(
@@ -50,18 +63,48 @@ def _program_scenario(scn, dt_ns: float):
                         dt_ns).validate()
 
 
+def dram_timing(config: dict, density: int) -> DramTiming:
+    """`config`'s DRAM at `density` Gb as the program's `DramTiming`: its
+    layout and every key of its `timing_ns`, with ``tRFC_ab_pb`` at that
+    density as ``tRFC_ab`` and ``tRFC_pb``. A key `DramTiming` does not
+    know, or a density the file gives no tRFC, raises."""
+    tm = dict(config["timing_ns"])
+    ab, pb = tm.pop("tRFC_ab_pb")[str(density)]
+    return DramTiming(density_gb=density, tRFC_ab=ab, tRFC_pb=pb,
+                      **config["layout"], **tm)
+
+
+def takes_timing() -> bool:
+    """Whether `SweepSpec` takes a grid's timing (`TIMING_FIELD`)."""
+    return TIMING_FIELD in {f.name for f in dataclasses.fields(SweepSpec)}
+
+
 def make_spec(traffic, config: dict) -> SweepSpec:
-    """The grid of `traffic` on `config`'s DRAM layout."""
+    """The grid of `traffic` on `config`'s DRAM: its layout, its timing at
+    each density of the grid, `dt_ns` and its write buffer.
+
+    Where `SweepSpec` takes no timing, a timing other than the program's
+    own table raises: the program would simulate another DRAM than the
+    one the reference is given."""
     lay, wb = config["layout"], config["wbuf"]
+    timing = {d: dram_timing(config, d) for d in traffic.densities}
+    given = {}
+    if takes_timing():
+        given[TIMING_FIELD] = timing
+    else:
+        for d, T in timing.items():
+            if T != timing_for_density(d, **lay):
+                raise ValueError(
+                    f"{config['name']}: the DRAM timing at {d} Gb differs "
+                    f"from the program's own table, and SweepSpec takes no "
+                    f"{TIMING_FIELD!r}; the program cannot simulate it")
     return SweepSpec(
         policies=traffic.policies,
         scenarios=[_program_scenario(s, config["dt_ns"])
                    for s in traffic.scenarios],
         densities=traffic.densities, reqs=traffic.reqs,
-        dt_ns=config["dt_ns"], n_banks=lay["n_banks"],
-        n_subarrays=lay["n_subarrays"], n_ranks=lay["n_ranks"],
-        n_channels=lay["n_channels"], wbuf_hi=wb["hi"], wbuf_lo=wb["lo"],
-        wbuf_cap=wb["cap"], mode=traffic.mode)
+        dt_ns=config["dt_ns"], wbuf_hi=wb["hi"], wbuf_lo=wb["lo"],
+        wbuf_cap=wb["cap"], mode=traffic.mode, **lay, **given)
 
 
 def device_sweep(spec: SweepSpec) -> list:

@@ -71,11 +71,9 @@ def _relabel(scn, seed: int, what):
                    row=row_perm[scn.row // S] * S + scn.row % S).validate()
 
 
-def _capture(config: dict):
+def _capture(config: dict, reference):
     """The source run of a replay kind: one closed-loop cell of the
-    reference simulator, recording its serves."""
-    from bench import reference
-
+    configuration's plain reference, recording its serves."""
     B, S = _layout(config)
 
     def capture(source: dict, reqs: int, seed: int):
@@ -90,8 +88,10 @@ def _capture(config: dict):
     return capture
 
 
-def build(mix: dict, config: dict, seed: int) -> Traffic:
-    """The traffic `mix` (a parsed traffic file) for `config` at `seed`."""
+def build(mix: dict, config: dict, seed: int, reference) -> Traffic:
+    """The traffic `mix` (a parsed traffic file) for `config` at `seed`;
+    a replay kind's source run is a cell of `reference`, the module
+    `config` names (`harness.load_reference`)."""
     B, S = _layout(config)
     reqs = int(mix["reqs"])
     scenarios = []
@@ -110,7 +110,7 @@ def build(mix: dict, config: dict, seed: int) -> Traffic:
         for sc in mix["scenarios"]:
             kw = {k: v for k, v in sc.items() if k not in ("name", "kind")}
             if sc["kind"] == "replay_capture":
-                kw["capture"] = _capture(config)
+                kw["capture"] = _capture(config, reference)
             base = OPEN_KINDS[sc["kind"]](
                 sc["name"], B, S, reqs, open_rs(sc["name"], mix["base_seed"]),
                 **kw).validate()

@@ -5,7 +5,7 @@ import os
 import pytest
 from _bench_helpers import REPO
 
-from bench import roofline
+from bench import harness, roofline
 from bench.traffic import build
 
 
@@ -32,5 +32,6 @@ def test_fig_closed_least_bytes_by_hand():
     # 8 policies x 5 scenarios x 3 densities cells, each 11 four-byte
     # fields + the finished flag + 4 cores' finish times:
     results = 8 * 5 * 3 * (11 * 4 + 1 + 4 * 4)     # 7,320
-    assert roofline.least_bytes(build(mix, config, 0)) \
+    traffic = build(mix, config, 0, harness.load_reference(config))
+    assert roofline.least_bytes(traffic) \
         == streams + results == 177_320

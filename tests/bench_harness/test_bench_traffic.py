@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 from _bench_helpers import REPO
 
+from bench import harness
 from bench.traffic import build
 
 CELLS = {"fig_closed": "ddr3-1333-1ch1r", "open_grid": "ddr3-1333-1ch1r",
@@ -27,6 +28,10 @@ def _mix(traffic):
     return mix, _load("configs", CELLS[traffic])
 
 
+def _built(mix, config, seed):
+    return build(mix, config, seed, harness.load_reference(config))
+
+
 def _arrays(scn):
     return {k: v for k, v in vars(scn).items() if isinstance(v, np.ndarray)}
 
@@ -43,8 +48,8 @@ def _padded_shape(tr):
 @pytest.mark.parametrize("traffic", sorted(CELLS))
 def test_same_seed_same_demand(traffic):
     mix, config = _mix(traffic)
-    a, b = build(mix, config, 17), build(mix, config, 17)
-    c = build(mix, config, 18)
+    a, b = _built(mix, config, 17), _built(mix, config, 17)
+    c = _built(mix, config, 18)
     for x, y in zip(a.scenarios, b.scenarios):
         assert all((u == v).all() for u, v in zip(_arrays(x).values(),
                                                   _arrays(y).values()))
@@ -55,7 +60,7 @@ def test_same_seed_same_demand(traffic):
 @pytest.mark.parametrize("traffic", sorted(CELLS))
 def test_demand_validates(traffic):
     mix, config = _mix(traffic)
-    tr = build(mix, config, 2 ** 31 + 5)
+    tr = _built(mix, config, 2 ** 31 + 5)
     for s in tr.scenarios:
         s.validate()
     lay = config["layout"]
@@ -67,14 +72,14 @@ def test_demand_validates(traffic):
 @pytest.mark.parametrize("traffic", sorted(CELLS))
 def test_every_seed_same_padded_shapes(traffic):
     mix, config = _mix(traffic)
-    shapes = {json.dumps(_padded_shape(build(mix, config, s)), default=str)
+    shapes = {json.dumps(_padded_shape(_built(mix, config, s)), default=str)
               for s in SEEDS}
     assert len(shapes) == 1
 
 
 def test_open_seed_relabels_banks_keeping_subarrays():
     mix, config = _mix("open_grid")
-    a, b = build(mix, config, 1), build(mix, config, 2)
+    a, b = _built(mix, config, 1), _built(mix, config, 2)
     for x, y in zip(a.scenarios, b.scenarios):
         assert (x.arrive == y.arrive).all()
         assert (x.sub == y.sub).all() and (x.row % 8 == x.sub).all()
