@@ -16,16 +16,17 @@ The engines expose tick-level *outcomes*; this package makes their
 
 Normative spec: docs/tick-contract.md section 7.
 """
-from repro.core.commands.trace import (MNEMONICS, TIMING_FIELDS, Cmd,
-                                       CmdRecorder, CmdTrace, event_meta,
-                                       tick_meta)
+from repro.core.commands.trace import (BANK_GROUP_FIELDS, MNEMONICS,
+                                       TIMING_FIELDS, Cmd, CmdRecorder,
+                                       CmdTrace, event_meta, tick_meta)
 from repro.core.commands.validator import RULES, Violation, validate_trace
 from repro.core.commands.replay import (ReplayWorkload, demand_from_commands,
                                         replay_trace, round_trip,
                                         traces_equal)
 
 __all__ = [
-    "MNEMONICS", "TIMING_FIELDS", "Cmd", "CmdRecorder", "CmdTrace",
+    "MNEMONICS", "TIMING_FIELDS", "BANK_GROUP_FIELDS", "Cmd", "CmdRecorder",
+    "CmdTrace",
     "tick_meta", "event_meta",
     "RULES", "Violation", "validate_trace",
     "ReplayWorkload", "demand_from_commands", "replay_trace", "round_trip",

@@ -56,9 +56,13 @@ class ReplayWorkload:
 
 
 def timing_from_meta(meta: dict):
-    """Rebuild the `DramTiming` a trace was emitted under."""
-    from repro.core.refresh.timing import timing_for_density
+    """Rebuild the `DramTiming` a trace was emitted under: its ``dram``
+    field by field, or for a trace without it the program's own table
+    at the trace's density and layout."""
+    from repro.core.refresh.timing import DramTiming, timing_for_density
 
+    if meta.get("dram") is not None:
+        return DramTiming(**meta["dram"])
     return timing_for_density(
         meta["density_gb"],
         n_banks=meta["n_banks"],
@@ -130,10 +134,12 @@ def replay_trace(trace: CmdTrace, *, policy: Optional[str] = None,
 
 def traces_equal(a: CmdTrace, b: CmdTrace) -> bool:
     """Command-for-command equality plus the timing/identity meta keys."""
-    from repro.core.commands.trace import TIMING_FIELDS, _key
+    from repro.core.commands.trace import (BANK_GROUP_FIELDS, TIMING_FIELDS,
+                                           _key)
 
-    keys = TIMING_FIELDS + ("policy", "level", "clock", "dt_ns", "n_banks",
-                            "n_ranks", "n_channels", "n_subarrays", "end")
+    keys = TIMING_FIELDS + BANK_GROUP_FIELDS + (
+        "policy", "level", "clock", "dt_ns", "n_banks", "n_ranks",
+        "n_channels", "n_subarrays", "end")
     if any(a.meta.get(k) != b.meta.get(k) for k in keys):
         return False
     return sorted(a.cmds, key=_key) == sorted(b.cmds, key=_key)

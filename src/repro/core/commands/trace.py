@@ -15,7 +15,8 @@ tick traces.
 `data` semantics per op:
 
 * ``RD``/``WR``      — tick the data burst completes (serve latency end),
-* ``REF_AB``/``REF_PB`` — the *decision* tick (phase 4 / refresher grant),
+* ``REF_AB``/``REF_PB``/``REF_SB`` — the *decision* tick (phase 4 /
+  refresher grant),
   which is what the postpone/pull-in budget is accounted against; the
   command's own timestamp is the decision tick plus ``TRP``,
 * everything else  — ``-1``.
@@ -26,7 +27,8 @@ from dataclasses import dataclass, field
 from typing import List, NamedTuple, Optional
 
 #: Normative command mnemonics (docs/tick-contract.md section 7).
-MNEMONICS = ("ACT", "PRE", "PREA", "RD", "WR", "REF_AB", "REF_PB")
+MNEMONICS = ("ACT", "PRE", "PREA", "RD", "WR", "REF_AB", "REF_PB",
+             "REF_SB")
 
 #: Normative timing/config fields carried in every trace's ``meta`` —
 #: the quantized `TickTiming`-style constants the validator re-derives
@@ -34,10 +36,15 @@ MNEMONICS = ("ACT", "PRE", "PREA", "RD", "WR", "REF_AB", "REF_PB")
 TIMING_FIELDS = ("REFI", "REFI_PB", "RFC_AB", "RFC_PB", "TRP", "HIT",
                  "MISS", "WR", "TURN", "RTR", "SARP_PEN", "BUDGET")
 
+#: Fields only the traces of a part with bank groups carry (tick clock):
+#: the group count, the same-bank set's refresh interval and the
+#: same-group serve adder (docs/tick-contract.md section 7).
+BANK_GROUP_FIELDS = ("n_bank_groups", "REFI_SB", "CCDL")
+
 # Canonical intra-tick order: decisions (precharges/refreshes) precede
 # serves, matching the per-tick phase order (phases 3-4 before phase 5).
 _OP_ORDER = {"PREA": 0, "PRE": 1, "ACT": 2, "REF_AB": 3, "REF_PB": 4,
-             "RD": 5, "WR": 6}
+             "REF_SB": 4, "RD": 5, "WR": 6}
 
 
 class Cmd(NamedTuple):
@@ -47,7 +54,8 @@ class Cmd(NamedTuple):
     op: str         # one of MNEMONICS
     ch: int         # channel
     rank: int       # rank within channel (-1 never; PREA/REF_AB are rank-level)
-    bank: int       # bank within rank; -1 for rank-level ops (PREA, REF_AB)
+    bank: int       # bank within rank; -1 for rank-level ops (PREA, REF_AB);
+    #                 REF_SB: bank k of every group, named by group 0's
     sub: int        # target subarray; -1 = whole bank (non-SARP refresh, etc.)
     row: int        # row address for ACT/RD/WR (and the row being closed by PRE)
     data: float     # see module docstring
@@ -64,7 +72,9 @@ class CmdTrace:
 
     ``meta`` carries the hierarchy (n_banks/n_ranks/n_channels/
     n_subarrays), the policy traits the validator needs (level, sarp,
-    hra, ideal), the clock, and every `TIMING_FIELDS` constant.
+    hra, ideal), the clock, every `TIMING_FIELDS` constant (and with
+    bank groups every `BANK_GROUP_FIELDS` one), and ``dram``, the
+    `DramTiming` the run simulated, field by field.
     ``demand`` (tick traces only) optionally carries the raw per-core
     request streams so `repro.core.commands.replay` can re-drive the
     originating run bit-identically.
@@ -152,6 +162,8 @@ class CmdRecorder:
 
 
 def _base_meta(T, pol, wbuf) -> dict:
+    from dataclasses import asdict
+
     return {
         "policy": pol.name,
         "level": pol.level,
@@ -166,6 +178,7 @@ def _base_meta(T, pol, wbuf) -> dict:
         "wbuf_cap": int(wbuf[0]),
         "wbuf_hi": int(wbuf[1]),
         "wbuf_lo": int(wbuf[2]),
+        "dram": asdict(T),
     }
 
 
@@ -191,6 +204,10 @@ def tick_meta(T, pol, dt_ns: float, *, scenario: Optional[str] = None,
         "WR": tk(T.tWR), "TURN": tk(T.tWTR), "RTR": tk(T.tRTR),
         "SARP_PEN": tk(T.sarp_penalty), "BUDGET": int(T.refresh_budget),
     })
+    if T.n_bank_groups > 1:
+        m.update({"n_bank_groups": int(T.n_bank_groups),
+                  "REFI_SB": max(1, REFI // T.n_refresh_units),
+                  "CCDL": tk(T.tCCD_L) - tk(T.tCCD_S)})
     return m
 
 

@@ -5,27 +5,32 @@ emitted `CmdTrace` is realizable on a real controller:
 
 * ``missing-prea``     — every REF must be preceded by its matching
                          precharge preamble (PREA for rank-level REF_AB,
-                         PRE for per-bank REF_PB), litedram-style.
+                         PRE for per-bank REF_PB, a PRE on every bank of
+                         the same-bank set for REF_SB), litedram-style.
 * ``short-trp``        — preamble -> REF gap must be >= TRP (tRP).
 * ``short-trfc``       — no demand command (PRE/ACT/RD/WR) may land in an
                          active refresh footprint ``[start, start+tRFC)``
-                         on the refreshing subarray(s); SARP sibling
-                         subarrays stay legal.
+                         on the refreshing subarray(s) — a REF_SB's on
+                         every bank of its set; SARP sibling subarrays
+                         stay legal.
 * ``postpone-budget``  — JEDEC postpone/pull-in: at every REF the bank's
                          (or rank's) refresh lag, accounted at the
                          *decision* tick the command carries in ``data``,
                          must stay within the +/-8 budget the
-                         `MaintenanceLedger` enforces.
+                         `MaintenanceLedger` enforces (a REF_SB's lag is
+                         its set's).
 * ``trtr-min-latency`` — tick clock only: a RD/WR's data tick must be at
                          least issue + HIT/MISS + SARP_PEN + TURN + RTR
-                         per the phase-5 serve rule (tRTR rank turnaround
-                         included).  Event-mode ns traces skip this rule
+                         (+ CCDL after a column command to the same bank
+                         group: tCCD_L) per the phase-5 serve rule.
+                         Event-mode ns traces skip this rule
                          (tick-contract section 5 divergence).
 * ``bad-sequence``     — structural breakage: access to a closed row
                          without a same-tick ACT, more than one serve
                          start per channel per tick, a SARP refresh
-                         naming the wrong target subarray, or
-                         out-of-range addressing.
+                         naming the wrong target subarray, a REF_PB
+                         on a part with bank groups or a REF_SB on one
+                         without, or out-of-range addressing.
 
 The checker is a single forward pass grouping commands by timestamp, so
 it streams over arbitrarily long traces with O(banks) state.
@@ -97,8 +102,14 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
     S = int(m["n_subarrays"])
     R = NR * NC
     B = R * NB
+    # per-bank-level refresh units: banks, or same-bank sets of bank k of
+    # every group of a rank (u = gr * BPG + k)
+    NBG = int(m.get("n_bank_groups", 1))
+    BPG = NB // NBG
+    U = R * BPG
     REFI = m["REFI"]
-    REFI_PB = m["REFI_PB"]
+    REFI_U = m.get("REFI_SB", m["REFI_PB"])
+    CCDL = m.get("CCDL", 0)
     RFC = {"REF_AB": m["RFC_AB"], "REF_PB": m["RFC_PB"]}
     TRP = m["TRP"]
     BUDGET = int(m["BUDGET"])
@@ -118,11 +129,11 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
     # --- per-bank / per-rank state -------------------------------------
     open_row = [[-1] * S for _ in range(B)]
     ctr = [0] * B                     # refresh-target rotation (ctr % S)
-    issued_pb = [0] * B
+    issued_pb = [0] * U
     issued_ab = [0] * R
-    # phase offsets match the engines: per-bank pb staggering and
+    # phase offsets match the engines: per-unit pb staggering and
     # per-rank ab staggering (tick-contract sections 3 and 4).
-    phase = [b * REFI_PB for b in range(B)]
+    phase = [u * REFI_U for u in range(U)]
     if tick_clock:
         rank_phase = [gr * (REFI // R) for gr in range(R)]
     else:
@@ -132,11 +143,15 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
     foots: List[_Footprint] = []
     last_op = [False] * NC
     last_rank = [-1] * NC
+    last_bg = [-1] * NC
 
-    def due_pb(b, t):
-        if t < phase[b]:
+    def due_pb(u, t):
+        if t < phase[u]:
             return 0
-        return int((t - phase[b]) // REFI) + 1
+        return int((t - phase[u]) // REFI) + 1
+
+    def unit_of(gb):
+        return (gb // NB) * BPG + gb % BPG
 
     def acc_ab(gr, t):
         d = t - rank_phase[gr]
@@ -187,7 +202,8 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
             if (not 0 <= ch < NC or not 0 <= rank < NR
                     or not 0 <= sub < S and sub != -1
                     or (rank_level and bank != -1)
-                    or (not rank_level and not 0 <= bank < NB)):
+                    or (not rank_level and not 0 <= bank < NB)
+                    or (c.op == "REF_SB" and not bank < BPG)):
                 emit("bad-sequence", t, idx, addr,
                      f"{c.op} addressing out of range for "
                      f"hierarchy C{NC}xR{NR}xB{NB}xS{S}")
@@ -229,26 +245,33 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
                 if sub >= 0:
                     open_row[gb][sub] = c.row
 
-            elif c.op == "REF_PB":
-                pre = pend_pre.pop((gb, sub), None)
-                if pre is None:
-                    emit("missing-prea", t, idx, addr,
-                         "REF_PB without a preceding PRE preamble")
-                    start_footprint(t, "REF_PB", gb, sub)
-                else:
-                    gap = t - pre[0]
-                    if gap < TRP:
-                        emit("short-trp", t, idx, addr,
-                             f"PRE->REF_PB gap {gap} < TRP {TRP}")
-                if sarp and sub != ctr[gb] % S:
+            elif c.op in ("REF_PB", "REF_SB"):
+                if (c.op == "REF_SB") != (NBG > 1):
                     emit("bad-sequence", t, idx, addr,
-                         f"SARP REF_PB targets s{sub}, rotation expects "
-                         f"s{ctr[gb] % S}")
-                ctr[gb] += 1
-                issued_pb[gb] += 1
+                         f"{c.op} on a part with {NBG} bank group(s)")
+                # REF_SB: bank k of every group of the rank
+                banks = [gr * NB + g * BPG + bank for g in range(NBG)] \
+                    if c.op == "REF_SB" else [gb]
+                for b in banks:
+                    pre = pend_pre.pop((b, sub), None)
+                    if pre is None:
+                        emit("missing-prea", t, idx, addr,
+                             f"{c.op} without a preceding PRE preamble "
+                             f"on bank {b % NB}")
+                        start_footprint(t, "REF_PB", b, sub)
+                    elif t - pre[0] < TRP:
+                        emit("short-trp", t, idx, addr,
+                             f"PRE->{c.op} gap {t - pre[0]} < TRP {TRP}")
+                    if sarp and sub != ctr[b] % S:
+                        emit("bad-sequence", t, idx, addr,
+                             f"SARP {c.op} targets s{sub}, rotation "
+                             f"expects s{ctr[b] % S}")
+                    ctr[b] += 1
+                u = unit_of(gb)
+                issued_pb[u] += 1
                 if level == "pb" and not ideal:
                     td = c.data if c.data >= 0 else t - TRP
-                    lag = due_pb(gb, td) - issued_pb[gb]
+                    lag = due_pb(u, td) - issued_pb[u]
                     if abs(lag) > BUDGET:
                         emit("postpone-budget", t, idx, addr,
                              f"per-bank refresh lag {lag} at decision "
@@ -313,12 +336,16 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
                     if 0 <= last_rank[ch] != gr:
                         exp += RTR
                         terms.append("RTR")
+                    if CCDL and last_bg[ch] == gb // BPG:
+                        exp += CCDL
+                        terms.append("CCDL")
                     if c.data - t < exp:
                         emit("trtr-min-latency", t, idx, addr,
                              f"{c.op} data at +{c.data - t} < minimum "
                              f"{exp} ({'+'.join(terms)})")
                     last_op[ch] = isw
                     last_rank[ch] = gr
+                    last_bg[ch] = gb // BPG
             else:
                 emit("bad-sequence", t, idx, addr,
                      f"unknown mnemonic {c.op!r}")
@@ -330,11 +357,12 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
         end = cmds[-1].tick
     if end is not None and not ideal:
         if level == "pb":
-            for b in range(B):
-                lag = due_pb(b, end) - issued_pb[b]
+            for u in range(U):
+                lag = due_pb(u, end) - issued_pb[u]
                 if lag > BUDGET:
+                    gr = u // BPG
                     emit("postpone-budget", end, -1,
-                         _addr(b // NB // NR, (b // NB) % NR, b % NB, -1),
+                         _addr(gr // NR, gr % NR, u % BPG, -1),
                          f"bank ends the trace {lag} refreshes behind "
                          f"(budget {BUDGET})")
         elif level == "ab":

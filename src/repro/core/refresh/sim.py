@@ -218,12 +218,14 @@ def energy_proxy(T: DramTiming, makespan_ns: float, reads: int, writes: int,
     shorter runtime (background term). Every rank burns background/standby
     power for the whole run, so that term scales with `n_ranks_total`;
     `ref_ab` counts per-rank REF_ab starts (each covers one rank's
-    `n_banks`). Assumptions + deliberate deviations from the paper's
-    power model are documented in docs/figures.md."""
+    `n_banks`), `ref_pb` per-bank-level starts (each covers one bank, or
+    with bank groups a same-bank set of `n_bank_groups` banks).
+    Assumptions + deliberate deviations from the paper's power model are
+    documented in docs/figures.md."""
     return (0.5 * makespan_ns * T.n_ranks_total  # background + periphery
             + 12.0 * misses                      # activates + precharges
             + 6.0 * (reads + writes)
-            + 0.15 * T.tRFC_pb * ref_pb          # refresh energy ~ latency
+            + 0.15 * T.tRFC_pb * ref_pb * T.n_bank_groups  # ~ latency
             + 0.15 * T.tRFC_ab * ref_ab * T.n_banks / 2)
 
 
@@ -512,8 +514,10 @@ class DramSim:
         from repro.core.refresh.workload import quantize_streams
         from repro.core.sweep.arbiter import (AGE_CAP, OCC_CAP, W_HIT,
                                               W_NOCONF, W_OCC, W_WRITE)
+        from repro.core.refresh.timing import refresh_units
         from repro.core.sweep.engine import (MAX_LAT_TICKS, _p99_ticks,
-                                             _scalar_refreshing_sub)
+                                             _scalar_refreshing_sub,
+                                             _unit_lists)
 
         pol = resolve_policy(self._policy_spec)
         T = self.T
@@ -525,11 +529,17 @@ class DramSim:
             return max(1, int(ns / dt_ns + 0.5))
 
         REFI = tkq(T.tREFI)
-        REFI_PB = max(1, REFI // B)
+        # pb debt per refresh unit: a bank, or with bank groups the
+        # same-bank set of bank k of every group of a rank
+        units = refresh_units(B, NB, T.n_bank_groups)
+        U, BPG = len(units), T.banks_per_group
+        REFI_SB = max(1, REFI // U)
         RFC_PB, RFC_AB = tkq(T.tRFC_pb), tkq(T.tRFC_ab)
         HIT, MISS = tkq(T.row_hit), tkq(T.row_miss)
         WR, TURN = tkq(T.tWR), tkq(T.tWTR)
         RTR = tkq(T.tRTR)
+        CCDL = (tkq(T.tCCD_L) - tkq(T.tCCD_S)
+                if T.n_bank_groups > 1 else 0)
         SARP_PEN = tkq(T.sarp_penalty)
         TRP = tkq(T.tRP)
         budget = T.refresh_budget
@@ -545,9 +555,12 @@ class DramSim:
             from repro.core.commands.trace import CmdRecorder, tick_meta
             rec = CmdRecorder(tick_meta(T, pol, dt_ns, wbuf=(CAP, HI, LO)))
 
-        led = MaintenanceLedger(B, interval=float(REFI), budget=budget,
+        led = MaintenanceLedger(U, interval=float(REFI), budget=budget,
                                 stagger=False)
-        led.phase = [float(b * REFI_PB) for b in range(B)]
+        led.phase = [float(u * REFI_SB) for u in range(U)]
+        unit_rank = tuple(u // BPG for u in range(U))
+        unit_chan = tuple(u // (T.n_ranks * BPG) for u in range(U))
+        ref_op = "REF_PB" if T.n_bank_groups == 1 else "REF_SB"
 
         if horizon is None:
             think_span = max((int(s["think"].sum()) for s in streams),
@@ -574,6 +587,7 @@ class DramSim:
         drain = False
         last_op = [False] * NC           # per-channel bus turnaround state
         last_rank = [-1] * NC            # per-channel last-served rank
+        last_bg = [-1] * NC              # ... and its bank group
         ab_pending = [0] * R             # per-rank all-bank refresh debt
         rank_drain = [False] * R
         maxlag = 0
@@ -586,33 +600,39 @@ class DramSim:
         timeline = ({"refresh": [], "serves": []} if record_timeline
                     else None)
 
-        def start_pb(b: int, t: int):
+        def start_pb(u: int, t: int):
             nonlocal refpb, maxlag
-            ns_ = ctr[b] % S
+            us = units[u]
             # hidden row activation: a refresh targeting a subarray other
             # than the bank's active one issues NOW, behind the in-flight
-            # access, instead of waiting for the bank to go idle
-            start = t if (hra and ns_ != open_sub[b]) else \
-                max(t, bank_free[b])
+            # access, instead of waiting for the bank to go idle; a
+            # same-bank set starts once every bank of it can
+            start = max(t if (hra and ctr[b] % S != open_sub[b])
+                        else max(t, bank_free[b]) for b in us)
             end = start + RFC_PB
             if rec is not None:
-                tsub = ns_ if pol.sarp else -1
-                rec.emit(start, "PRE", b, sub=tsub)
-                rec.emit(start + TRP, "REF_PB", b, sub=tsub, data=t)
-            if pol.sarp:
-                ref_until_s[b][ns_] = end
-                open_row_s[b][ns_] = -1
-                if timeline is not None:
-                    timeline["refresh"].append((b, ns_, start, end, "pb"))
-            else:
-                for s_ in range(S):
-                    ref_until_s[b][s_] = end
-                    open_row_s[b][s_] = -1
-                if timeline is not None:
-                    timeline["refresh"].append((b, -1, start, end, "pb"))
-            ctr[b] += 1
+                tsub = ctr[us[0]] % S if pol.sarp else -1
+                for b in us:
+                    rec.emit(start, "PRE", b, sub=tsub)
+                rec.emit(start + TRP, ref_op, us[0], sub=tsub, data=t)
+            for b in us:
+                ns_ = ctr[b] % S
+                if pol.sarp:
+                    ref_until_s[b][ns_] = end
+                    open_row_s[b][ns_] = -1
+                    if timeline is not None:
+                        timeline["refresh"].append((b, ns_, start, end,
+                                                    "pb"))
+                else:
+                    for s_ in range(S):
+                        ref_until_s[b][s_] = end
+                        open_row_s[b][s_] = -1
+                    if timeline is not None:
+                        timeline["refresh"].append((b, -1, start, end,
+                                                    "pb"))
+                ctr[b] += 1
             refpb += 1
-            maxlag = max(maxlag, abs(led.lag(b, float(t))))
+            maxlag = max(maxlag, abs(led.lag(u, float(t))))
 
         def start_ab(gr: int, t: int):
             nonlocal refab
@@ -731,20 +751,19 @@ class DramSim:
                                             start_ab(gr, t)
                 else:
                     view = led.view(
-                        float(t),
-                        demand=[len(q[b]) for b in range(B)],
-                        write_window=drain,
-                        ready=[all(ru <= t for ru in ref_until_s[b])
-                               for b in range(B)],
-                        idle=[bank_free[b] <= t for b in range(B)],
+                        float(t), write_window=drain,
                         n_ranks=T.n_ranks, n_channels=NC,
-                        rank_of=self._rank_of, channel_of=self._chan_of,
-                        n_subarrays=S,
-                        next_ref_sub=tuple(ctr[b] % S for b in range(B)),
-                        refreshing_sub=tuple(
-                            _scalar_refreshing_sub(ref_until_s[b], t)
-                            for b in range(B)),
-                        active_sub=tuple(open_sub))
+                        rank_of=unit_rank, channel_of=unit_chan,
+                        n_subarrays=S, **_unit_lists(
+                            units, demand=[len(q[b]) for b in range(B)],
+                            ready=[all(ru <= t for ru in ref_until_s[b])
+                                   for b in range(B)],
+                            idle=[bank_free[b] <= t for b in range(B)],
+                            next_sub=[ctr[b] % S for b in range(B)],
+                            refreshing=[
+                                _scalar_refreshing_sub(ref_until_s[b], t)
+                                for b in range(B)],
+                            active=open_sub))
                     decs = pol.select(view)
                     for dec in decs:
                         if dec.bank == ALL_BANKS:
@@ -792,10 +811,13 @@ class DramSim:
                         lat += TURN
                     if 0 <= last_rank[ch] != gr:
                         lat += RTR       # rank-to-rank bus handoff
+                    if last_bg[ch] == b // BPG:
+                        lat += CCDL      # same bank group: tCCD_L
                     done = t + lat
                     bank_free[b] = done + (WR if isw else 0)
                     last_op[ch] = isw
                     last_rank[ch] = gr
+                    last_bg[ch] = b // BPG
                     if rec is not None:
                         if not hit:
                             if open_row_s[b][sub] != -1:
@@ -846,6 +868,10 @@ class DramSim:
     def run(self, *, record_commands: bool = False) -> SimResult:
         self.policy = resolve_policy(self._policy_spec)
         T, pol = self.T, self.policy
+        if T.n_bank_groups > 1:
+            raise ValueError(
+                "event mode models no bank groups (n_bank_groups="
+                f"{T.n_bank_groups}); run_ticks() does")
         nb, ncore = T.n_banks_total, self.wl.n_cores
         R = T.n_ranks_total
 

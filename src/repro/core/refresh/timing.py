@@ -2,10 +2,17 @@
 
 Values follow the HPCA-14 DSARP paper (Table 2/3): DDR3-1333-class device
 timings, with tRFC scaling across 8/16/32 Gb densities. All times in ns.
+
+A part with bank groups (DDR4, DDR5) sets `n_bank_groups` > 1 and both
+bank-group column-to-column delays, `tCCD_L` (same group) and `tCCD_S`
+(other group). Its per-bank level refreshes a same-bank set (DDR5
+REFsb): bank k of every group of a rank at once (docs/tick-contract.md
+section 3), with `tRFC_pb` as the set's refresh latency (tRFCsb).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
+from typing import Optional
 
 
 @dataclass(frozen=True)
@@ -15,6 +22,7 @@ class DramTiming:
     n_subarrays: int = 8          # subarrays exposed for SARP
     n_ranks: int = 1              # ranks per channel
     n_channels: int = 1           # channels (one data bus each)
+    n_bank_groups: int = 1        # bank groups per rank (divides n_banks)
 
     # core timings (ns)
     tRCD: float = 13.75           # activate -> column
@@ -25,6 +33,11 @@ class DramTiming:
     tWTR: float = 7.5             # write->read turnaround
     tRTW: float = 7.5             # read->write turnaround
     tRTR: float = 3.0             # rank-to-rank bus turnaround (ODT swap)
+    # bank-group column-to-column delays; given iff n_bank_groups > 1 (a
+    # part without groups has one tCCD, folded into the one start per
+    # channel per tick)
+    tCCD_L: Optional[float] = None    # same bank group
+    tCCD_S: Optional[float] = None    # different bank group
 
     # refresh
     tREFI: float = 7812.5         # per-rank refresh interval
@@ -36,6 +49,26 @@ class DramTiming:
     # added latency for the shared peripheral handoff (paper §5: row-address
     # mux + separate subarray sense amps; I/O bus is untouched).
     sarp_penalty: float = 4.5
+
+    def __post_init__(self):
+        if self.n_bank_groups < 1 or self.n_banks % self.n_bank_groups:
+            raise ValueError(
+                f"n_bank_groups={self.n_bank_groups} must divide "
+                f"n_banks={self.n_banks}")
+        given = [k for k in ("tCCD_L", "tCCD_S")
+                 if getattr(self, k) is not None]
+        # like a missing or unexpected argument: the bank-group timings
+        # come with bank groups, and only with them
+        if self.n_bank_groups > 1 and len(given) < 2:
+            raise TypeError(
+                f"DramTiming with n_bank_groups={self.n_bank_groups} "
+                "needs both tCCD_L and tCCD_S")
+        if self.n_bank_groups == 1 and given:
+            raise TypeError(
+                f"DramTiming got {', '.join(given)} with n_bank_groups=1: "
+                "bank-group timings need bank groups")
+        if given and self.tCCD_L < self.tCCD_S:
+            raise ValueError(f"tCCD_L={self.tCCD_L} < tCCD_S={self.tCCD_S}")
 
     @property
     def n_ranks_total(self) -> int:
@@ -53,6 +86,17 @@ class DramTiming:
         """Per-bank refresh cadence: tREFI spread uniformly over every
         bank in the hierarchy (reduces to tREFI / n_banks at one rank)."""
         return self.tREFI / self.n_banks_total
+
+    @property
+    def banks_per_group(self) -> int:
+        return self.n_banks // self.n_bank_groups
+
+    @property
+    def n_refresh_units(self) -> int:
+        """Per-bank-level refresh units over the hierarchy: one per bank,
+        or with bank groups one same-bank set per (rank, bank of a
+        group)."""
+        return self.n_ranks_total * self.banks_per_group
 
     def rank_of(self, gb: int) -> int:
         """Global rank index of global bank `gb`."""
@@ -81,3 +125,14 @@ DENSITIES = tuple(sorted(_TRFC))
 def timing_for_density(density_gb: int, **kw) -> DramTiming:
     ab, pb = _TRFC[density_gb]
     return DramTiming(density_gb=density_gb, tRFC_ab=ab, tRFC_pb=pb, **kw)
+
+
+def refresh_units(n_banks_total: int, n_banks: int,
+                  n_bank_groups: int = 1) -> list[tuple[int, ...]]:
+    """Global banks of each per-bank-level refresh unit, in unit order
+    ``u = gr * K + k`` (K = banks per group): bank k of every group of
+    global rank gr, ``gr * n_banks + g * K + k`` for each group g. Without
+    bank groups every unit is one bank (``u == gb``)."""
+    K = n_banks // n_bank_groups
+    return [tuple(gr * n_banks + g * K + k for g in range(n_bank_groups))
+            for gr in range(n_banks_total // n_banks) for k in range(K)]

@@ -121,22 +121,23 @@ from __future__ import annotations
 
 import functools
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from itertools import product
-from typing import Optional, Sequence, Union
+from typing import Mapping, Optional, Sequence, Union
 
 import numpy as np
 
 from repro.core.policy import ALL_BANKS, MaintenanceView, resolve_policy
 from repro.core.refresh.scenarios import (ClosedDemand, Trace,
                                           make_closed_demand, make_trace)
-from repro.core.refresh.timing import timing_for_density
+from repro.core.refresh.timing import (DramTiming, refresh_units,
+                                       timing_for_density)
 from repro.core.sweep.arbiter import (AGE_CAP, OCC_CAP, W_HIT, W_NOCONF,
                                       W_OCC, W_WRITE, arbiter_scores,
                                       arbiter_scores_masked)
 from repro.core.sweep.policies import (KIND_AB, KIND_CUSTOM, KIND_IDEAL,
                                        KIND_STAG, classify, could_pick,
-                                       select_batch)
+                                       per_bank, per_unit, select_batch)
 
 #: read-latency histogram width (ticks); larger waits clip into the top bin
 MAX_LAT_TICKS = 4095
@@ -150,11 +151,18 @@ class TickTiming:
 
     `REFI_PB` spreads tREFI uniformly over every bank in the hierarchy
     (n_channels x n_ranks x n_banks), so per-bank refresh phases — and
-    hence whole ranks' refresh windows — stagger across ranks."""
+    hence whole ranks' refresh windows — stagger across ranks. `REFI_SB`
+    spreads it over the `U` refresh units instead (one per bank, or with
+    bank groups one same-bank set per rank and bank of a group; equal to
+    `REFI_PB` without groups). `CCDL` is the tCCD_L - tCCD_S serve adder
+    of a start in the bank group of its channel's previous start (0
+    without groups)."""
     density_gb: int
     dt_ns: float
     REFI: int
     REFI_PB: int
+    REFI_SB: int
+    U: int                       # refresh units per cell
     RFC_PB: int
     RFC_AB: int
     TRP: int                     # precharge-to-REF preamble gap
@@ -163,6 +171,7 @@ class TickTiming:
     WR: int
     TURN: int
     RTR: int                     # rank-to-rank bus turnaround
+    CCDL: int                    # same-bank-group column delay adder
     SARP_PEN: int
     budget: int
 
@@ -170,21 +179,28 @@ class TickTiming:
     def from_density(cls, density_gb: int, dt_ns: float = 6.0,
                      n_banks: int = 8, n_subarrays: int = 8,
                      n_ranks: int = 1, n_channels: int = 1) -> "TickTiming":
-        T = timing_for_density(density_gb, n_banks=n_banks,
-                               n_subarrays=n_subarrays, n_ranks=n_ranks,
-                               n_channels=n_channels)
+        """The program's own table (`timing_for_density`), quantized."""
+        return cls.from_timing(timing_for_density(
+            density_gb, n_banks=n_banks, n_subarrays=n_subarrays,
+            n_ranks=n_ranks, n_channels=n_channels), dt_ns)
 
+    @classmethod
+    def from_timing(cls, T: DramTiming, dt_ns: float = 6.0) -> "TickTiming":
         def tk(ns: float) -> int:
             return max(1, int(ns / dt_ns + 0.5))
 
         refi = tk(T.tREFI)
-        return cls(density_gb=density_gb, dt_ns=dt_ns, REFI=refi,
+        U = T.n_refresh_units
+        return cls(density_gb=T.density_gb, dt_ns=dt_ns, REFI=refi,
                    REFI_PB=max(1, refi // T.n_banks_total),
+                   REFI_SB=max(1, refi // U), U=U,
                    RFC_PB=tk(T.tRFC_pb),
                    RFC_AB=tk(T.tRFC_ab), TRP=tk(T.tRP), HIT=tk(T.row_hit),
                    MISS=tk(T.row_miss), WR=tk(T.tWR), TURN=tk(T.tWTR),
-                   RTR=tk(T.tRTR), SARP_PEN=tk(T.sarp_penalty),
-                   budget=T.refresh_budget)
+                   RTR=tk(T.tRTR),
+                   CCDL=(tk(T.tCCD_L) - tk(T.tCCD_S)
+                         if T.n_bank_groups > 1 else 0),
+                   SARP_PEN=tk(T.sarp_penalty), budget=T.refresh_budget)
 
 
 @dataclass(frozen=True)
@@ -218,12 +234,19 @@ class SweepSpec:
     n_subarrays: int = 8
     n_ranks: int = 1             # ranks per channel
     n_channels: int = 1          # independent data buses
+    n_bank_groups: int = 1       # bank groups per rank (divides n_banks)
     wbuf_hi: int = 48            # pending-write drain high watermark
     wbuf_lo: int = 16            # drain low watermark
     wbuf_cap: int = 64           # write-buffer capacity (closed-loop issue
     #                              backpressure; open-loop traces ignore it)
     mode: str = "open"           # 'open' | 'closed'
     horizon: Optional[int] = None   # tick cap; None = auto
+    #: the DRAM at each density; None = the program's own table
+    #: (`timing_for_density`). When given it is the only timing of every
+    #: backend, its layout must be the spec's, and a density it lacks
+    #: raises.
+    timing: Optional[Mapping[int, DramTiming]] = field(default=None,
+                                                       hash=False)
 
     def __post_init__(self):
         object.__setattr__(self, "policies", tuple(self.policies))
@@ -231,6 +254,29 @@ class SweepSpec:
         object.__setattr__(self, "densities", tuple(self.densities))
         if self.mode not in ("open", "closed"):
             raise ValueError(f"unknown sweep mode {self.mode!r}")
+        if self.timing is not None:
+            object.__setattr__(self, "timing", dict(self.timing))
+        for d in self.densities:
+            self.dram(d)
+
+    def dram(self, density: int) -> DramTiming:
+        """The DRAM this spec simulates at `density` Gb."""
+        lay = dict(n_banks=self.n_banks, n_subarrays=self.n_subarrays,
+                   n_ranks=self.n_ranks, n_channels=self.n_channels,
+                   n_bank_groups=self.n_bank_groups)
+        if self.timing is None:
+            return timing_for_density(density, **lay)
+        if density not in self.timing:
+            raise ValueError(f"SweepSpec.timing has no {density} Gb DRAM "
+                             f"(it gives {sorted(self.timing)})")
+        T = self.timing[density]
+        off = {k: (getattr(T, k), v) for k, v in lay.items()
+               if getattr(T, k) != v}
+        if off or T.density_gb != density:
+            raise ValueError(
+                f"SweepSpec.timing[{density}] is not the spec's DRAM: "
+                f"density_gb {T.density_gb}, layout (timing, spec) {off}")
+        return T
 
     @property
     def n_ranks_total(self) -> int:
@@ -392,6 +438,15 @@ class _Grid:
         self.chan_of_b = np.arange(B, dtype=np.int32) // (self.NR * self.NB)
         self.rank_of_t = tuple(int(x) for x in self.rank_of_b)
         self.chan_of_t = tuple(int(x) for x in self.chan_of_b)
+        # refresh units (per-bank-level refresh targets): one per bank, or
+        # with bank groups one same-bank set per (rank, bank of a group)
+        self.NBG = spec.n_bank_groups
+        self.BPG = self.NB // self.NBG       # banks per group
+        self.U = self.R * self.BPG
+        self.units = refresh_units(B, self.NB, self.NBG)
+        self.rank_of_u = tuple(u // self.BPG for u in range(self.U))
+        self.chan_of_u = tuple(u // (self.NR * self.BPG)
+                               for u in range(self.U))
         self.closed = spec.mode == "closed"
 
         split = None
@@ -455,10 +510,8 @@ class _Grid:
                         self.scn_qw[i, b, :n] = isw
             self.n_per_bank = np.zeros((G, B), np.int32)
 
-        self.timing = {d: TickTiming.from_density(
-            d, spec.dt_ns, spec.n_banks, spec.n_subarrays, spec.n_ranks,
-            spec.n_channels)
-            for d in spec.densities}
+        self.timing = {d: TickTiming.from_timing(spec.dram(d), spec.dt_ns)
+                       for d in spec.densities}
 
         # per-cell constants
         ints = lambda: np.zeros(G, np.int32)
@@ -470,9 +523,11 @@ class _Grid:
         self.urgent_at = np.ones(G, np.int32)
         self.budget = ints()
         for f in ("REFI", "RFC_PB", "RFC_AB", "TRP", "HIT", "MISS", "WR",
-                  "TURN", "RTR", "SARP_PEN"):
+                  "TURN", "RTR", "CCDL", "SARP_PEN"):
             setattr(self, f, ints())
-        self.phase = np.zeros((G, B), np.int32)
+        # per-(cell, refresh unit) pb debt phase: unit u's first refresh
+        # comes due u * tREFI/U in
+        self.phase = np.zeros((G, self.U), np.int32)
         # per-(cell, global rank) all-bank debt accrual phase: rank r's
         # debt lands r * tREFI/R after rank 0's (cross-rank staggering)
         self.rank_phase = np.zeros((G, self.R), np.int32)
@@ -529,9 +584,9 @@ class _Grid:
             self.urgent_at[g] = params.get("urgent_at", 1)
             self.budget[g] = tk.budget
             for f in ("REFI", "RFC_PB", "RFC_AB", "TRP", "HIT", "MISS",
-                      "WR", "TURN", "RTR", "SARP_PEN"):
+                      "WR", "TURN", "RTR", "CCDL", "SARP_PEN"):
                 getattr(self, f)[g] = getattr(tk, f)
-            self.phase[g] = np.arange(B, dtype=np.int32) * tk.REFI_PB
+            self.phase[g] = np.arange(self.U, dtype=np.int32) * tk.REFI_SB
             self.rank_phase[g] = (np.arange(self.R, dtype=np.int32)
                                   * (tk.REFI // self.R))
             if kind == KIND_CUSTOM:
@@ -600,6 +655,26 @@ def _scalar_refreshing_sub(ru_subs, t: int) -> int:
     return mid[0] if len(mid) == 1 else -1
 
 
+def _unit_lists(units, *, demand, ready, idle, next_sub, refreshing,
+                active) -> dict:
+    """The per-bank signals of a pb `MaintenanceView`, one bank's lists,
+    over refresh units (`refresh_units`): a same-bank set is ready and
+    idle when every bank of it is, and its demand is theirs summed. Its
+    banks start and end every refresh together, so its next and
+    refreshing subarrays are those of its first bank; its active
+    subarray is the one all its banks share, else -1. Without bank
+    groups a unit is one bank and the lists are unchanged."""
+    return dict(
+        demand=[sum(demand[b] for b in us) for us in units],
+        ready=[all(ready[b] for b in us) for us in units],
+        idle=[all(idle[b] for b in us) for us in units],
+        next_ref_sub=tuple(next_sub[us[0]] for us in units),
+        refreshing_sub=tuple(refreshing[us[0]] for us in units),
+        active_sub=tuple(active[us[0]] if all(active[b] == active[us[0]]
+                                              for b in us) else -1
+                         for us in units))
+
+
 def _p99_ticks(hist_row: np.ndarray, n_reads: int) -> int:
     if n_reads <= 0:
         return 0
@@ -621,9 +696,7 @@ def _finalize(grid: _Grid, g: int, *, reads, writes, hits, misses, refpb,
     from repro.core.refresh.sim import energy_proxy
     p, s, d = grid.cells[g]
     spec = grid.spec
-    T = timing_for_density(d, n_banks=spec.n_banks,
-                           n_subarrays=spec.n_subarrays,
-                           n_ranks=spec.n_ranks, n_channels=spec.n_channels)
+    T = spec.dram(d)
     dt = spec.dt_ns
     if core_finish is None:
         mode, cf = "open", ()
@@ -653,7 +726,7 @@ def _finalize(grid: _Grid, g: int, *, reads, writes, hits, misses, refpb,
 def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
     spec = grid.spec
     G, B, L, S = grid.G, grid.B, grid.L, grid.S
-    NB, R, NC = grid.NB, grid.R, grid.NC
+    NB, R, NC, NBG = grid.NB, grid.R, grid.NC, grid.NBG
     RBC = grid.NR * NB               # banks per channel
     HI, LO = spec.wbuf_hi, spec.wbuf_lo
 
@@ -678,7 +751,7 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
     open_row_s = np.full((G, B * S), -1, np.int32)
     open_sub = np.full((G, B), -1, np.int32)
     ctr = np.zeros((G, B), np.int32)
-    issued = np.zeros((G, B), np.int32)
+    issued = np.zeros((G, grid.U), np.int32)     # per refresh unit
     n_arrived = np.zeros((G, B), np.int32)
     n_served = np.zeros((G, B), np.int32)
     rr = np.zeros(G, np.int32)
@@ -687,6 +760,7 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
     drain = np.zeros(G, bool)
     last_op = np.zeros((G, NC), bool)      # per-channel bus turnaround
     last_rank = np.full((G, NC), -1, np.int32)
+    last_bg = np.full((G, NC), -1, np.int32)    # bank group, last start
     ab_pending = np.zeros((G, R), np.int32)
     rank_drain = np.zeros((G, R), bool)
     active = grid.n_tot > 0
@@ -767,15 +841,20 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
         demand = n_arrived - n_served
         ready = (ref_until_s.reshape(G, B, S) <= t).all(axis=2)
         idle = bank_free <= t
-        need = could_pick(kind=kind_active, lag=lag, demand=demand,
+        # the pb policies see refresh units: per_unit is the identity
+        # without bank groups
+        demand_u = per_unit(demand, "sum", R, NBG)
+        ready_u = per_unit(ready, "all", R, NBG)
+        idle_u = per_unit(idle, "all", R, NBG)
+        need = could_pick(kind=kind_active, lag=lag, demand=demand_u,
                           write_window=drain, budget=budget_g, wrp=wrp_g)
         picks = None
         if need.any():
             picks, rr = select_batch(
                 np, kind=np.where(need, kind_active, KIND_IDEAL), lag=lag,
-                ready=ready, idle=idle, demand=demand, write_window=drain,
-                budget=budget_g, wrp=wrp_g, urgent_at=urgent_g, rr=rr,
-                gate=True, nb=NB)
+                ready=ready_u, idle=idle_u, demand=demand_u,
+                write_window=drain, budget=budget_g, wrp=wrp_g,
+                urgent_at=urgent_g, rr=rr, gate=True, nb=grid.BPG)
             if not picks.any():
                 picks = None
 
@@ -833,24 +912,25 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
                             start_ab_r[g] |= ab_pending[g] > 0
             else:
                 view = MaintenanceView(
-                    now=float(t), n_banks=B, budget=int(grid.budget[g]),
-                    lag=lag[g].tolist(), demand=demand[g].tolist(),
-                    ready=ready[g].tolist(), idle=idle[g].tolist(),
+                    now=float(t), n_banks=grid.U,
+                    budget=int(grid.budget[g]), lag=lag[g].tolist(),
                     write_window=bool(drain[g]), max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
-                    rank_of=grid.rank_of_t, channel_of=grid.chan_of_t,
-                    n_subarrays=S,
-                    next_ref_sub=tuple(int(x) % S for x in ctr[g]),
-                    refreshing_sub=_refreshing_subs(
-                        ref_until_s[g].reshape(B, S), t),
-                    active_sub=tuple(int(x) for x in open_sub[g]))
+                    rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
+                    n_subarrays=S, **_unit_lists(
+                        grid.units, demand=demand[g].tolist(),
+                        ready=ready[g].tolist(), idle=idle[g].tolist(),
+                        next_sub=[int(x) % S for x in ctr[g]],
+                        refreshing=_refreshing_subs(
+                            ref_until_s[g].reshape(B, S), t),
+                        active=[int(x) for x in open_sub[g]]))
                 for dec in pol.select(view):
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
                             f"policy {pol.name!r} returned ALL_BANKS from "
                             f"a per-bank (level='pb') decision point")
                     if picks is None:
-                        picks = np.zeros((G, B), bool)
+                        picks = np.zeros((G, grid.U), bool)
                     picks[g, dec.bank] = True
 
         if start_ab_r is not None and start_ab_r.any():
@@ -870,6 +950,7 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
             refab += start_ab_r.sum(axis=1)
 
         if picks is not None:
+            picks_b = per_bank(np, picks, R, NBG)
             new_sub = (ctr % S).astype(np.int32)
             # HiRA hidden row activation: when the refresh targets a
             # subarray the in-flight access is NOT using, start it at t —
@@ -878,13 +959,15 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
             # access has been served, and bank_free <= t before then)
             start = np.maximum(t, bank_free)
             start = np.where(hra_c & (new_sub != open_sub), t, start)
-            mark = (np.repeat(picks, S, axis=1)
+            # a same-bank set starts once every bank of it can
+            start = per_bank(np, per_unit(start, "max", R, NBG), R, NBG)
+            mark = (np.repeat(picks_b, S, axis=1)
                     & np.where(sarp_c, np.repeat(new_sub, S, axis=1)
                                == sub_of_col, True))
             ref_until_s = np.where(
                 mark, np.repeat(start + RFC_PB_col, S, axis=1), ref_until_s)
             open_row_s = np.where(mark, -1, open_row_s)
-            ctr = ctr + picks
+            ctr = ctr + picks_b
             issued = issued + picks
             refpb += picks.sum(axis=1)
             lag_after = due - issued
@@ -936,10 +1019,13 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
             gr_b = bs // NB
             lr = last_rank[gs, ch]
             lat = lat + np.where((lr >= 0) & (lr != gr_b), grid.RTR[gs], 0)
+            bg_b = bs // grid.BPG
+            lat = lat + np.where(last_bg[gs, ch] == bg_b, grid.CCDL[gs], 0)
             done = t + lat
             bank_free[gs, bs] = done + np.where(isw, grid.WR[gs], 0)
             last_op[gs, ch] = isw
             last_rank[gs, ch] = gr_b
+            last_bg[gs, ch] = bg_b
             open_row_s[gs, bs * S + sub] = row
             open_sub[gs, bs] = sub
             n_served[gs, bs] += 1
@@ -995,7 +1081,7 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
     loop is untouched otherwise."""
     spec = grid.spec
     G, B, S = grid.G, grid.B, grid.S
-    NB, R, NC = grid.NB, grid.R, grid.NC
+    NB, R, NC, NBG = grid.NB, grid.R, grid.NC, grid.NBG
     RBC = grid.NR * NB               # banks per channel
     C, N, K = grid.C, grid.N, grid.K
     LQ = grid.LQ
@@ -1007,12 +1093,8 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
         from repro.core.commands.trace import CmdRecorder, tick_meta
         recs = []
         for (p, s, d) in grid.cells:
-            T = timing_for_density(d, n_banks=spec.n_banks,
-                                   n_subarrays=spec.n_subarrays,
-                                   n_ranks=spec.n_ranks,
-                                   n_channels=spec.n_channels)
             recs.append(CmdRecorder(tick_meta(
-                T, resolve_policy(p), spec.dt_ns,
+                spec.dram(d), resolve_policy(p), spec.dt_ns,
                 scenario=_scenario_name(s),
                 wbuf=(spec.wbuf_cap, spec.wbuf_hi, spec.wbuf_lo))))
 
@@ -1056,13 +1138,14 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
     open_row_s = np.full((G, B * S), -1, np.int32)
     open_sub = np.full((G, B), -1, np.int32)
     ctr = np.zeros((G, B), np.int32)
-    issued = np.zeros((G, B), np.int32)
+    issued = np.zeros((G, grid.U), np.int32)     # per refresh unit
     rr = np.zeros(G, np.int32)
     ab_rr = np.zeros(G, np.int32)          # staggered_ab rank pointer
     wpend = np.zeros(G, np.int32)
     drain = np.zeros(G, bool)
     last_op = np.zeros((G, NC), bool)      # per-channel bus turnaround
     last_rank = np.full((G, NC), -1, np.int32)
+    last_bg = np.full((G, NC), -1, np.int32)    # bank group, last start
     ab_pending = np.zeros((G, R), np.int32)
     rank_drain = np.zeros((G, R), bool)
     active = (remaining > 0).any(axis=1)
@@ -1173,15 +1256,20 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
         demand = q_tail - q_head
         ready = (ref_until_s.reshape(G, B, S) <= t).all(axis=2)
         idle = bank_free <= t
-        need = could_pick(kind=kind_active, lag=lag, demand=demand,
+        # the pb policies see refresh units: per_unit is the identity
+        # without bank groups
+        demand_u = per_unit(demand, "sum", R, NBG)
+        ready_u = per_unit(ready, "all", R, NBG)
+        idle_u = per_unit(idle, "all", R, NBG)
+        need = could_pick(kind=kind_active, lag=lag, demand=demand_u,
                           write_window=drain, budget=budget_g, wrp=wrp_g)
         picks = None
         if need.any():
             picks, rr = select_batch(
                 np, kind=np.where(need, kind_active, KIND_IDEAL), lag=lag,
-                ready=ready, idle=idle, demand=demand, write_window=drain,
-                budget=budget_g, wrp=wrp_g, urgent_at=urgent_g, rr=rr,
-                gate=True, nb=NB)
+                ready=ready_u, idle=idle_u, demand=demand_u,
+                write_window=drain, budget=budget_g, wrp=wrp_g,
+                urgent_at=urgent_g, rr=rr, gate=True, nb=grid.BPG)
             if not picks.any():
                 picks = None
 
@@ -1239,24 +1327,25 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
                             start_ab_r[g] |= ab_pending[g] > 0
             else:
                 view = MaintenanceView(
-                    now=float(t), n_banks=B, budget=int(grid.budget[g]),
-                    lag=lag[g].tolist(), demand=demand[g].tolist(),
-                    ready=ready[g].tolist(), idle=idle[g].tolist(),
+                    now=float(t), n_banks=grid.U,
+                    budget=int(grid.budget[g]), lag=lag[g].tolist(),
                     write_window=bool(drain[g]), max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
-                    rank_of=grid.rank_of_t, channel_of=grid.chan_of_t,
-                    n_subarrays=S,
-                    next_ref_sub=tuple(int(x) % S for x in ctr[g]),
-                    refreshing_sub=_refreshing_subs(
-                        ref_until_s[g].reshape(B, S), t),
-                    active_sub=tuple(int(x) for x in open_sub[g]))
+                    rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
+                    n_subarrays=S, **_unit_lists(
+                        grid.units, demand=demand[g].tolist(),
+                        ready=ready[g].tolist(), idle=idle[g].tolist(),
+                        next_sub=[int(x) % S for x in ctr[g]],
+                        refreshing=_refreshing_subs(
+                            ref_until_s[g].reshape(B, S), t),
+                        active=[int(x) for x in open_sub[g]]))
                 for dec in pol.select(view):
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
                             f"policy {pol.name!r} returned ALL_BANKS from "
                             f"a per-bank (level='pb') decision point")
                     if picks is None:
-                        picks = np.zeros((G, B), bool)
+                        picks = np.zeros((G, grid.U), bool)
                     picks[g, dec.bank] = True
 
         if start_ab_r is not None and start_ab_r.any():
@@ -1281,6 +1370,7 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
                                        int(r_), data=t)
 
         if picks is not None:
+            picks_b = per_bank(np, picks, R, NBG)
             new_sub = (ctr % S).astype(np.int32)
             # HiRA hidden row activation: when the refresh targets a
             # subarray the in-flight access is NOT using, start it at t —
@@ -1289,20 +1379,27 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
             # access has been served, and bank_free <= t before then)
             start = np.maximum(t, bank_free)
             start = np.where(hra_c & (new_sub != open_sub), t, start)
+            # a same-bank set starts once every bank of it can
+            start = per_bank(np, per_unit(start, "max", R, NBG), R, NBG)
             if recs is not None:
-                for g_, b_ in zip(*np.nonzero(picks)):
-                    st = int(start[g_, b_])
-                    tsub = int(new_sub[g_, b_]) if grid.sarp[g_] else -1
-                    recs[g_].emit(st, "PRE", int(b_), sub=tsub)
-                    recs[g_].emit(st + int(grid.TRP[g_]), "REF_PB",
-                                  int(b_), sub=tsub, data=t)
-            mark = (np.repeat(picks, S, axis=1)
+                # a same-bank set: PRE on each bank, one REF_SB naming
+                # its first bank (bank k of group 0)
+                ref_op = "REF_PB" if NBG == 1 else "REF_SB"
+                for g_, u_ in zip(*np.nonzero(picks)):
+                    us = grid.units[u_]
+                    st = int(start[g_, us[0]])
+                    tsub = int(new_sub[g_, us[0]]) if grid.sarp[g_] else -1
+                    for b_ in us:
+                        recs[g_].emit(st, "PRE", b_, sub=tsub)
+                    recs[g_].emit(st + int(grid.TRP[g_]), ref_op, us[0],
+                                  sub=tsub, data=t)
+            mark = (np.repeat(picks_b, S, axis=1)
                     & np.where(sarp_c, np.repeat(new_sub, S, axis=1)
                                == sub_of_col, True))
             ref_until_s = np.where(
                 mark, np.repeat(start + RFC_PB_col, S, axis=1), ref_until_s)
             open_row_s = np.where(mark, -1, open_row_s)
-            ctr = ctr + picks
+            ctr = ctr + picks_b
             issued = issued + picks
             refpb += picks.sum(axis=1)
             lag_after = due - issued
@@ -1359,6 +1456,8 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
             gr_b = bs // NB
             lr = last_rank[gs, ch]
             lat = lat + np.where((lr >= 0) & (lr != gr_b), grid.RTR[gs], 0)
+            bg_b = bs // grid.BPG
+            lat = lat + np.where(last_bg[gs, ch] == bg_b, grid.CCDL[gs], 0)
             done = t + lat
             if recs is not None:
                 oldr = head_or[gs, bs]
@@ -1374,6 +1473,7 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
             bank_free[gs, bs] = done + np.where(isw, grid.WR[gs], 0)
             last_op[gs, ch] = isw
             last_rank[gs, ch] = gr_b
+            last_bg[gs, ch] = bg_b
             open_row_s[gs, bs * S + sub] = row
             open_sub[gs, bs] = sub
             q_head[gs, bs] += 1
@@ -1433,7 +1533,7 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
                           grid.q_sub[g, b, :n].tolist(),
                           grid.q_write[g, b, :n].tolist())))
     total = sum(len(x) for x in q)
-    phase = [b * tk.REFI_PB for b in range(B)]
+    phase = [u * tk.REFI_SB for u in range(grid.U)]
     rank_phase = [gr * (tk.REFI // R) for gr in range(R)]
 
     bank_free = [0] * B
@@ -1441,13 +1541,14 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
     open_row_s = [[-1] * S for _ in range(B)]
     open_sub = [-1] * B
     ctr = [0] * B
-    issued = [0] * B
+    issued = [0] * grid.U                 # per refresh unit
     n_arrived = [0] * B
     n_served = [0] * B
     wpend = 0
     drain = False
     last_op = [False] * NC
     last_rank = [-1] * NC
+    last_bg = [-1] * NC
     ab_pending = [0] * R
     rank_drain = [False] * R
     served = 0
@@ -1458,27 +1559,31 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
     maxlag = 0
     last_done = 0
 
-    def due(b: int, t: int) -> int:
-        return 0 if t < phase[b] else (t - phase[b]) // tk.REFI + 1
+    def due(u: int, t: int) -> int:
+        return 0 if t < phase[u] else (t - phase[u]) // tk.REFI + 1
 
-    def start_pb(b: int, t: int):
+    def start_pb(u: int, t: int):
         nonlocal refpb, maxlag
-        ns = ctr[b] % S
+        us = grid.units[u]
         # HiRA: hide the refresh activation behind an in-flight access to
-        # a different subarray (start at t instead of waiting for the bank)
-        start = t if (hra and ns != open_sub[b]) else max(t, bank_free[b])
+        # a different subarray (start at t instead of waiting for the
+        # bank); a same-bank set starts once every bank of it can
+        start = max(t if (hra and ctr[b] % S != open_sub[b])
+                    else max(t, bank_free[b]) for b in us)
         end = start + tk.RFC_PB
-        if pol.sarp:
-            ref_until_s[b][ns] = end
-            open_row_s[b][ns] = -1
-        else:
-            for s_ in range(S):
-                ref_until_s[b][s_] = end
-                open_row_s[b][s_] = -1
-        ctr[b] += 1
-        issued[b] += 1
+        for b in us:
+            ns = ctr[b] % S
+            if pol.sarp:
+                ref_until_s[b][ns] = end
+                open_row_s[b][ns] = -1
+            else:
+                for s_ in range(S):
+                    ref_until_s[b][s_] = end
+                    open_row_s[b][s_] = -1
+            ctr[b] += 1
+        issued[u] += 1
         refpb += 1
-        maxlag = max(maxlag, abs(due(b, t) - issued[b]))
+        maxlag = max(maxlag, abs(due(u, t) - issued[u]))
 
     def start_ab(gr: int, t: int):
         nonlocal refab
@@ -1557,21 +1662,22 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
                     apply_ab_decisions(pol.select(ab_view(t)), t)
             else:
                 view = MaintenanceView(
-                    now=float(t), n_banks=B, budget=budget,
-                    lag=[due(b, t) - issued[b] for b in range(B)],
-                    demand=[n_arrived[b] - n_served[b] for b in range(B)],
-                    ready=[all(ru <= t for ru in ref_until_s[b])
-                           for b in range(B)],
-                    idle=[bank_free[b] <= t for b in range(B)],
+                    now=float(t), n_banks=grid.U, budget=budget,
+                    lag=[due(u, t) - issued[u] for u in range(grid.U)],
                     write_window=drain, max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
-                    rank_of=grid.rank_of_t, channel_of=grid.chan_of_t,
-                    n_subarrays=S,
-                    next_ref_sub=tuple(ctr[b] % S for b in range(B)),
-                    refreshing_sub=tuple(
-                        _scalar_refreshing_sub(ref_until_s[b], t)
-                        for b in range(B)),
-                    active_sub=tuple(open_sub))
+                    rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
+                    n_subarrays=S, **_unit_lists(
+                        grid.units,
+                        demand=[n_arrived[b] - n_served[b]
+                                for b in range(B)],
+                        ready=[all(ru <= t for ru in ref_until_s[b])
+                               for b in range(B)],
+                        idle=[bank_free[b] <= t for b in range(B)],
+                        next_sub=[ctr[b] % S for b in range(B)],
+                        refreshing=[_scalar_refreshing_sub(ref_until_s[b], t)
+                                    for b in range(B)],
+                        active=open_sub))
                 for dec in pol.select(view):
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
@@ -1611,10 +1717,13 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
                     lat += tk.TURN
                 if 0 <= last_rank[ch] != gr:
                     lat += tk.RTR
+                if last_bg[ch] == b // grid.BPG:
+                    lat += tk.CCDL       # same bank group: tCCD_L
                 done = t + lat
                 bank_free[b] = done + (tk.WR if isw else 0)
                 last_op[ch] = isw
                 last_rank[ch] = gr
+                last_bg[ch] = b // grid.BPG
                 open_row_s[b][sub] = row
                 open_sub[b] = sub
                 n_served[b] += 1
@@ -1661,7 +1770,7 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
     sb, sr = grid.s_bank[g], grid.s_row[g]
     ss, sth = grid.s_sub[g], grid.s_think[g]
     n_req = grid.n_req_c[g].tolist()
-    phase = [b * tk.REFI_PB for b in range(B)]
+    phase = [u * tk.REFI_SB for u in range(grid.U)]
     rank_phase = [gr * (tk.REFI // R) for gr in range(R)]
 
     # per-bank FIFO of (issue_tick, row, sub, is_write, core)
@@ -1679,11 +1788,12 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
     open_row_s = [[-1] * S for _ in range(B)]
     open_sub = [-1] * B
     ctr = [0] * B
-    issued = [0] * B
+    issued = [0] * grid.U                 # per refresh unit
     wpend = 0
     drain = False
     last_op = [False] * NC
     last_rank = [-1] * NC
+    last_bg = [-1] * NC
     ab_pending = [0] * R
     rank_drain = [False] * R
 
@@ -1693,27 +1803,31 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
     maxlag = 0
     last_done = 0
 
-    def due(b: int, t: int) -> int:
-        return 0 if t < phase[b] else (t - phase[b]) // tk.REFI + 1
+    def due(u: int, t: int) -> int:
+        return 0 if t < phase[u] else (t - phase[u]) // tk.REFI + 1
 
-    def start_pb(b: int, t: int):
+    def start_pb(u: int, t: int):
         nonlocal refpb, maxlag
-        ns = ctr[b] % S
+        us = grid.units[u]
         # HiRA: hide the refresh activation behind an in-flight access to
-        # a different subarray (start at t instead of waiting for the bank)
-        start = t if (hra and ns != open_sub[b]) else max(t, bank_free[b])
+        # a different subarray (start at t instead of waiting for the
+        # bank); a same-bank set starts once every bank of it can
+        start = max(t if (hra and ctr[b] % S != open_sub[b])
+                    else max(t, bank_free[b]) for b in us)
         end = start + tk.RFC_PB
-        if pol.sarp:
-            ref_until_s[b][ns] = end
-            open_row_s[b][ns] = -1
-        else:
-            for s_ in range(S):
-                ref_until_s[b][s_] = end
-                open_row_s[b][s_] = -1
-        ctr[b] += 1
-        issued[b] += 1
+        for b in us:
+            ns = ctr[b] % S
+            if pol.sarp:
+                ref_until_s[b][ns] = end
+                open_row_s[b][ns] = -1
+            else:
+                for s_ in range(S):
+                    ref_until_s[b][s_] = end
+                    open_row_s[b][s_] = -1
+            ctr[b] += 1
+        issued[u] += 1
         refpb += 1
-        maxlag = max(maxlag, abs(due(b, t) - issued[b]))
+        maxlag = max(maxlag, abs(due(u, t) - issued[u]))
 
     def start_ab(gr: int, t: int):
         nonlocal refab
@@ -1823,21 +1937,20 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
                     apply_ab_decisions(pol.select(ab_view(t)), t)
             else:
                 view = MaintenanceView(
-                    now=float(t), n_banks=B, budget=budget,
-                    lag=[due(b, t) - issued[b] for b in range(B)],
-                    demand=[len(q[b]) for b in range(B)],
-                    ready=[all(ru <= t for ru in ref_until_s[b])
-                           for b in range(B)],
-                    idle=[bank_free[b] <= t for b in range(B)],
+                    now=float(t), n_banks=grid.U, budget=budget,
+                    lag=[due(u, t) - issued[u] for u in range(grid.U)],
                     write_window=drain, max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
-                    rank_of=grid.rank_of_t, channel_of=grid.chan_of_t,
-                    n_subarrays=S,
-                    next_ref_sub=tuple(ctr[b] % S for b in range(B)),
-                    refreshing_sub=tuple(
-                        _scalar_refreshing_sub(ref_until_s[b], t)
-                        for b in range(B)),
-                    active_sub=tuple(open_sub))
+                    rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
+                    n_subarrays=S, **_unit_lists(
+                        grid.units, demand=[len(q[b]) for b in range(B)],
+                        ready=[all(ru <= t for ru in ref_until_s[b])
+                               for b in range(B)],
+                        idle=[bank_free[b] <= t for b in range(B)],
+                        next_sub=[ctr[b] % S for b in range(B)],
+                        refreshing=[_scalar_refreshing_sub(ref_until_s[b], t)
+                                    for b in range(B)],
+                        active=open_sub))
                 for dec in pol.select(view):
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
@@ -1879,10 +1992,13 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
                     lat += tk.TURN
                 if 0 <= last_rank[ch] != gr:
                     lat += tk.RTR
+                if last_bg[ch] == b // grid.BPG:
+                    lat += tk.CCDL       # same bank group: tCCD_L
                 done = t + lat
                 bank_free[b] = done + (tk.WR if isw else 0)
                 last_op[ch] = isw
                 last_rank[ch] = gr
+                last_bg[ch] = b // grid.BPG
                 open_row_s[b][sub] = row
                 open_sub[b] = sub
                 if hit:
@@ -1967,9 +2083,10 @@ def _run_jax(spec: SweepSpec, arbiter: str = "jnp") -> list[CellResult]:
     the device, initial state), ``sweep.tick_loop`` (the loop's dispatch
     until it has finished on the device), ``sweep.readback``
     (`jax.device_get`) and ``sweep.finalize`` (the `CellResult`s), the
-    last carrying the counters ``cells`` and ``loop_iterations`` (the
-    times the while loop ran). Without an active profiler a span costs
-    one inactive `TraceMe`."""
+    last carrying the counters ``cells``, ``loop_iterations`` (the times
+    the while loop ran), ``refresh_units`` (per cell: banks, or same-bank
+    sets with bank groups) and ``bank_groups`` (per rank). Without an
+    active profiler a span costs one inactive `TraceMe`."""
     import jax
     from jax.profiler import TraceAnnotation
 
@@ -1986,7 +2103,8 @@ def _run_jax(spec: SweepSpec, arbiter: str = "jnp") -> list[CellResult]:
     with TraceAnnotation("sweep.readback"):
         out = jax.device_get(out)
     with TraceAnnotation("sweep.finalize", cells=grid.G,
-                         loop_iterations=int(out["t"])):
+                         loop_iterations=int(out["t"]),
+                         refresh_units=grid.U, bank_groups=grid.NBG):
         if grid.closed:
             finished = (out["remaining"] <= 0).all(axis=1)
             fin = np.where(out["finish"] < 0, int(out["t"]), out["finish"])
@@ -2015,6 +2133,11 @@ def _run_mega(grid: _Grid, n_shards: int = 1) -> list[CellResult]:
     axis optionally sharded across devices (`n_shards`). Bit-identical
     to every other backend by construction."""
     _check_jax_guards(grid, backend="mega")
+    if grid.NBG > 1:
+        raise ValueError(
+            f"backend='mega' does not model bank groups (n_bank_groups="
+            f"{grid.NBG}): its packed parameter table has no same-bank "
+            "refresh or tCCD_L; use backend='jax' or 'batched'")
     from repro.kernels.sweep_megakernel import run_mega
 
     out = run_mega(grid, n_shards=n_shards)

@@ -42,7 +42,7 @@ from jax import lax
 
 from repro.core.sweep.engine import MAX_LAT_TICKS, _PAD_ARRIVE
 from repro.core.sweep.policies import (KIND_AB, KIND_IDEAL, KIND_STAG,
-                                       select_batch)
+                                       per_bank, per_unit, select_batch)
 
 
 # ------------------------------------------------------------------ config
@@ -70,6 +70,18 @@ class TickCfg:
     K: int = 0              # closed: MLP window slots
     LQ: int = 0             # closed: ring-queue capacity (power of two)
     CAP: int = 0            # closed: shared write-buffer capacity
+    NBG: int = 1            # bank groups per rank
+
+    @property
+    def BPG(self) -> int:
+        """Banks per bank group."""
+        return self.NB // self.NBG
+
+    @property
+    def U(self) -> int:
+        """Refresh units per cell: banks, or same-bank sets with bank
+        groups (`policies.per_unit`)."""
+        return self.R * self.BPG
 
 
 def open_cfg(grid) -> TickCfg:
@@ -77,7 +89,7 @@ def open_cfg(grid) -> TickCfg:
     return TickCfg(closed=False, B=grid.B, S=grid.S, NB=grid.NB,
                    NR=grid.NR, R=grid.R, NC=grid.NC, HI=spec.wbuf_hi,
                    LO=spec.wbuf_lo, has_stag=grid.has_stag,
-                   has_hra=grid.has_hra, L=grid.L)
+                   has_hra=grid.has_hra, L=grid.L, NBG=grid.NBG)
 
 
 def closed_cfg(grid) -> TickCfg:
@@ -86,7 +98,7 @@ def closed_cfg(grid) -> TickCfg:
                    NR=grid.NR, R=grid.R, NC=grid.NC, HI=spec.wbuf_hi,
                    LO=spec.wbuf_lo, has_stag=grid.has_stag,
                    has_hra=grid.has_hra, C=grid.C, N=grid.N, K=grid.K,
-                   LQ=grid.LQ, CAP=spec.wbuf_cap)
+                   LQ=grid.LQ, CAP=spec.wbuf_cap, NBG=grid.NBG)
 
 
 # ------------------------------------------------------------------ consts
@@ -96,7 +108,8 @@ def _j32(x):
 
 def _shared_consts(grid) -> dict:
     """Per-cell constant planes common to both modes (all [G] int32/bool
-    except the staggered refresh phases and the shared scalar horizon)."""
+    except the staggered refresh phases, [G, R] and [G, U], and the
+    shared scalar horizon); the tCCD_L term only with bank groups."""
     return dict(
         phase=_j32(grid.phase), rank_phase=_j32(grid.rank_phase),
         kind=_j32(grid.kind), level_ab=jnp.asarray(grid.level_ab),
@@ -107,7 +120,8 @@ def _shared_consts(grid) -> dict:
         RFC_AB=_j32(grid.RFC_AB), HIT=_j32(grid.HIT),
         MISS=_j32(grid.MISS), WR=_j32(grid.WR), TURN=_j32(grid.TURN),
         RTR=_j32(grid.RTR), SARP_PEN=_j32(grid.SARP_PEN),
-        horizon=jnp.int32(grid.horizon))
+        horizon=jnp.int32(grid.horizon),
+        **({"CCDL": _j32(grid.CCDL)} if grid.NBG > 1 else {}))
 
 
 def open_consts(grid) -> dict:
@@ -168,7 +182,7 @@ def open_state0(cfg: TickCfg, cst: dict) -> dict:
         open_row_s=jnp.full((G, B * S), -1, jnp.int32),
         open_sub=jnp.full((G, B), -1, jnp.int32),
         ctr=jnp.zeros((G, B), jnp.int32),
-        issued=jnp.zeros((G, B), jnp.int32),
+        issued=jnp.zeros((G, cfg.U), jnp.int32),
         n_arrived=jnp.zeros((G, B), jnp.int32),
         n_served=jnp.zeros((G, B), jnp.int32),
         rr=jnp.zeros(G, jnp.int32),
@@ -176,7 +190,10 @@ def open_state0(cfg: TickCfg, cst: dict) -> dict:
         wpend=jnp.zeros(G, jnp.int32),
         drain=jnp.zeros(G, bool),
         last_op=jnp.zeros((G, cfg.NC), bool),
-        last_rank=jnp.full((G, cfg.NC), -1, jnp.int32),
+        # bank group (gb // BPG, over all ranks) of each channel's last
+        # start: its rank is last_bg // NBG, so without groups it is the
+        # last start's rank
+        last_bg=jnp.full((G, cfg.NC), -1, jnp.int32),
         ab_pending=jnp.zeros((G, cfg.R), jnp.int32),
         rank_drain=jnp.zeros((G, cfg.R), bool),
         next_arrive=jnp.where(live, qa0, _PAD_ARRIVE),
@@ -227,13 +244,16 @@ def closed_state0(cfg: TickCfg, cst: dict) -> dict:
         open_row_s=jnp.full((G, B * S), -1, jnp.int32),
         open_sub=jnp.full((G, B), -1, jnp.int32),
         ctr=jnp.zeros((G, B), jnp.int32),
-        issued=jnp.zeros((G, B), jnp.int32),
+        issued=jnp.zeros((G, cfg.U), jnp.int32),
         rr=jnp.zeros(G, jnp.int32),
         ab_rr=jnp.zeros(G, jnp.int32),
         wpend=jnp.zeros(G, jnp.int32),
         drain=jnp.zeros(G, bool),
         last_op=jnp.zeros((G, cfg.NC), bool),
-        last_rank=jnp.full((G, cfg.NC), -1, jnp.int32),
+        # bank group (gb // BPG, over all ranks) of each channel's last
+        # start: its rank is last_bg // NBG, so without groups it is the
+        # last start's rank
+        last_bg=jnp.full((G, cfg.NC), -1, jnp.int32),
         ab_pending=jnp.zeros((G, cfg.R), jnp.int32),
         rank_drain=jnp.zeros((G, cfg.R), bool),
         # stats
@@ -331,9 +351,11 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         demand = n_arrived - n_served
         picks, rr = select_batch(
             jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
-            ready=ready, idle=idle, demand=demand, write_window=drain,
+            ready=per_unit(ready, "all", R, cfg.NBG),
+            idle=per_unit(idle, "all", R, cfg.NBG),
+            demand=per_unit(demand, "sum", R, cfg.NBG), write_window=drain,
             budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
-            nb=NB)
+            nb=cfg.BPG)
 
         quiet_r = (idle.reshape(G, R, NB).all(axis=2)
                    & ready.reshape(G, R, NB).all(axis=2))
@@ -379,14 +401,19 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
             # grids without the trait keep this out of the traced graph)
             start = jnp.where(hra[:, None] & (new_sub != open_sub), t,
                               start)
-        mark = (jnp.repeat(picks, S, axis=1)
+        # a same-bank set starts once every bank of it can (per_unit and
+        # per_bank are the identity without bank groups)
+        start = per_bank(jnp, per_unit(start, "max", R, cfg.NBG), R,
+                         cfg.NBG)
+        picks_b = per_bank(jnp, picks, R, cfg.NBG)
+        mark = (jnp.repeat(picks_b, S, axis=1)
                 & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
                             == sub_of_col, True))
         ref_until_s = jnp.where(
             mark, jnp.repeat(start + RFC_PB[:, None], S, axis=1),
             ref_until_s)
         open_row_s = jnp.where(mark, -1, open_row_s)
-        ctr = ctr + picks
+        ctr = ctr + picks_b
         issued = issued + picks
         refpb = s["refpb"] + picks.sum(axis=1)
         maxlag = jnp.maximum(
@@ -410,7 +437,7 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
     with jax.named_scope("tick.serve"):
         h_arr_s, h_row_s = s["h_arr"], s["h_row"]
         h_sub_s, h_w_s = s["h_sub"], s["h_w"]
-        last_op, last_rank = s["last_op"], s["last_rank"]
+        last_op, last_bg = s["last_op"], s["last_bg"]
         reads, writes = s["reads"], s["writes"]
         hits_s, misses_s = s["hits"], s["misses"]
         lat_sum, hist = s["lat_sum"], s["hist"]
@@ -423,20 +450,24 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
             arr, isw = h_arr_s[arG, bs], h_w_s[arG, bs]
             hit = row == head_or[arG, bs]
             gr_b = bs // NB
-            lr = last_rank[:, ch]
+            bg_b = gr_b if cfg.NBG == 1 else bs // cfg.BPG
+            lbg = last_bg[:, ch]
+            lr = lbg if cfg.NBG == 1 else lbg // cfg.NBG
             lat = (jnp.where(hit, HIT, MISS)
                    + jnp.where(sarp & bank_mid[arG, bs],
                                SARP_PEN, 0)
                    + jnp.where(isw != last_op[:, ch], TURN, 0)
                    + jnp.where((lr >= 0) & (lr != gr_b), RTR, 0))
+            if cfg.NBG > 1:
+                # a start in its channel's last bank group: tCCD_L
+                lat = lat + jnp.where(lbg == bg_b, cst["CCDL"], 0)
             done = t + lat
             bank_free = bank_free.at[arG, bs].set(
                 jnp.where(ok, done + jnp.where(isw, WR, 0),
                           bank_free[arG, bs]))
             last_op = last_op.at[:, ch].set(
                 jnp.where(ok, isw, last_op[:, ch]))
-            last_rank = last_rank.at[:, ch].set(
-                jnp.where(ok, gr_b, last_rank[:, ch]))
+            last_bg = last_bg.at[:, ch].set(jnp.where(ok, bg_b, lbg))
             gsub = bs * S + sub_
             open_row_s = open_row_s.at[arG, gsub].set(
                 jnp.where(ok, row, open_row_s[arG, gsub]))
@@ -472,7 +503,7 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         open_row_s=open_row_s, open_sub=open_sub,
         ctr=ctr, issued=issued, n_arrived=n_arrived,
         n_served=n_served, rr=rr, ab_rr=ab_rr, wpend=wpend,
-        drain=drain, last_op=last_op, last_rank=last_rank,
+        drain=drain, last_op=last_op, last_bg=last_bg,
         ab_pending=ab_pending, rank_drain=rank_drain,
         next_arrive=sub["next_arrive"], next_w=sub["next_w"],
         h_arr=h_arr_s, h_row=h_row_s, h_sub=h_sub_s, h_w=h_w_s,
@@ -580,9 +611,11 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         demand = q_tail - s["q_head"]
         picks, rr = select_batch(
             jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
-            ready=ready, idle=idle, demand=demand, write_window=drain,
+            ready=per_unit(ready, "all", R, cfg.NBG),
+            idle=per_unit(idle, "all", R, cfg.NBG),
+            demand=per_unit(demand, "sum", R, cfg.NBG), write_window=drain,
             budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
-            nb=NB)
+            nb=cfg.BPG)
 
         quiet_r = (idle.reshape(G, R, NB).all(axis=2)
                    & ready.reshape(G, R, NB).all(axis=2))
@@ -628,14 +661,19 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
             # grids without the trait keep this out of the traced graph)
             start = jnp.where(hra[:, None] & (new_sub != open_sub), t,
                               start)
-        mark = (jnp.repeat(picks, S, axis=1)
+        # a same-bank set starts once every bank of it can (per_unit and
+        # per_bank are the identity without bank groups)
+        start = per_bank(jnp, per_unit(start, "max", R, cfg.NBG), R,
+                         cfg.NBG)
+        picks_b = per_bank(jnp, picks, R, cfg.NBG)
+        mark = (jnp.repeat(picks_b, S, axis=1)
                 & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
                             == sub_of_col, True))
         ref_until_s = jnp.where(
             mark, jnp.repeat(start + RFC_PB[:, None], S, axis=1),
             ref_until_s)
         open_row_s = jnp.where(mark, -1, open_row_s)
-        ctr = ctr + picks
+        ctr = ctr + picks_b
         issued = issued + picks
         refpb = s["refpb"] + picks.sum(axis=1)
         maxlag = jnp.maximum(
@@ -664,7 +702,7 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
                        drain=drain, occ=demand,
                        rank_drain=jnp.repeat(rank_drain, NB, axis=1))
     with jax.named_scope("tick.serve"):
-        last_op, last_rank = s["last_op"], s["last_rank"]
+        last_op, last_bg = s["last_op"], s["last_bg"]
         q_head = s["q_head"]
         reads, writes = s["reads"], s["writes"]
         hits_s, misses_s = s["hits"], s["misses"]
@@ -679,20 +717,24 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
             core = h_core[arG, bs]
             hit = row == head_or[arG, bs]
             gr_b = bs // NB
-            lr = last_rank[:, ch]
+            bg_b = gr_b if cfg.NBG == 1 else bs // cfg.BPG
+            lbg = last_bg[:, ch]
+            lr = lbg if cfg.NBG == 1 else lbg // cfg.NBG
             lat = (jnp.where(hit, HIT, MISS)
                    + jnp.where(sarp & bank_mid[arG, bs],
                                SARP_PEN, 0)
                    + jnp.where(isw != last_op[:, ch], TURN, 0)
                    + jnp.where((lr >= 0) & (lr != gr_b), RTR, 0))
+            if cfg.NBG > 1:
+                # a start in its channel's last bank group: tCCD_L
+                lat = lat + jnp.where(lbg == bg_b, cst["CCDL"], 0)
             done = t + lat
             bank_free = bank_free.at[arG, bs].set(
                 jnp.where(ok, done + jnp.where(isw, WR, 0),
                           bank_free[arG, bs]))
             last_op = last_op.at[:, ch].set(
                 jnp.where(ok, isw, last_op[:, ch]))
-            last_rank = last_rank.at[:, ch].set(
-                jnp.where(ok, gr_b, last_rank[:, ch]))
+            last_bg = last_bg.at[:, ch].set(jnp.where(ok, bg_b, lbg))
             gsub = bs * S + sub_
             open_row_s = open_row_s.at[arG, gsub].set(
                 jnp.where(ok, row, open_row_s[arG, gsub]))
@@ -726,7 +768,7 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         open_row_s=open_row_s, open_sub=open_sub, ctr=ctr,
         issued=issued,
         rr=rr, ab_rr=ab_rr, wpend=wpend, drain=drain, last_op=last_op,
-        last_rank=last_rank,
+        last_bg=last_bg,
         ab_pending=ab_pending, rank_drain=rank_drain,
         reads=reads, writes=writes,
         hits=hits_s, misses=misses_s,

@@ -96,6 +96,31 @@ def could_pick(*, kind, lag, demand, write_window, budget, wrp) -> np.ndarray:
                   | (kind == KIND_HIRA))))
 
 
+def per_unit(x, how: str, n_ranks_total: int, n_bank_groups: int):
+    """A ``[G, B]`` per-bank plane as its ``[G, U]`` refresh-unit view:
+    ``how`` ("all", "sum" or "max") over bank k of every group of a rank
+    (unit ``u = gr * K + k``, K banks per group). The identity without
+    bank groups, where a unit is a bank."""
+    if n_bank_groups == 1:
+        return x
+    G, B = x.shape
+    K = B // (n_ranks_total * n_bank_groups)
+    y = getattr(x.reshape(G, n_ranks_total, n_bank_groups, K), how)(axis=2)
+    return y.reshape(G, n_ranks_total * K)
+
+
+def per_bank(xp, u, n_ranks_total: int, n_bank_groups: int):
+    """A ``[G, U]`` refresh-unit plane spread to the ``[G, B]`` banks of
+    each unit (`per_unit`'s layout). The identity without bank groups."""
+    if n_bank_groups == 1:
+        return u
+    G, U = u.shape
+    K = U // n_ranks_total
+    return xp.broadcast_to(
+        u.reshape(G, n_ranks_total, 1, K),
+        (G, n_ranks_total, n_bank_groups, K)).reshape(G, U * n_bank_groups)
+
+
 def _pick_one(xp, cand, key, allow):
     """One pick per row: the candidate with the largest key (ties -> lowest
     bank). Rows where `allow` is False or no candidate exists pick nothing."""
@@ -117,6 +142,10 @@ def select_batch(xp, *, kind, lag, ready, idle, demand, write_window,
     nb : banks per rank (static; 0 or B means a flat single-rank grid).
          Only the rank-aware families consume it — B is always the TOTAL
          bank count across channels and ranks.
+
+    With bank groups the engines pass the refresh-unit view (`per_unit`)
+    in place of banks: B is then the unit count and `nb` the units per
+    rank, and a pick names a same-bank set.
 
     Returns (picks [G, B] bool, rr_new [G]). Rows whose kind is not a
     vectorized pb family come back all-False (ideal/ab/custom cells are

@@ -221,3 +221,83 @@ def test_property_every_emitted_trace_is_jedec_clean(
     vio = validate_trace(res.commands, limit=3)
     assert vio == [], (policy, scenario, n_ranks, n_subarrays, seed,
                        [str(v) for v in vio])
+
+
+# ---------------------------------------- same-bank refresh (bank groups)
+
+def _grouped_run(policy="ref_pb", reqs=400, record=True):
+    """2 ranks x 4 bank groups of 2 banks, tREFI cut to a quarter so the
+    run owes same-bank refreshes."""
+    T = timing_for_density(32, n_ranks=2, n_subarrays=4, n_bank_groups=4,
+                           tCCD_L=9.0, tCCD_S=6.0, tREFI=1953.125)
+    wl = make_closed_workload("closed_multirank", reqs, 3)
+    return T, DramSim(T, wl, policy).run_ticks(record_commands=record)
+
+
+@pytest.mark.parametrize("policy", ("ref_pb", "dsarp", "ref_ab", "hira"))
+def test_same_bank_refresh_emits_ref_sb_validates_and_replays(policy):
+    T, res = _grouped_run(policy)
+    tr = res.commands
+    counts = tr.counts()
+    assert counts["REF_PB"] == 0
+    assert counts["REF_SB"] == res.refreshes_pb
+    assert (counts["REF_SB"] > 0) == (policy != "ref_ab")
+    for c in tr.cmds:
+        if c.op == "REF_SB":
+            assert 0 <= c.bank < T.banks_per_group
+    assert tr.meta["n_bank_groups"] == 4 and tr.meta["CCDL"] == 1
+    assert tr.meta["REFI_SB"] == tr.meta["REFI"] // T.n_refresh_units
+    assert validate_trace(tr) == []
+    replayed, bit_identical = round_trip(tr)
+    assert bit_identical and replayed.makespan == res.makespan
+    back = CmdTrace.from_json(json.loads(json.dumps(tr.to_json())))
+    assert traces_equal(tr, back) and round_trip(back)[1]
+
+
+def _planted(tr, change):
+    """`tr` with `change(cmds)` applied to a copy of its commands."""
+    cmds = list(tr.cmds)
+    change(cmds)
+    return CmdTrace(meta=dict(tr.meta), cmds=cmds, demand=tr.demand)
+
+
+def test_ref_sb_faults_fire_their_rules():
+    T, res = _grouped_run("ref_pb")
+    tr = res.commands
+    i = next(k for k, c in enumerate(tr.cmds) if c.op == "REF_SB")
+    ref = tr.cmds[i]
+    # a set bank's preamble left out
+    j = next(k for k, c in enumerate(tr.cmds)
+             if c.op == "PRE" and c.tick == ref.tick - tr.meta["TRP"]
+             and (c.ch, c.rank) == (ref.ch, ref.rank)
+             and c.bank % T.banks_per_group == ref.bank
+             and c.bank != ref.bank)
+    fired = validate_trace(_planted(tr, lambda cmds: cmds.pop(j)))
+    assert fired and fired[0].rule == "missing-prea"
+    # an ACT to another bank of the set inside the refresh window
+    other = ref.bank + T.banks_per_group
+    fired = validate_trace(_planted(tr, lambda cmds: cmds.append(
+        ref._replace(tick=ref.tick + 1, op="ACT", bank=other, row=5,
+                     data=-1))))
+    assert "short-trfc" in {v.rule for v in fired}
+    # REF_PB where the part refreshes same-bank sets
+    fired = validate_trace(_planted(tr, lambda cmds: cmds.__setitem__(
+        i, ref._replace(op="REF_PB"))))
+    assert "bad-sequence" in {v.rule for v in fired}
+    # a serve that skips tCCD_L after a same-group column command
+    bad = CmdTrace(meta=dict(tr.meta, CCDL=tr.meta["CCDL"] + 4),
+                   cmds=list(tr.cmds))
+    assert "trtr-min-latency" in {v.rule for v in validate_trace(bad)}
+
+
+def test_batched_sweep_ref_sb_emission_matches_run_ticks():
+    T, _ = _grouped_run(record=False)
+    spec = SweepSpec(policies=("dsarp", "ref_ab", "ref_pb"),
+                     scenarios=("closed_multirank",), densities=(32,),
+                     reqs=400, seed=3, n_ranks=2, n_subarrays=4,
+                     n_bank_groups=4, mode="closed", timing={32: T})
+    res = sweep(spec, "batched", record_commands=True)
+    for p in spec.policies:
+        tr = res.commands_for(p, "closed_multirank", 32)
+        assert validate_trace(tr) == [], p
+        assert traces_equal(tr, _grouped_run(p)[1].commands), p

@@ -328,3 +328,87 @@ def test_ledger_per_rank_budget_conservation():
             rank_due = sum(led.due(b, t) for b in banks)
             rank_issued = sum(led.banks[b].issued for b in banks)
             assert abs(rank_due - rank_issued) <= NB * budget
+
+
+# ------------------------------------------ bank groups, same-bank refresh
+def _grouped(n_bank_groups, density=DENSITY, **layout):
+    """The program's DRAM with `n_bank_groups` groups per rank, tCCD_L one
+    tick above tCCD_S, and tREFI cut to a quarter so that a short run
+    owes same-bank refreshes."""
+    return timing_for_density(density, n_bank_groups=n_bank_groups,
+                              tCCD_L=9.0, tCCD_S=6.0, tREFI=1953.125,
+                              **layout)
+
+
+@pytest.mark.parametrize("n_bank_groups,n_ranks,n_channels",
+                         [(2, 2, 1), (4, 2, 2), (4, 1, 2), (8, 2, 1)])
+def test_bank_groups_all_backends_bit_identical_to_run_ticks(
+        n_bank_groups, n_ranks, n_channels):
+    """Same-bank refresh units and the tCCD_L serve term: scalar, batched
+    and jax stay bit-identical to `DramSim.run_ticks` at every group
+    count, for every registered policy."""
+    T = _grouped(n_bank_groups, n_ranks=n_ranks, n_channels=n_channels)
+    policies = tuple(list_policies())
+    spec = SweepSpec(policies=policies, scenarios=("closed_multirank",),
+                     densities=(DENSITY,), reqs=240, seed=SEED,
+                     mode="closed", n_ranks=n_ranks, n_channels=n_channels,
+                     n_bank_groups=n_bank_groups, timing={DENSITY: T})
+    batched = sweep(spec, "batched")
+    _cells_equal(sweep(spec, "scalar"), batched, f"scalar G={n_bank_groups}")
+    _cells_equal(sweep(spec, "jax"), batched, f"jax G={n_bank_groups}")
+    wl = make_closed_workload("closed_multirank", 240, SEED)
+    for p in policies:
+        cell = batched.get(p, "closed_multirank", DENSITY)
+        assert cell.finished, (p, n_bank_groups)
+        _assert_cell_equals_sim(cell, DramSim(T, wl, p).run_ticks())
+    # every per-bank-level refresh is one command over a whole set
+    assert batched.get("ref_pb", "closed_multirank", DENSITY).refreshes_pb
+
+
+def test_one_bank_group_is_the_flat_engine():
+    """n_bank_groups=1 names no new mechanism: a spec and a `DramTiming`
+    that say so are the default grid, cell for cell."""
+    base = _spec(2, 2)
+    explicit = SweepSpec(policies=POLICIES, scenarios=("closed_multirank",),
+                         densities=(DENSITY,), reqs=REQS, seed=SEED,
+                         mode="closed", n_ranks=2, n_channels=2,
+                         n_bank_groups=1)
+    _cells_equal(sweep(base, "batched"), sweep(explicit, "batched"),
+                 "default/explicit-1-group")
+
+
+def test_megakernel_refuses_bank_groups():
+    T = _grouped(2)
+    spec = SweepSpec(policies=("darp",), scenarios=("closed_mixed",),
+                     densities=(DENSITY,), reqs=40, mode="closed",
+                     n_bank_groups=2, timing={DENSITY: T})
+    with pytest.raises(ValueError, match="bank groups"):
+        sweep(spec, "mega")
+
+
+def test_timing_bank_group_layout():
+    T = _grouped(4, n_ranks=2)
+    assert T.banks_per_group == 2 and T.n_refresh_units == 4
+    with pytest.raises(ValueError, match="divide"):
+        timing_for_density(32, n_bank_groups=3, tCCD_L=9.0, tCCD_S=6.0)
+    with pytest.raises(TypeError, match="n_bank_groups"):
+        timing_for_density(32, n_bank_groups=2)
+    with pytest.raises(TypeError, match="tCCD_S"):
+        timing_for_density(32, tCCD_S=6.0)
+
+
+def test_energy_proxy_counts_every_bank_of_a_same_bank_refresh():
+    from repro.core.refresh.sim import energy_proxy
+    flat, grouped = timing_for_density(32), _grouped(4)
+    e1 = energy_proxy(flat, 1e6, 100, 50, 30, 10, 2)
+    e4 = energy_proxy(grouped, 1e6, 100, 50, 30, 10, 2)
+    assert e4 - e1 == pytest.approx(3 * 0.15 * flat.tRFC_pb * 10)
+
+
+def test_event_mode_refuses_bank_groups():
+    """`DramSim.run` is the float event mode, not the tick contract, and
+    models no bank groups; `run_ticks` does."""
+    wl = make_closed_workload("closed_mixed", 40, SEED)
+    with pytest.raises(ValueError, match="bank groups"):
+        DramSim(_grouped(2), wl, "darp").run()
+    assert DramSim(_grouped(2), wl, "darp").run_ticks().reads_done > 0

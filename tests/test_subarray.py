@@ -275,3 +275,64 @@ def test_run_ticks_exposes_subarray_view_fields():
     assert seen["n_subarrays"] == 4
     assert seen["lens"] == (T.n_banks,) * 3
     assert all(0 <= s < 4 for s in seen["next_ref_sub"])
+
+
+# ------------------------------------------ bank groups, same-bank refresh
+def _grouped(n_bank_groups, n_subarrays, **layout):
+    """The program's DRAM with `n_bank_groups` groups per rank, tCCD_L one
+    tick above tCCD_S, and tREFI cut to a quarter so that a short run
+    owes same-bank refreshes."""
+    return timing_for_density(DENSITY, n_subarrays=n_subarrays,
+                              n_bank_groups=n_bank_groups, tCCD_L=9.0,
+                              tCCD_S=6.0, tREFI=1953.125, **layout)
+
+
+@pytest.mark.parametrize("n_bank_groups,n_subarrays",
+                         [(2, 1), (2, 8), (4, 4)])
+def test_bank_groups_subarray_all_backends_bit_identical_to_run_ticks(
+        n_bank_groups, n_subarrays):
+    """Every registered policy on both subarray scenarios with same-bank
+    refresh: scalar, batched and jax bit-identical to `run_ticks`."""
+    T = _grouped(n_bank_groups, n_subarrays)
+    spec = SweepSpec(policies=tuple(list_policies()), scenarios=SCENARIOS,
+                     densities=(DENSITY,), reqs=REQS * 2, seed=SEED,
+                     mode="closed", n_subarrays=n_subarrays,
+                     n_bank_groups=n_bank_groups, timing={DENSITY: T})
+    batched = sweep(spec, "batched")
+    _cells_equal(sweep(spec, "scalar"), batched,
+                 f"scalar G={n_bank_groups} S={n_subarrays}")
+    _cells_equal(sweep(spec, "jax"), batched,
+                 f"jax G={n_bank_groups} S={n_subarrays}")
+    for scen in SCENARIOS:
+        wl = make_closed_workload(scen, REQS * 2, SEED)
+        for p in list_policies():
+            cell = batched.get(p, scen, DENSITY)
+            assert cell.finished, (p, scen)
+            _assert_cell_equals_sim(cell, DramSim(T, wl, p).run_ticks())
+
+
+@pytest.mark.parametrize("policy", ["ref_pb", "darp", "sarp_pb", "hira"])
+def test_same_bank_refresh_blocks_every_bank_of_its_set(policy):
+    """A recorded timeline: every same-bank refresh occupies each bank of
+    its set over one window; no serve lands inside its own subarray's
+    window (a non-SARP REFsb's is the whole bank), while SARP ones serve
+    a sibling subarray; and the lag of every set stays within the
+    budget."""
+    T = _grouped(4, 4, n_ranks=2)
+    wl = make_closed_workload("closed_multirank", 600, SEED)
+    sim = DramSim(T, wl, policy).run_ticks(record_timeline=True)
+    sets = {}
+    for (b, rs, s0, s1, kind) in sim.timeline["refresh"]:
+        assert kind == "pb"
+        gr, k = b // T.n_banks, b % T.banks_per_group
+        sets.setdefault((gr, k, s0, s1), []).append((b, rs))
+    assert sim.refreshes_pb == len(sets) > 0
+    for (gr, k, s0, s1), banks in sets.items():
+        assert sorted(b for b, _ in banks) == [
+            gr * T.n_banks + g * T.banks_per_group + k
+            for g in range(T.n_bank_groups)]
+        assert len({rs for _, rs in banks}) == 1
+    sibling, own = _overlapped_serves(sim)
+    assert own == 0
+    assert (sibling > 0) == DramSim(T, wl, policy).policy.sarp
+    assert sim.max_abs_lag <= T.refresh_budget

@@ -1,6 +1,7 @@
 """Sweep engine + scenario library: determinism, batched-vs-scalar
 bit-identity for every registered policy, the jax/pallas fast paths, and
 the grid-vs-loop speed smoke."""
+import dataclasses
 import time
 
 import numpy as np
@@ -8,8 +9,10 @@ import pytest
 
 from repro.core.policy import PolicyBase, list_policies, register_policy
 from repro.core.policy.registry import _REGISTRY
+from repro.core.refresh import DramSim, make_closed_workload
 from repro.core.refresh.scenarios import (Trace, list_scenarios, make_trace,
                                           register_scenario)
+from repro.core.refresh.timing import timing_for_density
 from repro.core.sweep import CellResult, SweepSpec, sweep
 
 SMALL = dict(densities=(32,), reqs=120, seed=3)
@@ -284,3 +287,66 @@ def test_batched_grid_beats_scalar_loop():
     t_s = time.perf_counter() - t0
     _cells_equal(rb, rs)
     assert t_b < t_s, (t_b, t_s)
+
+
+# -------------------------------------------------- the spec's own DRAM
+def _timed(spec, **timing_ns):
+    """`spec` with its DRAM given as `SweepSpec.timing`: the program's
+    table at each density, with `timing_ns` replaced."""
+    lay = dict(n_banks=spec.n_banks, n_subarrays=spec.n_subarrays,
+               n_ranks=spec.n_ranks, n_channels=spec.n_channels)
+    return dataclasses.replace(spec, timing={
+        d: dataclasses.replace(timing_for_density(d, **lay), **timing_ns)
+        for d in spec.densities})
+
+
+TIMED = {
+    "open": SweepSpec(policies=("ref_ab", "ref_pb", "dsarp", "hira"),
+                      scenarios=("mixed", "write_burst_draining"),
+                      densities=(8, 32), reqs=160, seed=4),
+    "closed": SweepSpec(policies=("ref_ab", "ref_pb", "darp", "dsarp",
+                                  "staggered_ab"),
+                        scenarios=("closed_multirank",), densities=(8, 32),
+                        reqs=160, seed=4, mode="closed", n_ranks=2),
+}
+
+
+@pytest.mark.parametrize("backend", ["batched", "jax"])
+@pytest.mark.parametrize("mode", sorted(TIMED))
+def test_timing_equal_to_the_table_is_no_timing(mode, backend):
+    spec = TIMED[mode]
+    _cells_equal(sweep(_timed(spec), backend), sweep(spec, backend))
+
+
+@pytest.mark.parametrize("mode", sorted(TIMED))
+def test_other_timing_agrees_across_backends_and_with_run_ticks(mode):
+    """Halved tREFI and other tRFC: every backend simulates the spec's
+    DRAM, not the table's, and the closed grid equals
+    `DramSim.run_ticks` on that DRAM cell for cell."""
+    spec = _timed(TIMED[mode], tREFI=3906.25, tRFC_ab=260.0, tRFC_pb=110.0)
+    batched = sweep(spec, "batched")
+    _cells_equal(sweep(spec, "scalar"), batched)
+    _cells_equal(sweep(spec, "jax"), batched)
+    table = sweep(dataclasses.replace(spec, timing=None), "batched")
+    assert any(a != b for a, b in zip(batched.cells, table.cells))
+    if mode == "closed":
+        for (p, s, d), cell in zip(spec.cells(), batched.cells):
+            sim = DramSim(spec.timing[d], make_closed_workload(
+                s, spec.reqs, spec.seed), p).run_ticks()
+            assert (cell.makespan, cell.energy, cell.refreshes_pb,
+                    cell.refreshes_ab, cell.max_abs_lag,
+                    list(cell.core_finish)) == (
+                sim.makespan, sim.energy, sim.refreshes_pb,
+                sim.refreshes_ab, sim.max_abs_lag, list(sim.core_finish))
+
+
+def test_timing_must_be_the_specs_dram():
+    spec = TIMED["open"]
+    with pytest.raises(ValueError, match="no 8 Gb"):
+        dataclasses.replace(spec, timing={32: timing_for_density(32)})
+    with pytest.raises(ValueError, match="not the spec's DRAM"):
+        dataclasses.replace(spec, timing={
+            d: timing_for_density(d, n_ranks=2) for d in (8, 32)})
+    with pytest.raises(TypeError, match="tCCD_L"):
+        SweepSpec(policies=("darp",), scenarios=("mixed",),
+                  n_bank_groups=2)

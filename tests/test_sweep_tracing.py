@@ -1,6 +1,7 @@
 """The sweep's device path on the profiler's clock: the host spans of
 `sweep(..., backend="jax")`, the counters they carry, and the tick-phase
-scopes in the compiled loop, for both modes.
+scopes in the compiled loop, for both modes and for a DRAM with bank
+groups.
 
 A small grid is swept once warm, then once more inside a caller's span
 under `jax.profiler.trace`; the trace is read back with `ProfileData`.
@@ -11,6 +12,7 @@ import re
 import jax
 import pytest
 
+from repro.core.refresh.timing import timing_for_density
 from repro.core.sweep import SweepSpec, jaxbody, sweep
 from repro.core.sweep.engine import _Grid, _jax_arbiter
 
@@ -26,7 +28,15 @@ SPECS = {
     "open": SweepSpec(policies=("ref_pb", "darp", "elastic"),
                       scenarios=("mixed", "write_burst_draining"),
                       densities=(32,), reqs=120, seed=3),
+    # 2 ranks x 4 groups of 2 banks: 4 same-bank sets per cell
+    "groups": SweepSpec(
+        policies=("ref_pb", "dsarp"), scenarios=("closed_multirank",),
+        densities=(32,), reqs=160, seed=3, mode="closed", n_ranks=2,
+        n_bank_groups=4, timing={32: timing_for_density(
+            32, n_ranks=2, n_bank_groups=4, tCCD_L=9.0, tCCD_S=6.0)}),
 }
+#: refresh units and bank groups each spec's cells have
+UNITS = {"closed": (8, 1), "open": (8, 1), "groups": (4, 4)}
 
 
 def _final_t(spec) -> int:
@@ -37,7 +47,7 @@ def _final_t(spec) -> int:
 
 @pytest.fixture(scope="module", params=sorted(SPECS))
 def traced(request, tmp_path_factory):
-    """(mode, results, [(name, start, end, stats)] of the caller's span
+    """(spec name, results, [(name, start, end, stats)] of the caller's span
     and every `sweep.*` span, in start order)."""
     spec = SPECS[request.param]
     sweep(spec, backend="jax")                    # compile outside the trace
@@ -67,15 +77,18 @@ def test_spans_once_each_in_order_disjoint_inside_caller(traced):
 
 
 def test_finalize_carries_cells_and_loop_iterations(traced):
-    mode, res, events = traced
+    name, res, events = traced
     stats = {n: st for n, _, _, st in events}
     assert all(not stats[n] for n in SPANS[:-1])
     counters = stats["sweep.finalize"]
-    assert set(counters) == {"cells", "loop_iterations"}
-    assert counters["cells"] == len(res.cells) == len(SPECS[mode].cells())
-    assert counters["loop_iterations"] == _final_t(SPECS[mode])
-    ticks = [round(c.makespan / SPECS[mode].dt_ns) for c in res.cells]
-    if mode == "closed":
+    assert set(counters) == {"cells", "loop_iterations", "refresh_units",
+                             "bank_groups"}
+    assert counters["cells"] == len(res.cells) == len(SPECS[name].cells())
+    assert counters["loop_iterations"] == _final_t(SPECS[name])
+    assert (counters["refresh_units"],
+            counters["bank_groups"]) == UNITS[name]
+    ticks = [round(c.makespan / SPECS[name].dt_ns) for c in res.cells]
+    if SPECS[name].mode == "closed":
         # a core finishes at the tick its last request retires: the loop
         # runs until the slowest cell's last core has finished
         assert counters["loop_iterations"] > max(ticks)

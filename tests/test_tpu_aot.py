@@ -4,7 +4,8 @@ The TPU compiler is installed with JAX and compiles for a described
 `v5e:2x2` topology, so these tests catch what interpret mode cannot: a
 program or kernel that the chip's compiler refuses. Nothing runs. They
 cover the sweep's device path (the `jax` backend's while loop at the
-paper's closed grid and the open-loop grid that `chip_smoke.py` runs), the
+paper's closed grid and the open-loop grid that `chip_smoke.py` runs,
+and at a DDR5 layout with bank groups and same-bank refresh), the
 Pallas arbiter kernel, and the megakernel, which Mosaic still refuses.
 
 The topology is described inside a module-scoped fixture, never at
@@ -20,10 +21,22 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from chip_smoke import CLOSED_GRID, OPEN_GRID
-from repro.core.sweep import jaxbody
+from repro.core.refresh.timing import DramTiming
+from repro.core.sweep import SweepSpec, jaxbody
 from repro.core.sweep.engine import _Grid, _jax_arbiter
 
-GRIDS = {"closed": CLOSED_GRID, "open": OPEN_GRID}
+#: 2 subchannels x 2 ranks x 8 bank groups x 4 banks, DDR5-4800-like
+#: timing on ticks of tBL = 10/3 ns
+_DDR5 = dict(n_channels=2, n_ranks=2, n_banks=32, n_bank_groups=8)
+GROUPS_GRID = SweepSpec(
+    policies=("ref_ab", "ref_pb", "darp", "sarp_pb", "dsarp", "ideal"),
+    scenarios=("closed_multirank",), densities=(32,), reqs=320, seed=1,
+    mode="closed", dt_ns=10 / 3, **_DDR5,
+    timing={32: DramTiming(
+        density_gb=32, tRCD=16.25, tRP=16.25, tCL=16.67, tBL=10 / 3, tWR=30.0,
+        tWTR=10.0, tCCD_L=5.0, tCCD_S=10 / 3, tREFI=1950.0,
+        tRFC_ab=220.0, tRFC_pb=190.0, **_DDR5)})
+GRIDS = {"closed": CLOSED_GRID, "open": OPEN_GRID, "groups": GROUPS_GRID}
 
 
 @pytest.fixture(scope="module")
@@ -54,7 +67,7 @@ def _on(sharding, tree):
         np.shape(a), jnp.asarray(a).dtype, sharding=sharding), tree)
 
 
-@pytest.mark.parametrize("mode", ["closed", "open"])
+@pytest.mark.parametrize("mode", ["closed", "open", "groups"])
 def test_jax_backend_loop_compiles_for_v5e(one_chip, mode):
     """The program `sweep(..., backend="jax")` runs, at the grid
     `chip_smoke.py` runs it on."""
