@@ -223,11 +223,11 @@ def closed_state0(cfg: TickCfg, cst: dict) -> dict:
     C, K, LQ = cfg.C, cfg.K, cfg.LQ
     return dict(
         t=jnp.int32(0),
-        # ring bank queues (flat [G*B*LQ] so appends are one scatter)
+        # ring bank queues (flat [G*B*LQ] so appends are one scatter);
+        # qc holds core << 1 | is_write, so no bool plane is scattered
         qa=jnp.zeros(G * B * LQ, jnp.int32),
         qr=jnp.zeros(G * B * LQ, jnp.int32),
         qs=jnp.zeros(G * B * LQ, jnp.int32),
-        qw=jnp.zeros(G * B * LQ, bool),
         qc=jnp.zeros(G * B * LQ, jnp.int32),
         q_head=jnp.zeros((G, B), jnp.int32),
         q_tail=jnp.zeros((G, B), jnp.int32),
@@ -577,9 +577,8 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
                                   mode="drop")
         qr = s["qr"].at[tgtf].set(sr[flat_gc, sl].ravel(), mode="drop")
         qs_ = s["qs"].at[tgtf].set(ssub[flat_gc, sl].ravel(), mode="drop")
-        qw = s["qw"].at[tgtf].set(head_w.ravel(), mode="drop")
-        qc = s["qc"].at[tgtf].set(jnp.broadcast_to(
-            arC[None, :], (G, C)).ravel(), mode="drop")
+        qc = s["qc"].at[tgtf].set(
+            ((arC[None, :] << 1) | head_w).ravel(), mode="drop")
         q_tail = s["q_tail"] + oh.sum(axis=1)
         wpend = s["wpend"] + ok_w.sum(axis=1)
         out_reads = out_reads + want_r
@@ -688,8 +687,8 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         def head(q):                       # each bank's ring-queue head
             return pick(q.reshape(G, B, LQ), hslot)
 
-        h_row, h_sub, h_arr, h_w = head(qr), head(qs_), head(qa), head(qw)
-        h_core = head(qc)
+        h_row, h_sub, h_arr, hc = head(qr), head(qs_), head(qa), head(qc)
+        h_core, h_w = hc >> 1, (hc & 1) == 1
         has_req = (demand > 0) & active[:, None]
         ru3 = ref_until_s.reshape(G, B, S)
         head_ru = pick(ru3, h_sub)
@@ -760,7 +759,7 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
                 jnp.where(rmask, done, comp_t[arG, core, free_k]))
 
     return dict(
-        t=t + 1, qa=qa, qr=qr, qs=qs_, qw=qw, qc=qc,
+        t=t + 1, qa=qa, qr=qr, qs=qs_, qc=qc,
         q_head=q_head, q_tail=q_tail,
         next_idx=next_idx, next_issue=next_issue, out_reads=out_reads,
         remaining=remaining, finish=finish, comp_t=comp_t,
