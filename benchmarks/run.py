@@ -18,11 +18,6 @@ dicts go to results/bench/*.json.
   sweep_subarray      the [bank, subarray] hierarchy: subarray-storm grid
                  at n_subarrays in {1,4,8}, bit_identical per subarray
                  count, per-count weighted speedup vs ideal
-  sweep_mega     the fused Pallas tick-loop megakernel: giga-sweep
-                 ladder (10^3/10^4/10^5 cells) vs the jitted
-                 lax.while_loop backend, 1/2/4-way shard_map,
-                 bit_identical spot checks, warm-kernel regression
-                 guard vs the batched backend on the 8x8x3 grid
   command_trace  command layer: DFI-trace emission overhead (enabled vs
                  disabled run_ticks), validator violations, round-trip
                  bit_identical flag
@@ -120,17 +115,6 @@ def main() -> None:
           f"bit_identical={ss['bit_identical']};"
           f"sarp_ws_8sub_32gb={ws8['sarp_pb'][32]};"
           f"refpb_ws_8sub_32gb={ws8['ref_pb'][32]}", ss)
-
-    t0 = time.perf_counter()
-    sm = FR.sweep_mega(fast=fast)
-    top = sm["ladder"][-1]
-    _emit("sweep_mega", (time.perf_counter() - t0) * 1e6,
-          f"cells={top['cells']};"
-          f"mega_cells_per_s={top['mega_cells_per_s']};"
-          f"vs_jax={top['speedup_vs_jax']}x;"
-          f"fused_beats_batched="
-          f"{sm['ref_grid_8x8x3']['fused_beats_batched']};"
-          f"bit_identical={sm['bit_identical']}", sm)
 
     t0 = time.perf_counter()
     ct = FR.command_trace(fast=fast)

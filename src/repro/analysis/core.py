@@ -92,7 +92,6 @@ class RepoContext:
 
     FIELDS = "src/repro/core/sweep/fields.py"
     ARBITER = "src/repro/core/sweep/arbiter.py"
-    KERNEL_ARBITER = "src/repro/kernels/sweep_arbiter.py"
     DOC_CONTRACT = "docs/tick-contract.md"
     ENGINE = "src/repro/core/sweep/engine.py"
     SIM = "src/repro/core/refresh/sim.py"

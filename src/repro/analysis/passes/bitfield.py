@@ -2,10 +2,10 @@
 
 `repro/core/sweep/fields.py` is the declared single source of truth for
 the packed int32 score layout. This pass does NOT trust that claim: it
-re-derives the *effective* constants of each consumer module
-(`sweep/arbiter.py`, `kernels/sweep_arbiter.py`) by walking that module's
-own top-level statements — an ``from ...fields import`` binds the
-fields.py values, a later local assignment overrides them — so a stray
+re-derives the *effective* constants of its consumer module
+(`sweep/arbiter.py`) by walking that module's own top-level statements —
+an ``from ...fields import`` binds the fields.py values, a later local
+assignment overrides them — so a stray
 local redefinition, a dropped import, or an edit to fields.py itself all
 surface as drift. The field table in `docs/tick-contract.md` is parsed
 independently and compared against the same ground truth.
@@ -46,7 +46,7 @@ def module_view(ctx: RepoContext, rel: str,
                     dict[str, int], dict[str, int]]:
     """Effective top-level int constants of a module.
 
-    ``sources`` maps import-suffix (e.g. "fields", "arbiter") to that
+    ``sources`` maps import-suffix (e.g. "fields") to that
     module's already-evaluated env; an ``from x.y.fields import A, B``
     statement binds from it. Later local assignments override — that is
     exactly the drift this pass exists to catch.
@@ -243,8 +243,8 @@ def check_doc(ctx: RepoContext, truth: dict[str, int]) -> list[Finding]:
 
 @register_pass("bitfield", rules=RULES)
 def run(ctx: RepoContext) -> list[Finding]:
-    """Prove numpy arbiter, Pallas kernel, and the tick-contract doc all
-    agree on one well-formed int32-safe packed score layout."""
+    """Prove the arbiter and the tick-contract doc agree on one
+    well-formed int32-safe packed score layout."""
     out: list[Finding] = []
     ftree = ctx.tree(ctx.FIELDS)
     if ftree is None:
@@ -257,10 +257,10 @@ def run(ctx: RepoContext) -> list[Finding]:
         return out  # ground truth malformed; drift checks would be noise
 
     sources = {"fields": {n: truth[n] for n in CANON}}
-    for rel in (ctx.ARBITER, ctx.KERNEL_ARBITER):
-        if not ctx.exists(rel):
-            out.append(Finding(rel, 0, "BF101", "consumer module missing"))
-            continue
+    rel = ctx.ARBITER
+    if not ctx.exists(rel):
+        out.append(Finding(rel, 0, "BF101", "consumer module missing"))
+    else:
         env, lines = module_view(ctx, rel, sources)
         for name in CANON:
             if name not in env:
@@ -279,10 +279,6 @@ def run(ctx: RepoContext) -> list[Finding]:
             out.extend(
                 f for f in check_layout(env, rel, 1)
                 if f.rule in ("BF102", "BF103", "BF104"))
-        # make the arbiter's effective env available to modules that
-        # import the constants via the historical arbiter import site
-        if rel == ctx.ARBITER:
-            sources["arbiter"] = {n: env[n] for n in CANON if n in env}
 
     out.extend(check_doc(ctx, truth))
     return out

@@ -15,14 +15,7 @@ catching statically:
   function makes the intent explicit.
 * ``pl.pallas_call`` without an ``interpret=`` argument (or with it
   hardcoded ``False``) — every kernel must keep the off-TPU interpret
-  fallback reachable, per the `make_arbiter`/`ops._default_interpret`
-  idiom.
-* Megakernel plane-table drift — the fused sweep kernel moves per-cell
-  params and stats through packed int32 planes whose column layout is
-  owned by ``core.sweep.fields`` (``MP_*``/``MS_*``/``MEGA_*``). A
-  kernel module that re-declares one of those names locally, or spells
-  a block/output shape's trailing width as a literal int instead of the
-  fields name, desyncs silently the next time a column is added.
+  fallback reachable, per the `ops._default_interpret` idiom.
 * Fused-update completeness — the tick state lives in the dict returned
   by the paired ``<mode>_state0`` / ``<mode>_body`` functions
   (``core.sweep.jaxbody``). A key present in ``state0``'s dict but
@@ -34,13 +27,11 @@ Rules
   PL502  grid size floor-divided without a ceil idiom or divisibility
          guard
   PL503  pallas_call without a reachable interpret fallback
-  PL504  kernel plane width/name not pinned to core.sweep.fields
   PL505  tick-state plane dropped from a fused body's return dict
 """
 from __future__ import annotations
 
 import ast
-import re
 
 from repro.analysis.astutil import attr_chain, names_in
 from repro.analysis.core import Finding, RepoContext, register_pass
@@ -49,7 +40,6 @@ RULES = (
     ("PL501", "data-dependent Python control flow in kernel"),
     ("PL502", "grid floor-division without ceil or divisibility guard"),
     ("PL503", "pallas_call without interpret fallback"),
-    ("PL504", "kernel plane width/name not pinned to fields.py"),
     ("PL505", "tick-state plane dropped from fused body return"),
 )
 
@@ -250,62 +240,7 @@ def check_interpret(tree: ast.Module, path: str) -> list[Finding]:
     return out
 
 
-_MEGA_NAME = re.compile(r"^(MEGA_|MP_|MS_)")
 _SWEEP_DIR = "src/repro/core/sweep"
-
-
-def _imports_mega_fields(tree: ast.Module) -> bool:
-    """True if the module imports any plane-table name from fields."""
-    return any(
-        isinstance(node, ast.ImportFrom) and node.module
-        and node.module.rpartition(".")[2] == "fields"
-        and any(_MEGA_NAME.match(a.name) for a in node.names)
-        for node in tree.body)
-
-
-def check_mega_shapes(tree: ast.Module, path: str) -> list[Finding]:
-    """PL504 — plane-table integrity in kernel modules.
-
-    (a) A top-level assignment binding an ``MP_*``/``MS_*``/``MEGA_*``
-    name shadows the fields.py plane table with a local copy.
-    (b) In modules that import plane-table names from fields, a
-    ``BlockSpec``/``ShapeDtypeStruct`` whose shape tuple ends in a
-    literal int hardcodes the packed width: adding a column to
-    fields.py would leave the kernel reading a stale layout.
-    """
-    out: list[Finding] = []
-    for node in tree.body:
-        if not isinstance(node, ast.Assign):
-            continue
-        for tgt in node.targets:
-            if isinstance(tgt, ast.Name) and _MEGA_NAME.match(tgt.id):
-                out.append(Finding(
-                    path, node.lineno, "PL504",
-                    f"'{tgt.id}' is (re)defined locally — plane-table "
-                    "column indices and widths must be imported from "
-                    "core.sweep.fields, the single source of truth"))
-    if not _imports_mega_fields(tree):
-        return out
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
-            continue
-        chain = attr_chain(node.func)
-        if not chain or chain[-1] not in ("BlockSpec", "ShapeDtypeStruct"):
-            continue
-        shape = node.args[0] if node.args else next(
-            (k.value for k in node.keywords
-             if k.arg in ("block_shape", "shape")), None)
-        if (isinstance(shape, ast.Tuple) and shape.elts
-                and isinstance(shape.elts[-1], ast.Constant)
-                and type(shape.elts[-1].value) is int):
-            out.append(Finding(
-                path, shape.elts[-1].lineno, "PL504",
-                f"trailing dimension of a {chain[-1]} shape is a literal "
-                "int in a module using the fields.py plane tables — pin "
-                "the packed width to its fields name (MEGA_NPARAM / "
-                "MEGA_NSTAT / a cfg field) so a table change cannot "
-                "desync the kernel layout"))
-    return out
 
 
 def _own_returns(fn: ast.FunctionDef) -> list[ast.Return]:
@@ -367,8 +302,8 @@ def check_state_keysets(tree: ast.Module, path: str) -> list[Finding]:
 @register_pass("pallas-lint", rules=RULES)
 def run(ctx: RepoContext) -> list[Finding]:
     """Lint every Pallas kernel module for traced control flow, grid
-    divisibility, the interpret-mode fallback, and plane-table pinning;
-    lint the shared tick-state modules for fused-update completeness."""
+    divisibility and the interpret-mode fallback; lint the shared
+    tick-state modules for fused-update completeness."""
     out: list[Finding] = []
     for rel in ctx.py_files(ctx.KERNELS_DIR):
         text = ctx.text(rel)
@@ -380,8 +315,6 @@ def run(ctx: RepoContext) -> list[Finding]:
         out.extend(check_kernel_control_flow(tree, rel))
         out.extend(check_grids(tree, rel))
         out.extend(check_interpret(tree, rel))
-        out.extend(check_mega_shapes(tree, rel))
-        out.extend(check_state_keysets(tree, rel))
     for rel in ctx.py_files(_SWEEP_DIR):
         text = ctx.text(rel)
         if text is None or "_state0" not in text:

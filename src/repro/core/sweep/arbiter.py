@@ -4,9 +4,8 @@ This is the hot inner step of the batched simulator: given the stacked
 machine state, score every (cell, bank) pair and pick at most one request
 start per cell for this tick (the data bus serializes starts — one burst
 per tick, tick == tBL). The scoring is written against a pluggable array
-module `xp` so the numpy backend and the jax/pallas fast path
-(`repro.kernels.sweep_arbiter`) share one definition; everything is int32
-so every backend is bit-identical.
+module `xp` so the numpy backend and the jitted jax tick loop share one
+definition; everything is int32 so every backend is bit-identical.
 
 Priority of an eligible head request (descending):
   1. drain-mode writes (the write window empties the buffer first,
@@ -40,8 +39,8 @@ from __future__ import annotations
 import numpy as np
 
 # The packed score-field constants live in `sweep/fields.py` (single
-# source of truth, cross-checked against the Pallas kernel and the
-# docs/tick-contract.md field table by `repro.analysis`); re-exported
+# source of truth, cross-checked against the docs/tick-contract.md
+# field table by `repro.analysis`); re-exported
 # here because this module is the historical import site.
 from repro.core.sweep.fields import (AGE_CAP, OCC_CAP, W_HIT, W_NOCONF,
                                      W_OCC, W_WRITE)

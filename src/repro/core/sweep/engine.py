@@ -9,8 +9,8 @@ makes that grid cheap by hoisting the per-tick machine state (banks, bus,
 write buffer, refresh ledger) into stacked ``[G, n_banks]`` arrays, where
 ``G`` is the number of grid cells, and advancing every cell one tick at a
 time with vectorized numpy (policy decisions included — see
-`sweep.policies`); the availability/arbitration inner step also has a
-jax/pallas kernel (`repro.kernels.sweep_arbiter`) for accelerator runs.
+`sweep.policies`), or with one jitted `lax.while_loop` on an
+accelerator (`sweep.jaxbody`).
 
 State is stacked over GLOBAL banks: every cell carries a full
 [channel, rank, bank] hierarchy (`SweepSpec.n_channels` x `n_ranks` x
@@ -40,7 +40,7 @@ Tick semantics (the contract every backend implements identically;
     tBL, so each channel's data bus serializes to at most one request
     START per cell per channel per tick). All derived timings quantize
     via ``max(1, round(ns / dt_ns))`` — all-integer state means the
-    scalar oracle, the batched numpy backend, and the jax/pallas arbiter
+    scalar oracle, the batched numpy backend, and the jitted jax loop
     are **bit-identical**, not merely close.
   * Each tick, per active cell, in order:
       A. arrivals join their bank FIFO; pending-write count may trip the
@@ -103,8 +103,9 @@ identically):
 Backends:
 
   * ``backend="batched"`` — stacked numpy, vectorized policies, the
-    default. `arbiter="pallas"` routes step D through the jax/pallas
-    kernel (interpret mode off-TPU).
+    default; the command-emitting path and the custom-policy fallback.
+  * ``backend="jax"`` — the same contract as one jitted
+    `lax.while_loop` (`sweep.jaxbody`); the device path on a TPU.
   * ``backend="scalar"`` — the reference oracle: a plain-Python
     per-cell tick loop that drives the *real* registered policy objects
     through `MaintenanceView`/`select()`. Slow by construction; exists so
@@ -409,17 +410,9 @@ def _scenario_name(s) -> str:
 
 # ------------------------------------------------------------------ grid
 class _Grid:
-    """Spec unpacked into stacked arrays + per-cell constants.
+    """Spec unpacked into stacked arrays + per-cell constants."""
 
-    ``stack_streams=False`` (the megakernel layout) skips the per-cell
-    ``[G, ...]`` demand-stream stacking and keeps one stream plane per
-    *scenario* (``scn_*``, indexed by ``scn_of_cell``) instead — every
-    cell of a scenario replays the same stream, so a 10^5-cell grid needs
-    only ``n_scenarios`` stream copies; the fused kernel gathers its
-    tile's plane via scalar prefetch. Per-cell constants and totals are
-    identical in both layouts."""
-
-    def __init__(self, spec: SweepSpec, stack_streams: bool = True):
+    def __init__(self, spec: SweepSpec):
         if not (spec.policies and spec.scenarios and spec.densities):
             raise ValueError(
                 "sweep() needs at least one policy, scenario, and density "
@@ -488,26 +481,10 @@ class _Grid:
                     L = max(L, int(m.sum()))
                 split[name] = per_bank
             self.L = L
-            if stack_streams:
-                self.q_arrive = np.full((G, B, L), _PAD_ARRIVE, np.int32)
-                self.q_row = np.zeros((G, B, L), np.int32)
-                self.q_sub = np.zeros((G, B, L), np.int32)
-                self.q_write = np.zeros((G, B, L), bool)
-            else:
-                NS = len(traces)
-                self.scn_qa = np.full((NS, B, L), _PAD_ARRIVE, np.int32)
-                self.scn_qr = np.zeros((NS, B, L), np.int32)
-                self.scn_qs = np.zeros((NS, B, L), np.int32)
-                self.scn_qw = np.zeros((NS, B, L), bool)
-                self.scn_npb = np.zeros((NS, B), np.int32)
-                for i, name in enumerate(traces):
-                    for b, (arr, row, sub, isw) in enumerate(split[name]):
-                        n = len(arr)
-                        self.scn_npb[i, b] = n
-                        self.scn_qa[i, b, :n] = arr
-                        self.scn_qr[i, b, :n] = row
-                        self.scn_qs[i, b, :n] = sub
-                        self.scn_qw[i, b, :n] = isw
+            self.q_arrive = np.full((G, B, L), _PAD_ARRIVE, np.int32)
+            self.q_row = np.zeros((G, B, L), np.int32)
+            self.q_sub = np.zeros((G, B, L), np.int32)
+            self.q_write = np.zeros((G, B, L), bool)
             self.n_per_bank = np.zeros((G, B), np.int32)
 
         self.timing = {d: TickTiming.from_timing(spec.dram(d), spec.dt_ns)
@@ -540,37 +517,13 @@ class _Grid:
                     for dem in self.demands.values())
             self.C, self.N = C, N
             self.K = max(dem.mlp for dem in self.demands.values())
-            if stack_streams:
-                self.s_write = np.zeros((G, C, N), bool)
-                self.s_bank = np.zeros((G, C, N), np.int32)
-                self.s_row = np.zeros((G, C, N), np.int32)
-                self.s_sub = np.zeros((G, C, N), np.int32)
-                self.s_think = np.zeros((G, C, N), np.int32)
-            else:
-                NS = len(self.demands)
-                self.scn_write = np.zeros((NS, C, N), bool)
-                self.scn_bank = np.zeros((NS, C, N), np.int32)
-                self.scn_row = np.zeros((NS, C, N), np.int32)
-                self.scn_sub = np.zeros((NS, C, N), np.int32)
-                self.scn_think = np.zeros((NS, C, N), np.int32)
-                self.scn_nreq = np.zeros((NS, C), np.int32)
-                for i, dem in enumerate(self.demands.values()):
-                    c, n = dem.is_write.shape
-                    self.scn_write[i, :c, :n] = dem.is_write
-                    self.scn_bank[i, :c, :n] = dem.bank
-                    self.scn_row[i, :c, :n] = dem.row
-                    self.scn_sub[i, :c, :n] = dem.sub
-                    self.scn_think[i, :c, :n] = dem.think
-                    self.scn_nreq[i, :c] = n
+            self.s_write = np.zeros((G, C, N), bool)
+            self.s_bank = np.zeros((G, C, N), np.int32)
+            self.s_row = np.zeros((G, C, N), np.int32)
+            self.s_sub = np.zeros((G, C, N), np.int32)
+            self.s_think = np.zeros((G, C, N), np.int32)
             self.n_req_c = np.zeros((G, C), np.int32)
             self.mlp_g = np.zeros(G, np.int32)
-        # scenario index of every cell (megakernel tiles gather their
-        # scenario's stream plane through this; cheap in both layouts)
-        scn_names = list(self.demands) if self.closed else list(traces)
-        scn_index = {n: i for i, n in enumerate(scn_names)}
-        self.scn_of_cell = np.array(
-            [scn_index[_scenario_name(s)] for _, s, _ in self.cells],
-            dtype=np.int32)
 
         for g, (p, s, d) in enumerate(self.cells):
             tk = self.timing[d]
@@ -594,15 +547,14 @@ class _Grid:
             if self.closed:
                 dem = self.demands[_scenario_name(s)]
                 c, n = dem.is_write.shape
-                if stack_streams:
-                    self.s_write[g, :c, :n] = dem.is_write
-                    self.s_bank[g, :c, :n] = dem.bank
-                    self.s_row[g, :c, :n] = dem.row
-                    self.s_sub[g, :c, :n] = dem.sub
-                    self.s_think[g, :c, :n] = dem.think
+                self.s_write[g, :c, :n] = dem.is_write
+                self.s_bank[g, :c, :n] = dem.bank
+                self.s_row[g, :c, :n] = dem.row
+                self.s_sub[g, :c, :n] = dem.sub
+                self.s_think[g, :c, :n] = dem.think
                 self.n_req_c[g, :c] = n
                 self.mlp_g[g] = dem.mlp
-            elif stack_streams:
+            else:
                 for b, (arr, row, sub, isw) in enumerate(
                         split[_scenario_name(s)]):
                     n = len(arr)
@@ -611,8 +563,6 @@ class _Grid:
                     self.q_row[g, b, :n] = row
                     self.q_sub[g, b, :n] = sub
                     self.q_write[g, b, :n] = isw
-            else:
-                self.n_per_bank[g] = self.scn_npb[self.scn_of_cell[g]]
 
         self.has_stag = bool((self.kind == KIND_STAG).any())
         self.has_hra = bool(self.hra.any())
@@ -624,8 +574,7 @@ class _Grid:
             # (C * mlp) + buffered writes (wbuf_cap)
             need = self.C * int(self.K) + spec.wbuf_cap + 1
             self.LQ = 1 << max(1, (need - 1).bit_length())
-            s_think = self.s_think if stack_streams else self.scn_think
-            think_span = int(s_think.sum(axis=2).max())
+            think_span = int(self.s_think.sum(axis=2).max())
             auto = (think_span + 4 * int(self.n_tot.max()) * svc
                     + 8 * int(self.RFC_AB.max()) + 64)
         else:
@@ -684,15 +633,12 @@ def _p99_ticks(hist_row: np.ndarray, n_reads: int) -> int:
 
 def _finalize(grid: _Grid, g: int, *, reads, writes, hits, misses, refpb,
               refab, lat_sum, hist, maxlag, last_done, finished,
-              core_finish=None, p99=None) -> CellResult:
+              core_finish=None) -> CellResult:
     """Integer machine stats -> CellResult. Shared by every backend (and
     mirrored by `DramSim.run_ticks`) so the derived floats are
     bit-identical whenever the integers are. `core_finish` (per-core
     finish ticks) switches the cell to closed-loop accounting: makespan
-    becomes the last core's finish instead of the last data burst.
-    `p99` (the p99 tick index, already reduced from the histogram — the
-    megakernel computes it in-kernel and never ships the [4096] rows
-    home) skips `_p99_ticks`; `hist` may be None then."""
+    becomes the last core's finish instead of the last data burst."""
     from repro.core.refresh.sim import energy_proxy
     p, s, d = grid.cells[g]
     spec = grid.spec
@@ -712,8 +658,7 @@ def _finalize(grid: _Grid, g: int, *, reads, writes, hits, misses, refpb,
         policy=p, scenario=_scenario_name(s), density_gb=d,
         makespan=makespan, reads_done=int(reads), writes_done=int(writes),
         avg_read_latency=(dt * int(lat_sum) / int(reads)) if reads else 0.0,
-        p99_read_latency=dt * (_p99_ticks(hist, int(reads))
-                               if p99 is None else int(p99)),
+        p99_read_latency=dt * _p99_ticks(hist, int(reads)),
         refreshes_pb=int(refpb), refreshes_ab=int(refab),
         row_hits=int(hits), row_misses=int(misses),
         energy=energy_proxy(T, makespan, int(reads), int(writes),
@@ -723,19 +668,12 @@ def _finalize(grid: _Grid, g: int, *, reads, writes, hits, misses, refpb,
 
 
 # --------------------------------------------------------- batched backend
-def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
+def _run_batched(grid: _Grid) -> list[CellResult]:
     spec = grid.spec
     G, B, L, S = grid.G, grid.B, grid.L, grid.S
     NB, R, NC, NBG = grid.NB, grid.R, grid.NC, grid.NBG
     RBC = grid.NR * NB               # banks per channel
     HI, LO = spec.wbuf_hi, spec.wbuf_lo
-
-    score_fn = None
-    if arbiter == "pallas":
-        from repro.kernels.sweep_arbiter import make_arbiter
-        score_fn = make_arbiter(G, B)
-    elif arbiter != "numpy":
-        raise ValueError(f"unknown arbiter {arbiter!r}")
 
     # flat [G*B, L] views for single-op queue gathers
     qa = grid.q_arrive.reshape(G * B, L)
@@ -989,18 +927,11 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
         head_or = np.take_along_axis(
             open_row_s.reshape(G, B, S), h_sub[:, :, None], 2)[:, :, 0]
         bank_mid = (ru3 > t).any(axis=2)
-        if score_fn is not None:
-            score = np.asarray(score_fn(
-                t, has_req=has_req, head_row=h_row, head_arrive=h_arr,
-                head_is_write=h_w, bank_free=bank_free,
-                head_ref_until=head_ru, bank_mid_ref=bank_mid,
-                open_row=head_or, drain=drain, rank_drain=rank_drain_b))
-        else:
-            score = arbiter_scores_masked(
-                t, has_req=has_req, idle=idle, head_ready=head_ru <= t,
-                bank_mid_ref=bank_mid, head_row=h_row, head_arrive=h_arr,
-                head_is_write=h_w, open_row=head_or, drain=drain,
-                rank_drain=rank_drain_b, rank_can_drain=has_drain_block)
+        score = arbiter_scores_masked(
+            t, has_req=has_req, idle=idle, head_ready=head_ru <= t,
+            bank_mid_ref=bank_mid, head_row=h_row, head_arrive=h_arr,
+            head_is_write=h_w, open_row=head_or, drain=drain,
+            rank_drain=rank_drain_b, rank_can_drain=has_drain_block)
         for ch in range(NC):
             sc_ch = score[:, ch * RBC:(ch + 1) * RBC]
             bs_loc = sc_ch.argmax(axis=1)
@@ -1066,8 +997,7 @@ def _run_batched(grid: _Grid, arbiter: str = "numpy") -> list[CellResult]:
 
 
 # ------------------------------------------------ batched backend (closed)
-def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
-                        record_commands: bool = False):
+def _run_batched_closed(grid: _Grid, *, record_commands: bool = False):
     """Closed-loop mode over the stacked state: the open-loop machine plus
     vectorized per-core MLP windows, write-buffer backpressure, and ring
     bank queues fed by the cores (contract in the module docstring).
@@ -1097,13 +1027,6 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
                 spec.dram(d), resolve_policy(p), spec.dt_ns,
                 scenario=_scenario_name(s),
                 wbuf=(spec.wbuf_cap, spec.wbuf_hi, spec.wbuf_lo))))
-
-    score_fn = None
-    if arbiter == "pallas":
-        from repro.kernels.sweep_arbiter import make_arbiter
-        score_fn = make_arbiter(G, B)
-    elif arbiter != "numpy":
-        raise ValueError(f"unknown arbiter {arbiter!r}")
 
     # flat [G*C, N] stream views for single-op gathers
     sw = grid.s_write.reshape(G * C, N)
@@ -1423,20 +1346,12 @@ def _run_batched_closed(grid: _Grid, arbiter: str = "numpy", *,
         head_or = np.take_along_axis(
             open_row_s.reshape(G, B, S), h_sub[:, :, None], 2)[:, :, 0]
         bank_mid = (ru3 > t).any(axis=2)
-        if score_fn is not None:
-            score = np.asarray(score_fn(
-                t, has_req=has_req, head_row=h_row, head_arrive=h_arr,
-                head_is_write=h_w, bank_free=bank_free,
-                head_ref_until=head_ru, bank_mid_ref=bank_mid,
-                open_row=head_or, drain=drain, rank_drain=rank_drain_b,
-                occ=demand))
-        else:
-            score = arbiter_scores_masked(
-                t, has_req=has_req, idle=idle, head_ready=head_ru <= t,
-                bank_mid_ref=bank_mid, head_row=h_row, head_arrive=h_arr,
-                head_is_write=h_w, open_row=head_or, drain=drain,
-                rank_drain=rank_drain_b, rank_can_drain=has_drain_block,
-                occ=demand)
+        score = arbiter_scores_masked(
+            t, has_req=has_req, idle=idle, head_ready=head_ru <= t,
+            bank_mid_ref=bank_mid, head_row=h_row, head_arrive=h_arr,
+            head_is_write=h_w, open_row=head_or, drain=drain,
+            rank_drain=rank_drain_b, rank_can_drain=has_drain_block,
+            occ=demand)
         for ch in range(NC):
             sc_ch = score[:, ch * RBC:(ch + 1) * RBC]
             bs_loc = sc_ch.argmax(axis=1)
@@ -2027,52 +1942,42 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
 
 
 # --------------------------------------------------------- jax fast path
-def _check_jax_guards(grid: _Grid, backend: str = "jax") -> None:
-    """Shared preconditions of the traced backends (jax and mega)."""
+def _check_jax_guards(grid: _Grid) -> None:
+    """Preconditions of the traced backend."""
     if grid.customs:
         raise ValueError(
-            f"backend={backend!r} supports only the built-in policy "
-            "classes; custom policies "
-            f"{[p.name for _, p in grid.customs]!r} need "
+            "backend='jax' supports only the built-in policy classes; "
+            f"custom policies {[p.name for _, p in grid.customs]!r} need "
             "backend='batched'")
     # jnp runs x32: the clipped-latency sum fits int32 only while
     # reads_per_cell * MAX_LAT_TICKS < 2**31
     if int(grid.n_tot.max()) * MAX_LAT_TICKS >= 2 ** 31:
         raise ValueError(
-            f"backend={backend!r} accumulates latency sums in int32; "
+            "backend='jax' accumulates latency sums in int32; "
             f"{int(grid.n_tot.max())} requests per cell could overflow — "
             "use backend='batched'")
 
 
 @functools.lru_cache(maxsize=None)
 def _jax_arbiter(arbiter: str):
-    """The arbitration callable for the traced tick body: the jnp scoring
-    definitions, or the Pallas arbiter kernel (interpret mode off-TPU).
-    Cached, so one arbiter name is one static argument of
-    `jaxbody.run_loop` and its compiled loop is reused."""
-    import jax
+    """The arbitration callable of the traced tick body: the jnp scoring
+    definitions of `sweep.arbiter`. Cached, so it is one static argument
+    of `jaxbody.run_loop` and its compiled loop is reused. "jnp" is the
+    only name."""
     import jax.numpy as jnp
 
-    if arbiter == "pallas":
-        from repro.kernels.sweep_arbiter import _arbiter_call
-        interp = jax.default_backend() != "tpu"
-
-        def scores(t, **kw):
-            return _arbiter_call(t, **kw, interpret=interp)
-    elif arbiter == "jnp":
-        def scores(t, **kw):
-            return arbiter_scores(jnp, t, **kw)
-    else:
+    if arbiter != "jnp":
         raise ValueError(f"unknown jax arbiter {arbiter!r}")
+
+    def scores(t, **kw):
+        return arbiter_scores(jnp, t, **kw)
     return scores
 
 
-def _run_jax(spec: SweepSpec, arbiter: str = "jnp") -> list[CellResult]:
+def _run_jax(spec: SweepSpec) -> list[CellResult]:
     """The whole tick loop as one jitted `lax.while_loop`
-    (`jaxbody.run_loop`, the body shared verbatim with the fused Pallas
-    megakernel), in either mode: state lives in jnp int32 arrays,
-    policies run through the same xp-generic `select_batch`, and the
-    arbitration step optionally routes through the Pallas kernel.
+    (`jaxbody.run_loop`), in either mode: state lives in jnp int32
+    arrays and policies run through the same xp-generic `select_batch`.
     Closed grids add per-core MLP-window state and core-fed ring bank
     queues. Integer arithmetic keeps this bit-identical to the numpy
     backend and the scalar oracle; custom (non-vectorizable) policy
@@ -2099,7 +2004,7 @@ def _run_jax(spec: SweepSpec, arbiter: str = "jnp") -> list[CellResult]:
         cfg, cst, st = jaxbody.program(grid)
     with TraceAnnotation("sweep.tick_loop"):
         out = jax.block_until_ready(
-            jaxbody.run_loop(cfg, cst, _jax_arbiter(arbiter), st))
+            jaxbody.run_loop(cfg, cst, _jax_arbiter("jnp"), st))
     with TraceAnnotation("sweep.readback"):
         out = jax.device_get(out)
     with TraceAnnotation("sweep.finalize", cells=grid.G,
@@ -2122,119 +2027,50 @@ def _run_jax(spec: SweepSpec, arbiter: str = "jnp") -> list[CellResult]:
                 for g in range(grid.G)]
 
 
-# ----------------------------------------------------- megakernel backend
-def _run_mega(grid: _Grid, n_shards: int = 1) -> list[CellResult]:
-    """The fused Pallas tick-loop megakernel
-    (`repro.kernels.sweep_megakernel`): the same traced body as the jax
-    backend (`sweep.jaxbody`), but run to completion *inside* a
-    cell-tiled kernel — per-scenario streams gathered via scalar
-    prefetch, scenario-pure tiles early-exiting independently, stats
-    reduced in-kernel (no [G, 4096] histogram round-trip), and the tile
-    axis optionally sharded across devices (`n_shards`). Bit-identical
-    to every other backend by construction."""
-    _check_jax_guards(grid, backend="mega")
-    if grid.NBG > 1:
-        raise ValueError(
-            f"backend='mega' does not model bank groups (n_bank_groups="
-            f"{grid.NBG}): its packed parameter table has no same-bank "
-            "refresh or tCCD_L; use backend='jax' or 'batched'")
-    from repro.kernels.sweep_megakernel import run_mega
-
-    out = run_mega(grid, n_shards=n_shards)
-    cf = out.get("core_finish")
-    return [_finalize(grid, g, reads=out["reads"][g],
-                      writes=out["writes"][g], hits=out["hits"][g],
-                      misses=out["misses"][g], refpb=out["refpb"][g],
-                      refab=out["refab"][g], lat_sum=out["lat_sum"][g],
-                      hist=None, maxlag=out["maxlag"][g],
-                      last_done=out["last_done"][g],
-                      finished=out["finished"][g], p99=out["p99"][g],
-                      core_finish=None if cf is None else cf[g])
-            for g in range(grid.G)]
-
-
 # ------------------------------------------------------------------ entry
-def sweep(spec: SweepSpec, backend: str = "batched",
-          arbiter: Optional[str] = None, *,
-          record_commands: bool = False, n_shards: int = 1) -> SweepResult:
+def sweep(spec: SweepSpec, backend: str = "batched", *,
+          record_commands: bool = False) -> SweepResult:
     """Run the whole grid.
 
     backend="batched" : stacked-numpy lock-step (default; supports custom
-                        policy registrations via per-cell fallback),
+                        policy registrations via per-cell fallback, and
+                        emits command traces),
     backend="jax"     : the whole tick loop jitted (`lax.while_loop`),
                         built-in policy classes only; the device path
                         on a TPU,
-    backend="mega"    : the fused Pallas tick-loop megakernel
-                        (`repro.kernels.sweep_megakernel`) — the same
-                        traced body as "jax" run to completion inside a
-                        cell-tiled kernel; interpret mode only, so on a
-                        TPU it raises `MegakernelRefused` before
-                        lowering; `n_shards` > 1 additionally shards the
-                        cell-tile axis across devices with `shard_map`,
     backend="scalar"  : plain-Python per-cell reference oracle.
-
-    `arbiter` selects the availability/arbitration step implementation:
-    "numpy" (batched default), "jnp" (jax default), or "pallas" (the
-    kernel in `repro.kernels.sweep_arbiter`; interpret mode off-TPU).
 
     All three backends exist for both `spec.mode` values; closed-loop
     cells additionally carry `core_finish`, making
     `CellResult.weighted_speedup_vs` (the paper's metric) available.
 
-    `record_commands=True` (batched or mega backend, closed mode only)
+    `record_commands=True` (batched backend, closed mode only)
     additionally emits a per-cell DFI-style command trace, retrievable
     via `SweepResult.commands_for(policy, scenario, density)` — the same
     `repro.core.commands.CmdTrace` `DramSim.run_ticks` emits, command
-    for command (tick-contract section 7). The megakernel does not emit
-    in-kernel: it reruns the grid on the emitting batched backend and
-    *reconciles* — every CellResult must match bit-for-bit, or the
-    sweep raises.
+    for command (tick-contract section 7).
     """
+    if backend not in ("batched", "jax", "scalar"):
+        raise ValueError(f"unknown sweep backend {backend!r}")
     closed = spec.mode == "closed"
-    if record_commands and not (backend in ("batched", "mega") and closed):
+    if record_commands and not (backend == "batched" and closed):
         raise ValueError(
-            "record_commands=True needs backend='batched' or 'mega' and "
+            "record_commands=True needs backend='batched' and "
             "mode='closed' (the jitted/scalar backends do not emit; use "
             "DramSim.run_ticks(record_commands=True) per cell instead)")
-    if n_shards != 1 and backend != "mega":
-        raise ValueError(
-            f"n_shards is a megakernel knob; backend={backend!r} runs on "
-            "one device (use backend='mega')")
-    if backend == "mega":
-        grid = _Grid(spec, stack_streams=False)
-        cells = _run_mega(grid, n_shards=n_shards)
-        res = SweepResult(spec, cells, backend)
-        if record_commands:
-            ref = sweep(spec, backend="batched", record_commands=True)
-            bad = [i for i, (a, b) in enumerate(zip(cells, ref.cells))
-                   if a != b]
-            if bad:
-                raise RuntimeError(
-                    "megakernel results fail to reconcile with the "
-                    "command-emitting batched backend at cells "
-                    f"{bad[:5]}{'...' if len(bad) > 5 else ''} of "
-                    f"{len(cells)}")
-            res.commands = ref.commands
-        return res
     if backend == "jax":
-        return SweepResult(spec, _run_jax(spec, arbiter=arbiter or "jnp"),
-                           backend)
+        return SweepResult(spec, _run_jax(spec), backend)
     grid = _Grid(spec)
     traces = None
-    if backend == "batched":
-        if closed:
-            if record_commands:
-                cells, traces = _run_batched_closed(
-                    grid, arbiter=arbiter or "numpy", record_commands=True)
-            else:
-                cells = _run_batched_closed(grid, arbiter=arbiter or "numpy")
-        else:
-            cells = _run_batched(grid, arbiter=arbiter or "numpy")
-    elif backend == "scalar":
+    if backend == "scalar":
         run_cell = _run_scalar_cell_closed if closed else _run_scalar_cell
         cells = [run_cell(grid, g) for g in range(grid.G)]
+    elif not closed:
+        cells = _run_batched(grid)
+    elif record_commands:
+        cells, traces = _run_batched_closed(grid, record_commands=True)
     else:
-        raise ValueError(f"unknown sweep backend {backend!r}")
+        cells = _run_batched_closed(grid)
     res = SweepResult(spec, cells, backend)
     if traces is not None:
         res.commands = {(c.policy, c.scenario, c.density_gb): tr

@@ -2,12 +2,12 @@
 
 The sweep engine's arbitration step packs its FR-FCFS-style priority into
 one int32 per (cell, bank) so a single argmax picks the winner. The field
-layout below is shared by every consumer — `sweep/arbiter.py` (the numpy
-scoring definitions), `kernels/sweep_arbiter.py` (the Pallas kernel), and
-the normative field table in `docs/tick-contract.md` — and is mechanically
-cross-checked by the `bitfield` pass of `repro.analysis`
-(`python tools/check_contract.py --pass bitfield`): redefining any of
-these names downstream, or letting the doc table drift, fails CI.
+layout below is shared by every consumer — `sweep/arbiter.py` (the
+xp-generic scoring definitions) and the normative field table in
+`docs/tick-contract.md` — and is mechanically cross-checked by the
+`bitfield` pass of `repro.analysis` (`python tools/check_contract.py
+--pass bitfield`): redefining any of these names downstream, or letting
+the doc table drift, fails CI.
 
 Layout (descending priority):
 
@@ -56,34 +56,6 @@ W_WRITE = 1 << WRITE_SHIFT
 #: exclusive top bit of the packed layout — must stay < 31 for int32
 SCORE_BITS = WRITE_SHIFT + 1
 
-# -- megakernel plane tables ------------------------------------------------
-# The fused tick-loop kernel (`kernels/sweep_megakernel.py`) carries each
-# cell's per-cell constants as one int32 row of a ``[G, MEGA_NPARAM]``
-# block and returns its integer machine stats as one row of a
-# ``[G, MEGA_NSTAT]`` block. These column tables are the single source of
-# truth for both widths; the `pallas-lint` pass (PL504) rejects kernel
-# modules that redefine them locally or spell the widths as literals.
-
-#: per-cell parameter columns (policy kind/traits, quantized timings,
-#: closed-loop MLP window, shared horizon, and the pad-cell flag)
-(MP_KIND, MP_LEVEL_AB, MP_SARP, MP_HRA, MP_WRP, MP_URGENT, MP_BUDGET,
- MP_REFI, MP_REFI_PB, MP_RFC_PB, MP_RFC_AB, MP_HIT, MP_MISS, MP_WR,
- MP_TURN, MP_RTR, MP_SARP_PEN, MP_MLP, MP_HORIZON, MP_PAD) = range(20)
-MEGA_NPARAM = 20
-
-#: per-cell integer stat columns (the exact inputs `engine._finalize`
-#: needs, plus the in-kernel p99 tick index and the finished flag)
-(MS_READS, MS_WRITES, MS_HITS, MS_MISSES, MS_REFPB, MS_REFAB, MS_LATSUM,
- MS_MAXLAG, MS_LASTDONE, MS_P99, MS_FINISHED) = range(11)
-MEGA_NSTAT = 11
-
 __all__ = ["AGE_BITS", "AGE_CAP", "NOCONF_SHIFT", "W_NOCONF", "HIT_SHIFT",
            "W_HIT", "OCC_SHIFT", "OCC_BITS", "W_OCC", "OCC_CAP",
-           "WRITE_SHIFT", "W_WRITE", "SCORE_BITS",
-           "MP_KIND", "MP_LEVEL_AB", "MP_SARP", "MP_HRA", "MP_WRP",
-           "MP_URGENT", "MP_BUDGET", "MP_REFI", "MP_REFI_PB", "MP_RFC_PB",
-           "MP_RFC_AB", "MP_HIT", "MP_MISS", "MP_WR", "MP_TURN", "MP_RTR",
-           "MP_SARP_PEN", "MP_MLP", "MP_HORIZON", "MP_PAD", "MEGA_NPARAM",
-           "MS_READS", "MS_WRITES", "MS_HITS", "MS_MISSES", "MS_REFPB",
-           "MS_REFAB", "MS_LATSUM", "MS_MAXLAG", "MS_LASTDONE", "MS_P99",
-           "MS_FINISHED", "MEGA_NSTAT"]
+           "WRITE_SHIFT", "W_WRITE", "SCORE_BITS"]

@@ -1,22 +1,18 @@
-"""jaxbody — the traced tick loop shared by `engine` and the megakernel.
+"""jaxbody — the traced tick loop of the sweep engine's device path.
 
 The open- and closed-loop tick bodies (tick-contract phases A-E / 0-5)
-used to live inline in `engine._run_jax` / `engine._run_jax_closed`. The
-fused Pallas megakernel (`repro.kernels.sweep_megakernel`) needs the
-*same* traced body inside a kernel, so the loop now lives here as pure
-functions of three ingredients:
+are pure functions of three ingredients:
 
   * ``TickCfg``  — static shape/config facts (frozen dataclass, hashable,
-                   usable as a jit/pallas static argument),
+                   usable as a jit static argument),
   * ``cst``      — per-grid constant planes (jnp arrays, traced so one
                    compiled loop serves many grids of the same shape),
   * ``s``        — the per-tick state dict.
 
 `loop` runs either body to completion as one `lax.while_loop`. The engine's
 jax backend calls it jitted (`run_loop`, on the inputs `program` builds);
-the megakernel calls it inside a cell-tiled `pallas_call`. Both paths
-therefore stay bit-identical to the batched numpy backend and the scalar
-oracle by construction — there is exactly one traced tick body.
+integer arithmetic keeps it bit-identical to the batched numpy backend
+and the scalar oracle.
 
 Everything is int32/bool (tick-contract section 3). The ``*_state0``
 functions build the canonical initial state and each ``*_body`` returns a
@@ -48,7 +44,7 @@ from repro.core.sweep.policies import (KIND_AB, KIND_IDEAL, KIND_STAG,
 # ------------------------------------------------------------------ config
 @dataclass(frozen=True)
 class TickCfg:
-    """Static facts of one grid's tick loop (hashable for jit/pallas).
+    """Static facts of one grid's tick loop (hashable for jit).
 
     ``closed`` selects the closed-loop body; the open-loop fields (``L``)
     and closed-loop fields (``C``/``N``/``K``/``LQ``/``CAP``) are only
@@ -167,8 +163,7 @@ def pick(plane, idx):
 # ------------------------------------------------------------- state zero
 def open_state0(cfg: TickCfg, cst: dict) -> dict:
     """Canonical open-loop t=0 state. The next-arrival mirror is masked by
-    ``n_pb > 0`` so banks with no requests (including megakernel pad
-    cells, whose ``n_pb`` is forced to 0) never fire an arrival; for the
+    ``n_pb > 0`` so banks with no requests never fire an arrival; for the
     engine's stacked queues this is the identity, because empty queue
     slots are pre-filled with `_PAD_ARRIVE`."""
     G, B, S = cst["n_pb"].shape[0], cfg.B, cfg.S
@@ -217,8 +212,7 @@ def open_state0(cfg: TickCfg, cst: dict) -> dict:
 
 def closed_state0(cfg: TickCfg, cst: dict) -> dict:
     """Canonical closed-loop t=0 state. Cells with no requests at all
-    (megakernel pad cells) start with ``remaining == 0`` and are finished
-    at t=0, exactly like an engine cell whose demand is empty."""
+    start with ``remaining == 0`` and are finished at t=0."""
     G, B, S = cst["n_req"].shape[0], cfg.B, cfg.S
     C, K, LQ = cfg.C, cfg.K, cfg.LQ
     return dict(
@@ -284,9 +278,7 @@ def closed_cond(cst: dict, s: dict):
 def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
     """One open-loop tick (phases A-E) for every cell at once. `scores`
     is the arbitration callable ``scores(t, **planes) -> [G, B] int32``
-    (the jnp scoring definitions, or the Pallas arbiter on the engine
-    path — the megakernel inlines the jnp scoring, a kernel cannot nest
-    a `pallas_call`)."""
+    (the jnp scoring definitions of `sweep.arbiter`)."""
     B, L, S = cfg.B, cfg.L, cfg.S
     NB, R, NC = cfg.NB, cfg.R, cfg.NC
     RBC = cfg.NR * cfg.NB            # banks per channel

@@ -43,7 +43,6 @@ LOGICAL_RULES_SINGLE_POD: dict[str, tuple] = {
     "pages": ("model",),
     "stack": (),
     "state": (),
-    "cells": ("data",),
 }
 
 LOGICAL_RULES_MULTI_POD = dict(LOGICAL_RULES_SINGLE_POD, batch=("pod", "data"))

@@ -2,12 +2,12 @@
 
 The sweep engine's arbitration step packs its FR-FCFS-style priority into
 one int32 per (cell, bank) so a single argmax picks the winner. The field
-layout below is shared by every consumer — `sweep/arbiter.py` (the numpy
-scoring definitions), `kernels/sweep_arbiter.py` (the Pallas kernel), and
-the normative field table in `docs/tick-contract.md` — and is mechanically
-cross-checked by the `bitfield` pass of `repro.analysis`
-(`python tools/check_contract.py --pass bitfield`): redefining any of
-these names downstream, or letting the doc table drift, fails CI.
+layout below is shared by every consumer — `sweep/arbiter.py` (the
+xp-generic scoring definitions) and the normative field table in
+`docs/tick-contract.md` — and is mechanically cross-checked by the
+`bitfield` pass of `repro.analysis` (`python tools/check_contract.py
+--pass bitfield`): redefining any of these names downstream, or letting
+the doc table drift, fails CI.
 
 Layout (descending priority):
 

@@ -9,61 +9,6 @@ from repro.kernels import ops, ref
 RS = np.random.RandomState(42)
 
 
-# ------------------------------------------------------------- megakernel
-def _mega_spec(mode="closed"):
-    from repro.core.sweep import SweepSpec
-    scen = (("closed_mixed", "closed_read_heavy") if mode == "closed"
-            else ("mixed", "read_heavy"))
-    return SweepSpec(policies=("ideal", "ref_ab", "darp", "dsarp"),
-                     scenarios=scen, densities=(8, 32), reqs=48, seed=11,
-                     mode=mode)
-
-
-@pytest.mark.parametrize("mode", ["closed", "open"])
-def test_megakernel_interpret_matches_compiled_while_loop(mode):
-    """Interpret-vs-compiled equivalence for the fused tick-loop kernel:
-    `backend='mega'` (explicitly interpret-mode Pallas) against
-    `backend='jax'` — the XLA-compiled `lax.while_loop` of the *same*
-    traced body (`sweep.jaxbody`) — must agree bit-for-bit. This pins
-    the interpreter against the compiled trace; on a TPU the kernel is
-    refused (next test)."""
-    from repro.core.sweep import CellResult, sweep
-    spec = _mega_spec(mode)
-    a, b = sweep(spec, "mega"), sweep(spec, "jax")
-    bad = [(x.policy, x.scenario, x.density_gb, f)
-           for x, y in zip(a.cells, b.cells) if x != y
-           for f in CellResult.__dataclass_fields__
-           if getattr(x, f) != getattr(y, f)]
-    assert not bad, f"mega/jax diverged: {bad[:8]}"
-
-
-def test_megakernel_compiled_matches_interpret_on_tpu(monkeypatch):
-    """The compiled kernel would have to equal its interpreter, but
-    Mosaic refuses to lower it for a TPU (`tests/test_tpu_aot.py`
-    compiles it ahead of time for a described v5e chip). Until it does,
-    `backend="mega"` on a TPU raises `MegakernelRefused` before lowering
-    and points to `backend="jax"`; it never runs the interpreter there."""
-    from repro.core.sweep import sweep
-    from repro.kernels.sweep_megakernel import MegakernelRefused
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(MegakernelRefused, match="backend='jax'"):
-        sweep(_mega_spec(), "mega")
-
-
-def test_megakernel_invariant_to_tile_and_chunk_shape():
-    """Tile height, chunk size, and pad cells are pure dispatch choices:
-    forcing tiny tiles (pad rows in every tile) and multi-chunk
-    streaming must reproduce the default dispatch exactly."""
-    from repro.core.sweep.engine import _Grid
-    from repro.kernels.sweep_megakernel import run_mega
-    grid = _Grid(_mega_spec(), stack_streams=False)
-    base = run_mega(grid)
-    odd = run_mega(grid, tile=3, chunk_tiles=2)
-    for k in base:
-        np.testing.assert_array_equal(np.asarray(base[k]),
-                                      np.asarray(odd[k]), k)
-
-
 # ------------------------------------------------------------------- flash
 @pytest.mark.parametrize("bh,s,d", [(2, 64, 16), (1, 128, 32), (3, 256, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -54,20 +54,15 @@ def _spec(n_ranks, n_channels=1, policies=POLICIES,
 @pytest.mark.parametrize("n_ranks,n_channels", [(2, 1), (4, 1), (2, 2)])
 def test_multirank_all_backends_bit_identical_to_run_ticks(n_ranks,
                                                            n_channels):
-    """Every backend (batched numpy, jitted jax, fused Pallas megakernel,
-    pallas-scored batched, scalar oracle) stays bit-identical to
-    `DramSim.run_ticks` at every rank/channel count, for every policy on
-    the multirank axis."""
+    """Every backend (batched numpy, jitted jax, scalar oracle) stays
+    bit-identical to `DramSim.run_ticks` at every rank/channel count, for
+    every policy on the multirank axis."""
     spec = _spec(n_ranks, n_channels)
     batched = sweep(spec, "batched")
     _cells_equal(sweep(spec, "scalar"), batched,
                  f"scalar/batched R={n_ranks} C={n_channels}")
     _cells_equal(sweep(spec, "jax"), batched,
                  f"jax/batched R={n_ranks} C={n_channels}")
-    _cells_equal(sweep(spec, "mega"), batched,
-                 f"mega/batched R={n_ranks} C={n_channels}")
-    _cells_equal(sweep(spec, "batched", arbiter="pallas"), batched,
-                 f"pallas/batched R={n_ranks} C={n_channels}")
     wl = make_closed_workload("closed_multirank", REQS, SEED)
     T = timing_for_density(DENSITY, n_ranks=n_ranks, n_channels=n_channels)
     for p in POLICIES:
@@ -91,10 +86,9 @@ def test_multirank_wrapped_ring_queues_bit_identical_to_run_ticks():
         np.add.at(per_bank[g], grid.s_bank[g, c, :grid.n_req_c[g, c]], 1)
     assert per_bank.max() > grid.LQ, (per_bank.max(), grid.LQ)
     batched = sweep(spec, "batched")
-    for backend, kw in (("scalar", {}), ("jax", {}), ("mega", {}),
-                        ("batched", {"arbiter": "pallas"})):
-        _cells_equal(sweep(spec, backend, **kw), batched,
-                     f"{backend}{kw}/batched wrapped rings")
+    for backend in ("scalar", "jax"):
+        _cells_equal(sweep(spec, backend), batched,
+                     f"{backend}/batched wrapped rings")
     wl = make_closed_workload("closed_multirank", reqs, SEED)
     T = timing_for_density(DENSITY, n_ranks=2, n_channels=2)
     for p in POLICIES:
@@ -375,15 +369,6 @@ def test_one_bank_group_is_the_flat_engine():
                          n_bank_groups=1)
     _cells_equal(sweep(base, "batched"), sweep(explicit, "batched"),
                  "default/explicit-1-group")
-
-
-def test_megakernel_refuses_bank_groups():
-    T = _grouped(2)
-    spec = SweepSpec(policies=("darp",), scenarios=("closed_mixed",),
-                     densities=(DENSITY,), reqs=40, mode="closed",
-                     n_bank_groups=2, timing={DENSITY: T})
-    with pytest.raises(ValueError, match="bank groups"):
-        sweep(spec, "mega")
 
 
 def test_timing_bank_group_layout():

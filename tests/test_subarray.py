@@ -57,20 +57,15 @@ def _spec(n_subarrays, policies=None, scenarios=SCENARIOS):
 # --------------------------------------------- subarray conformance grid
 @pytest.mark.parametrize("n_subarrays", SUBARRAYS)
 def test_subarray_all_backends_bit_identical_to_run_ticks(n_subarrays):
-    """Every backend (batched numpy, jitted jax, fused Pallas megakernel,
-    pallas-scored batched, scalar oracle) stays bit-identical to
-    `DramSim.run_ticks` at every subarray count, for EVERY registered
-    policy on both subarray scenarios."""
+    """Every backend (batched numpy, jitted jax, scalar oracle) stays
+    bit-identical to `DramSim.run_ticks` at every subarray count, for
+    EVERY registered policy on both subarray scenarios."""
     spec = _spec(n_subarrays)
     batched = sweep(spec, "batched")
     _cells_equal(sweep(spec, "scalar"), batched,
                  f"scalar/batched S={n_subarrays}")
     _cells_equal(sweep(spec, "jax"), batched,
                  f"jax/batched S={n_subarrays}")
-    _cells_equal(sweep(spec, "mega"), batched,
-                 f"mega/batched S={n_subarrays}")
-    _cells_equal(sweep(spec, "batched", arbiter="pallas"), batched,
-                 f"pallas/batched S={n_subarrays}")
     for scen in SCENARIOS:
         wl = make_closed_workload(scen, REQS, SEED)
         T = timing_for_density(DENSITY, n_subarrays=n_subarrays)

@@ -1,6 +1,6 @@
 """Sweep engine + scenario library: determinism, batched-vs-scalar
-bit-identity for every registered policy, the jax/pallas fast paths, and
-the grid-vs-loop speed smoke."""
+bit-identity for every registered policy, the jax fast path, and the
+grid-vs-loop speed smoke."""
 import dataclasses
 import time
 
@@ -163,7 +163,7 @@ def test_sarp_orderings_on_adversarial_scenario():
     assert adv >= friendly - 0.02    # adversarial erodes the advantage
 
 
-# ----------------------------------------------------- jax / pallas paths
+# --------------------------------------------------------------- jax path
 def test_jax_backend_bit_identical():
     spec = SweepSpec(policies=("ref_ab", "ref_pb", "darp", "dsarp",
                                "elastic", "hira", "ideal"),
@@ -183,6 +183,28 @@ def test_jax_backend_rejects_custom_policies():
             sweep(spec, "jax")
     finally:
         del _REGISTRY["_test_sweep_nojit"]
+
+
+@pytest.mark.parametrize("backend,kw,error", [
+    ("mega", {}, ValueError),
+    ("jax", {"arbiter": "pallas"}, TypeError),
+])
+def test_sweep_rejects_removed_options(backend, kw, error):
+    """`sweep` has one device path: a backend or keyword outside its
+    API is refused, not silently mapped to another path."""
+    spec = SweepSpec(policies=("darp",), scenarios=("mixed",), **SMALL)
+    with pytest.raises(error):
+        sweep(spec, backend, **kw)
+
+
+def test_jax_scoring_is_one_cached_jnp_callable():
+    """The traced body's scoring is the jnp definition only, and one
+    object per name, so `run_loop`'s static argument reuses its
+    compiled loop."""
+    from repro.core.sweep.engine import _jax_arbiter
+    assert _jax_arbiter("jnp") is _jax_arbiter("jnp")
+    with pytest.raises(ValueError, match="pallas"):
+        _jax_arbiter("pallas")
 
 
 def test_empty_axis_spec_rejected_with_clear_error():
@@ -228,43 +250,6 @@ def test_masked_scores_match_shared():
             rank_can_drain=True, occ=kw["occ"])
         np.testing.assert_array_equal(np.asarray(got, np.int64),
                                       np.asarray(expect, np.int64), str(t))
-
-
-def test_pallas_arbiter_matches_numpy_scores():
-    from repro.core.sweep.arbiter import arbiter_scores
-    from repro.kernels.sweep_arbiter import make_arbiter
-
-    rs = np.random.RandomState(11)
-    G, B = 37, 8                      # deliberately not a tile multiple
-    kw = dict(
-        has_req=rs.rand(G, B) < 0.7,
-        head_row=rs.randint(0, 4096, (G, B)).astype(np.int32),
-        head_arrive=rs.randint(0, 500, (G, B)).astype(np.int32),
-        head_is_write=rs.rand(G, B) < 0.3,
-        bank_free=rs.randint(0, 700, (G, B)).astype(np.int32),
-        head_ref_until=rs.randint(0, 700, (G, B)).astype(np.int32),
-        bank_mid_ref=rs.rand(G, B) < 0.3,
-        open_row=rs.randint(-1, 4096, (G, B)).astype(np.int32),
-        drain=rs.rand(G) < 0.4,
-        # per-bank rank-drain plane (each bank carries its rank's flag)
-        rank_drain=np.repeat(rs.rand(G, 2) < 0.1, B // 2, axis=1),
-    )
-    t = 512
-    expect = arbiter_scores(np, t, **kw)
-    got = make_arbiter(G, B)(t, **kw)
-    np.testing.assert_array_equal(np.asarray(got), expect)
-    # occupancy field (closed-loop mode) must match through the kernel too
-    occ = rs.randint(0, 20, (G, B)).astype(np.int32)
-    expect_occ = arbiter_scores(np, t, occ=occ, **kw)
-    got_occ = make_arbiter(G, B)(t, occ=occ, **kw)
-    np.testing.assert_array_equal(np.asarray(got_occ), expect_occ)
-
-
-def test_batched_with_pallas_arbiter_identical():
-    spec = SweepSpec(policies=("ref_pb", "dsarp"), scenarios=("mixed",),
-                     densities=(32,), reqs=80, seed=5)
-    _cells_equal(sweep(spec, "batched", arbiter="pallas"),
-                 sweep(spec, "scalar"))
 
 
 # ------------------------------------------------------------ speed smoke

@@ -5,8 +5,7 @@ The TPU compiler is installed with JAX and compiles for a described
 program or kernel that the chip's compiler refuses. Nothing runs. They
 cover the sweep's device path (the `jax` backend's while loop at the
 paper's closed grid and the open-loop grid that `chip_smoke.py` runs,
-and at a DDR5 layout with bank groups and same-bank refresh), the
-Pallas arbiter kernel, and the megakernel, which Mosaic still refuses.
+and at a DDR5 layout with bank groups and same-bank refresh).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and this
@@ -98,51 +97,3 @@ def test_jax_backend_loop_compiles_for_v5e(one_chip, mode):
         assert appends
         pred_appends = [ln for ln in appends if "= pred[" in ln]
         assert not pred_appends, pred_appends[:3]
-
-
-@pytest.mark.parametrize("mode", ["closed", "open"])
-def test_sweep_arbiter_kernel_compiles_for_v5e(one_chip, mode):
-    """The Pallas arbiter with `interpret=False`, at each grid's [G, B]
-    score plane. `arbiter="pallas"` picks interpret mode on the CPU, so
-    the kernel is lowered directly."""
-    from repro.kernels.sweep_arbiter import _arbiter_call
-
-    grid = _Grid(GRIDS[mode])
-    gb = jax.ShapeDtypeStruct((grid.G, grid.B), jnp.int32, sharding=one_chip)
-    g1 = jax.ShapeDtypeStruct((grid.G,), jnp.int32, sharding=one_chip)
-    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    occ = gb if grid.closed else None
-    compiled = _arbiter_call.lower(
-        t, *([gb] * 8), g1, gb, occ, interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.xfail(strict=True, raises=(AssertionError, ValueError),
-                   reason="Mosaic refuses the megakernel "
-                          "(sweep_megakernel.REFUSAL)")
-@pytest.mark.parametrize("mode", ["closed", "open"])
-def test_megakernel_compiles_for_v5e(one_chip, mode):
-    """The fused megakernel with `interpret=False`. While Mosaic refuses
-    it, `run_mega` raises `MegakernelRefused` instead of lowering it;
-    the day this compiles, the strict xfail fails and the refusal must
-    be lifted."""
-    from repro.kernels import sweep_megakernel as mk
-
-    grid = _Grid(GRIDS[mode], stack_streams=False)
-    _, tile_scn, tile = mk._layout(grid, None)
-    if grid.closed:
-        cfg = jaxbody.closed_cfg(grid)
-        streams = (grid.scn_write, grid.scn_bank, grid.scn_row,
-                   grid.scn_sub, grid.scn_think, grid.scn_nreq)
-        call = mk._closed_call_jit
-    else:
-        cfg = jaxbody.open_cfg(grid)
-        streams = (grid.scn_qa, grid.scn_qr, grid.scn_qs, grid.scn_qw,
-                   grid.scn_npb)
-        call = mk._open_call_jit
-    n_tiles = len(tile_scn)
-    args = [np.zeros(n_tiles, np.int32),
-            np.zeros((n_tiles * tile, mk.MEGA_NPARAM), np.int32),
-            *(np.asarray(a, np.int32) for a in streams)]
-    call.lower(*_on(one_chip, args), cfg=cfg, n_tiles=n_tiles, tile=tile,
-               interpret=False).compile()
