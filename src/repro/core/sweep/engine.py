@@ -1990,8 +1990,10 @@ def _run_jax(spec: SweepSpec) -> list[CellResult]:
     (`jax.device_get`) and ``sweep.finalize`` (the `CellResult`s), the
     last carrying the counters ``cells``, ``loop_iterations`` (the times
     the while loop ran), ``refresh_units`` (per cell: banks, or same-bank
-    sets with bank groups) and ``bank_groups`` (per rank). Without an
-    active profiler a span costs one inactive `TraceMe`."""
+    sets with bank groups), ``bank_groups`` (per rank) and
+    ``stream_pick_lanes`` (the N stream lanes over which a closed loop
+    picks each core's next request; 0 in open mode). Without an active
+    profiler a span costs one inactive `TraceMe`."""
     import jax
     from jax.profiler import TraceAnnotation
 
@@ -2009,7 +2011,8 @@ def _run_jax(spec: SweepSpec) -> list[CellResult]:
         out = jax.device_get(out)
     with TraceAnnotation("sweep.finalize", cells=grid.G,
                          loop_iterations=int(out["t"]),
-                         refresh_units=grid.U, bank_groups=grid.NBG):
+                         refresh_units=grid.U, bank_groups=grid.NBG,
+                         stream_pick_lanes=cfg.N):
         if grid.closed:
             finished = (out["remaining"] <= 0).all(axis=1)
             fin = np.where(out["finish"] < 0, int(out["t"]), out["finish"])

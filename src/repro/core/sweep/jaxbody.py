@@ -133,16 +133,27 @@ def open_consts(grid) -> dict:
 
 
 def closed_consts(grid) -> dict:
+    """The closed body's constant planes. The per-core request streams
+    are three [G*C, N] int32 planes: `srec`, the packed record
+    (`stream_record`: global subarray and kind), `sr` (row) and `sth`
+    (think ticks)."""
     G, C, N = grid.G, grid.C, grid.N
+    srec = stream_record(grid.s_bank, grid.s_sub, grid.s_write, grid.S)
     return dict(
-        sw=jnp.asarray(grid.s_write.reshape(G * C, N)),
-        sb=_j32(grid.s_bank.reshape(G * C, N)),
+        srec=_j32(srec.reshape(G * C, N)),
         sr=_j32(grid.s_row.reshape(G * C, N)),
-        ssub=_j32(grid.s_sub.reshape(G * C, N)),
         sth=_j32(grid.s_think.reshape(G * C, N)),
         n_req=_j32(grid.n_req_c),
         mlp=_j32(grid.mlp_g),
         **_shared_consts(grid))
+
+
+def stream_record(bank, sub, is_write, S: int):
+    """A closed stream request's packed record, ``gsub << 1 | is_write``
+    with ``gsub = bank * S + sub`` the request's global subarray (the
+    column of the [G, B*S] planes). ``B * S`` stays far below 2**30 in
+    any layout, so the record fits an int32."""
+    return ((bank * S + sub) << 1) | is_write
 
 
 # ------------------------------------------------------------ lane picks
@@ -158,6 +169,22 @@ def pick(plane, idx):
     if plane.dtype == jnp.bool_:
         return (hot & plane).any(axis=-1)
     return jnp.where(hot, plane, 0).sum(axis=-1, dtype=plane.dtype)
+
+
+def next_requests(cfg: TickCfg, cst: dict, next_idx):
+    """Each core's request at its stream position ``next_idx`` [G, C]
+    (clamped to the stream's last entry), unpacked from the [G*C, N]
+    stream planes: ``(bank, sub, is_write, row, think)``, each [G, C].
+    Each plane is read by a one-hot `pick` over its N lanes; the clamped
+    index is in range, so it reads what a gather would, bit for bit."""
+    sl = jnp.minimum(next_idx, cfg.N - 1).ravel()
+
+    def read(plane):
+        return pick(plane, sl).reshape(next_idx.shape)
+
+    rec, row, think = read(cst["srec"]), read(cst["sr"]), read(cst["sth"])
+    gsub = rec >> 1
+    return gsub // cfg.S, gsub % cfg.S, (rec & 1) == 1, row, think
 
 
 # ------------------------------------------------------------- state zero
@@ -515,12 +542,10 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
     B, S = cfg.B, cfg.S
     NB, R, NC = cfg.NB, cfg.R, cfg.NC
     RBC = cfg.NR * cfg.NB            # banks per channel
-    C, N = cfg.C, cfg.N
+    C = cfg.C
     LQ = cfg.LQ
     QM = LQ - 1
     HI, LO, CAP = cfg.HI, cfg.LO, cfg.CAP
-    sw, sb, sr = cst["sw"], cst["sb"], cst["sr"]
-    ssub, sth = cst["ssub"], cst["sth"]
     n_req, mlp_col = cst["n_req"], cst["mlp"][:, None]
     phase, rank_phase = cst["phase"], cst["rank_phase"]
     kind, level_ab = cst["kind"], cst["level_ab"]
@@ -533,7 +558,6 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
     arG = jnp.arange(G)
     arB = jnp.arange(B)
     arC = jnp.arange(C)
-    flat_gc = arG[:, None] * C + arC[None, :]
     sub_of_col = jnp.tile(jnp.arange(S, dtype=jnp.int32), B)[None, :]
     OOB = G * B * LQ                       # scatter target for non-issues
 
@@ -549,34 +573,35 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
 
         # ---- 1: core issue (at most one per core per tick, core order)
         next_idx = s["next_idx"]
-        sl = jnp.minimum(next_idx, N - 1)
-        head_w = sw[flat_gc, sl]
+        hb, req_sub, head_w, req_row, req_think = next_requests(
+            cfg, cst, next_idx)
         can = (next_idx < n_req) & (s["next_issue"] <= t)
         want_w = can & head_w
         want_r = can & ~head_w & (out_reads < mlp_col)
         rank_w = jnp.cumsum(want_w, axis=1) - want_w
         ok_w = want_w & (rank_w < (CAP - s["wpend"])[:, None])
         issue = ok_w | want_r
-        hb = sb[flat_gc, sl]
         oh = issue[:, :, None] & (hb[:, :, None] == arB[None, None, :])
         pref = jnp.cumsum(oh, axis=1) - oh
-        pos_in = jnp.take_along_axis(pref, hb[:, :, None], axis=2)[:, :, 0]
-        tail_b = jnp.take_along_axis(s["q_tail"], hb, axis=1)
+        # each issue's place among its bank's appends this tick, and the
+        # bank's tail, by one-hot selects over the B lanes
+        pos_in = pick(pref, hb)
+        tail_b = pick(jnp.broadcast_to(s["q_tail"][:, None, :], (G, C, B)),
+                      hb)
         slot = (tail_b + pos_in) & QM
         tgt = jnp.where(issue, (arG[:, None] * B + hb) * LQ + slot, OOB)
         tgtf = tgt.ravel()
         qa = s["qa"].at[tgtf].set(jnp.full(G * C, t, jnp.int32),
                                   mode="drop")
-        qr = s["qr"].at[tgtf].set(sr[flat_gc, sl].ravel(), mode="drop")
-        qs_ = s["qs"].at[tgtf].set(ssub[flat_gc, sl].ravel(), mode="drop")
+        qr = s["qr"].at[tgtf].set(req_row.ravel(), mode="drop")
+        qs_ = s["qs"].at[tgtf].set(req_sub.ravel(), mode="drop")
         qc = s["qc"].at[tgtf].set(
             ((arC[None, :] << 1) | head_w).ravel(), mode="drop")
         q_tail = s["q_tail"] + oh.sum(axis=1)
         wpend = s["wpend"] + ok_w.sum(axis=1)
         out_reads = out_reads + want_r
         remaining = remaining - ok_w          # writes retire at issue
-        next_issue = jnp.where(issue, t + sth[flat_gc, sl],
-                               s["next_issue"])
+        next_issue = jnp.where(issue, t + req_think, s["next_issue"])
         next_idx = next_idx + issue
         finish = jnp.where((remaining == 0) & (s["finish"] < 0), t,
                            s["finish"])

@@ -37,6 +37,10 @@ SPECS = {
 }
 #: refresh units and bank groups each spec's cells have
 UNITS = {"closed": (8, 1), "open": (8, 1), "groups": (4, 4)}
+#: the stream lanes each spec's loop picks a core's next request over:
+#: the closed specs' N (160 requests over 4 and 8 cores); open mode has
+#: no streams
+PICK_LANES = {"closed": 40, "open": 0, "groups": 20}
 
 
 def _final_t(spec) -> int:
@@ -82,11 +86,14 @@ def test_finalize_carries_cells_and_loop_iterations(traced):
     assert all(not stats[n] for n in SPANS[:-1])
     counters = stats["sweep.finalize"]
     assert set(counters) == {"cells", "loop_iterations", "refresh_units",
-                             "bank_groups"}
+                             "bank_groups", "stream_pick_lanes"}
     assert counters["cells"] == len(res.cells) == len(SPECS[name].cells())
     assert counters["loop_iterations"] == _final_t(SPECS[name])
     assert (counters["refresh_units"],
             counters["bank_groups"]) == UNITS[name]
+    assert counters["stream_pick_lanes"] == PICK_LANES[name]
+    if SPECS[name].mode == "closed":
+        assert PICK_LANES[name] == _Grid(SPECS[name]).N
     ticks = [round(c.makespan / SPECS[name].dt_ns) for c in res.cells]
     if SPECS[name].mode == "closed":
         # a core finishes at the tick its last request retires: the loop

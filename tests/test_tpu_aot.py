@@ -97,3 +97,9 @@ def test_jax_backend_loop_compiles_for_v5e(one_chip, mode):
         assert appends
         pred_appends = [ln for ln in appends if "= pred[" in ln]
         assert not pred_appends, pred_appends[:3]
+        # each core's next request (from the packed stream record, `sr`
+        # and `sth`), its place among the appends and its bank's tail
+        # are one-hot lane selects too
+        front_gathers = [ln for ln in hlo.splitlines()
+                         if " gather(" in ln and "/tick.front_end/" in ln]
+        assert not front_gathers, front_gathers[:3]
