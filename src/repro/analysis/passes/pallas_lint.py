@@ -259,17 +259,35 @@ def _own_returns(fn: ast.FunctionDef) -> list[ast.Return]:
     return out
 
 
+def _splat_keys(v: ast.expr) -> set[str] | None:
+    """String keys of a ``**`` splat built of dict literals, such as
+    ``**({"k": x} if cfg.flag else {})`` (a plane only some grids
+    carry); None for any other splat."""
+    if isinstance(v, ast.IfExp):
+        a, b = _splat_keys(v.body), _splat_keys(v.orelse)
+        return None if a is None or b is None else a | b
+    if isinstance(v, ast.Dict) and all(
+            isinstance(k, ast.Constant) and isinstance(k.value, str)
+            for k in v.keys):
+        return {k.value for k in v.keys}
+    return None
+
+
 def _returned_dict_keys(fn: ast.FunctionDef) -> set[str] | None:
-    """Union of keyword names over every ``return dict(...)`` of ``fn``;
-    None when no return is a ``dict(...)`` keyword call (not a state
-    function in the jaxbody idiom — nothing to check)."""
+    """Union of keyword names over every ``return dict(...)`` of ``fn``,
+    with the keys of its dict-literal ``**`` splats; None when no return
+    is such a ``dict(...)`` call (not a state function in the jaxbody
+    idiom — nothing to check)."""
     keys: set[str] | None = None
     for ret in _own_returns(fn):
         v = ret.value
-        if (isinstance(v, ast.Call) and isinstance(v.func, ast.Name)
-                and v.func.id == "dict" and v.keywords
-                and all(kw.arg for kw in v.keywords)):
-            keys = (keys or set()) | {kw.arg for kw in v.keywords}
+        if not (isinstance(v, ast.Call) and isinstance(v.func, ast.Name)
+                and v.func.id == "dict" and v.keywords):
+            continue
+        got = [{kw.arg} if kw.arg else _splat_keys(kw.value)
+               for kw in v.keywords]
+        if all(k is not None for k in got):
+            keys = (keys or set()).union(*got)
     return keys
 
 

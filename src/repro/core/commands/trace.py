@@ -41,6 +41,11 @@ TIMING_FIELDS = ("REFI", "REFI_PB", "RFC_AB", "RFC_PB", "TRP", "HIT",
 #: same-group serve adder (docs/tick-contract.md section 7).
 BANK_GROUP_FIELDS = ("n_bank_groups", "REFI_SB", "CCDL")
 
+#: Fields only the traces of a part with a per-bank refresh issue rule
+#: carry (tick clock): the tpbR2pbR spacing in ticks (0 = none) and the
+#: one-per-round rule (docs/tick-contract.md section 7).
+REFPB_RULE_FIELDS = ("PB2PB", "REFPB_ROUNDS")
+
 # Canonical intra-tick order: decisions (precharges/refreshes) precede
 # serves, matching the per-tick phase order (phases 3-4 before phase 5).
 _OP_ORDER = {"PREA": 0, "PRE": 1, "ACT": 2, "REF_AB": 3, "REF_PB": 4,
@@ -73,7 +78,8 @@ class CmdTrace:
     ``meta`` carries the hierarchy (n_banks/n_ranks/n_channels/
     n_subarrays), the policy traits the validator needs (level, sarp,
     hra, ideal), the clock, every `TIMING_FIELDS` constant (and with
-    bank groups every `BANK_GROUP_FIELDS` one), and ``dram``, the
+    bank groups every `BANK_GROUP_FIELDS` one, with a per-bank refresh
+    issue rule every `REFPB_RULE_FIELDS` one), and ``dram``, the
     `DramTiming` the run simulated, field by field.
     ``demand`` (tick traces only) optionally carries the raw per-core
     request streams so `repro.core.commands.replay` can re-drive the
@@ -208,6 +214,10 @@ def tick_meta(T, pol, dt_ns: float, *, scenario: Optional[str] = None,
         m.update({"n_bank_groups": int(T.n_bank_groups),
                   "REFI_SB": max(1, REFI // T.n_refresh_units),
                   "CCDL": tk(T.tCCD_L) - tk(T.tCCD_S)})
+    if T.has_refpb_rules:
+        from repro.core.refresh.timing import spacing_ticks
+        m.update({"PB2PB": spacing_ticks(T.tpbR2pbR, dt_ns),
+                  "REFPB_ROUNDS": bool(T.refpb_rounds)})
     return m
 
 

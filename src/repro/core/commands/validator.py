@@ -25,6 +25,13 @@ emitted `CmdTrace` is realizable on a real controller:
                          group: tCCD_L) per the phase-5 serve rule.
                          Event-mode ns traces skip this rule
                          (tick-contract section 5 divergence).
+* ``refpb-spacing``    — tick clock, where the trace states a spacing
+                         (``PB2PB`` > 0, tpbR2pbR): two REF_PB (REF_SB)
+                         on one rank must be at least ``PB2PB`` apart.
+* ``refpb-round``      — where the trace states refresh rounds
+                         (``REFPB_ROUNDS``): no refresh unit of a rank
+                         gets a REF_PB (REF_SB) while another unit of
+                         that rank has had fewer.
 * ``bad-sequence``     — structural breakage: access to a closed row
                          without a same-tick ACT, more than one serve
                          start per channel per tick, a SARP refresh
@@ -44,7 +51,8 @@ from repro.core.commands.trace import CmdTrace, _key
 
 #: Rule identifiers, in severity-agnostic catalog order.
 RULES = ("missing-prea", "short-trp", "short-trfc", "postpone-budget",
-         "trtr-min-latency", "bad-sequence")
+         "trtr-min-latency", "bad-sequence", "refpb-spacing",
+         "refpb-round")
 
 
 @dataclass(frozen=True)
@@ -118,6 +126,9 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
     level = m.get("level", "pb")
     HIT, MISS = m["HIT"], m["MISS"]
     TURN, RTR, SARP_PEN = m["TURN"], m["RTR"], m["SARP_PEN"]
+    # the per-bank refresh issue rules the trace states, if any
+    PB2PB = int(m.get("PB2PB", 0)) if tick_clock else 0
+    ROUNDS = bool(m.get("REFPB_ROUNDS", False))
 
     cmds = sorted(trace.cmds, key=_key)
     out: List[Violation] = []
@@ -131,6 +142,7 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
     ctr = [0] * B                     # refresh-target rotation (ctr % S)
     issued_pb = [0] * U
     issued_ab = [0] * R
+    last_pb = [None] * R              # tick of each rank's last REF_PB/SB
     # phase offsets match the engines: per-unit pb staggering and
     # per-rank ab staggering (tick-contract sections 3 and 4).
     phase = [u * REFI_U for u in range(U)]
@@ -268,6 +280,19 @@ def validate_trace(trace: CmdTrace, *, limit: int = 64) -> List[Violation]:
                              f"expects s{ctr[b] % S}")
                     ctr[b] += 1
                 u = unit_of(gb)
+                if PB2PB and last_pb[gr] is not None \
+                        and t - last_pb[gr] < PB2PB:
+                    emit("refpb-spacing", t, idx, addr,
+                         f"{c.op} {t - last_pb[gr]} ticks after the "
+                         f"rank's last, below tpbR2pbR {PB2PB}")
+                last_pb[gr] = t
+                if ROUNDS:
+                    rank_units = issued_pb[gr * BPG:(gr + 1) * BPG]
+                    if issued_pb[u] > min(rank_units):
+                        emit("refpb-round", t, idx, addr,
+                             f"{c.op} #{issued_pb[u] + 1} of its unit "
+                             f"while another unit of the rank has had "
+                             f"{min(rank_units)}")
                 issued_pb[u] += 1
                 if level == "pb" and not ideal:
                     td = c.data if c.data >= 0 else t - TRP

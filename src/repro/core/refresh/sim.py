@@ -514,7 +514,7 @@ class DramSim:
         from repro.core.refresh.workload import quantize_streams
         from repro.core.sweep.arbiter import (AGE_CAP, OCC_CAP, W_HIT,
                                               W_NOCONF, W_OCC, W_WRITE)
-        from repro.core.refresh.timing import refresh_units
+        from repro.core.refresh.timing import refresh_units, spacing_ticks
         from repro.core.sweep.engine import (MAX_LAT_TICKS, _p99_ticks,
                                              _scalar_refreshing_sub,
                                              _unit_lists)
@@ -544,6 +544,10 @@ class DramSim:
         TRP = tkq(T.tRP)
         budget = T.refresh_budget
         rank_phase = [gr * (REFI // R) for gr in range(R)]
+        # per-bank issue rules over the units of a rank: the tpbR2pbR
+        # spacing (ticks, rounded up; 0 = none) and refresh rounds
+        PB2PB, ROUNDS = spacing_ticks(T.tpbR2pbR, dt_ns), T.refpb_rounds
+        pb_next = [0] * R                # per rank: spacing reopens at
 
         streams = quantize_streams(self.streams, dt_ns)
         C, mlp = len(streams), self.wl.mlp
@@ -600,6 +604,16 @@ class DramSim:
         timeline = ({"refresh": [], "serves": []} if record_timeline
                     else None)
 
+        def pb_rules_ok(u: int, t: int) -> bool:
+            gr = unit_rank[u]
+            if PB2PB and t < pb_next[gr]:
+                return False
+            if not ROUNDS:
+                return True
+            mine = led.banks[u].issued
+            return all(mine <= led.banks[v].issued
+                       for v in range(gr * BPG, (gr + 1) * BPG))
+
         def start_pb(u: int, t: int):
             nonlocal refpb, maxlag
             us = units[u]
@@ -633,6 +647,8 @@ class DramSim:
                 ctr[b] += 1
             refpb += 1
             maxlag = max(maxlag, abs(led.lag(u, float(t))))
+            if PB2PB:
+                pb_next[unit_rank[u]] = start + PB2PB
 
         def start_ab(gr: int, t: int):
             nonlocal refab
@@ -750,20 +766,26 @@ class DramSim:
                                         if ab_pending[gr] > 0:
                                             start_ab(gr, t)
                 else:
+                    lists = _unit_lists(
+                        units, demand=[len(q[b]) for b in range(B)],
+                        ready=[all(ru <= t for ru in ref_until_s[b])
+                               for b in range(B)],
+                        idle=[bank_free[b] <= t for b in range(B)],
+                        next_sub=[ctr[b] % S for b in range(B)],
+                        refreshing=[
+                            _scalar_refreshing_sub(ref_until_s[b], t)
+                            for b in range(B)],
+                        active=open_sub)
+                    if PB2PB or ROUNDS:
+                        # the issue rules narrow which units are ready
+                        lists["ready"] = [
+                            r and pb_rules_ok(u, t)
+                            for u, r in enumerate(lists["ready"])]
                     view = led.view(
                         float(t), write_window=drain,
                         n_ranks=T.n_ranks, n_channels=NC,
                         rank_of=unit_rank, channel_of=unit_chan,
-                        n_subarrays=S, **_unit_lists(
-                            units, demand=[len(q[b]) for b in range(B)],
-                            ready=[all(ru <= t for ru in ref_until_s[b])
-                                   for b in range(B)],
-                            idle=[bank_free[b] <= t for b in range(B)],
-                            next_sub=[ctr[b] % S for b in range(B)],
-                            refreshing=[
-                                _scalar_refreshing_sub(ref_until_s[b], t)
-                                for b in range(B)],
-                            active=open_sub))
+                        n_subarrays=S, **lists)
                     decs = pol.select(view)
                     for dec in decs:
                         if dec.bank == ALL_BANKS:
@@ -771,6 +793,14 @@ class DramSim:
                                 f"policy {pol.name!r} returned ALL_BANKS "
                                 "from a per-bank (level='pb') decision "
                                 "point")
+                    if PB2PB:
+                        # one start a rank a tick: its lowest picked unit;
+                        # the spacing closes the rank to the others, and
+                        # they are not issued
+                        kept = {}
+                        for dec in sorted(decs, key=lambda d: d.bank):
+                            kept.setdefault(unit_rank[dec.bank], dec)
+                        decs = list(kept.values())
                     for b in led.apply(decs, float(t)):
                         start_pb(b, t)
             # 5: occupancy-aware arbitration (one start per CHANNEL per
@@ -872,6 +902,11 @@ class DramSim:
             raise ValueError(
                 "event mode models no bank groups (n_bank_groups="
                 f"{T.n_bank_groups}); run_ticks() does")
+        if T.has_refpb_rules:
+            raise ValueError(
+                "event mode models no per-bank refresh issue rules "
+                f"(tpbR2pbR={T.tpbR2pbR}, refpb_rounds={T.refpb_rounds}); "
+                "run_ticks() does")
         nb, ncore = T.n_banks_total, self.wl.n_cores
         R = T.n_ranks_total
 

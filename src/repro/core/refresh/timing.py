@@ -8,9 +8,16 @@ bank-group column-to-column delays, `tCCD_L` (same group) and `tCCD_S`
 (other group). Its per-bank level refreshes a same-bank set (DDR5
 REFsb): bank k of every group of a rank at once (docs/tick-contract.md
 section 3), with `tRFC_pb` as the set's refresh latency (tRFCsb).
+
+A part whose controller addresses each per-bank refresh (LPDDR4 REFpb)
+may state the two issue rules of such refreshes over the refresh units of
+one rank (docs/tick-contract.md section 3): `tpbR2pbR`, the least time
+between two of them on a rank (0: none), and `refpb_rounds`, that no unit
+is refreshed again before every unit of its rank has been.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional
 
@@ -44,6 +51,9 @@ class DramTiming:
     tRFC_ab: float = 350.0        # all-bank refresh latency (density-scaled)
     tRFC_pb: float = 90.0         # per-bank refresh latency (density-scaled)
     refresh_budget: int = 8       # max postponed/pulled-in commands (JEDEC)
+    # per-bank refresh issue rules over the refresh units of one rank
+    tpbR2pbR: float = 0.0         # least gap between two starts (0: none)
+    refpb_rounds: bool = False    # one per unit per round of the rank
 
     # SARP: a refreshing bank can serve other-subarray accesses with a small
     # added latency for the shared peripheral handoff (paper §5: row-address
@@ -69,6 +79,22 @@ class DramTiming:
                 "bank-group timings need bank groups")
         if given and self.tCCD_L < self.tCCD_S:
             raise ValueError(f"tCCD_L={self.tCCD_L} < tCCD_S={self.tCCD_S}")
+        if not self.tpbR2pbR >= 0:
+            raise ValueError(f"tpbR2pbR={self.tpbR2pbR} must be >= 0 ns")
+        if self.tpbR2pbR * self.banks_per_group > self.tREFI:
+            # a rank's round of spaced refreshes outlasts tREFI: no
+            # controller can keep the postpone budget
+            raise ValueError(
+                f"tpbR2pbR={self.tpbR2pbR} ns x {self.banks_per_group} "
+                f"refresh units a rank exceeds tREFI={self.tREFI} ns")
+        if not isinstance(self.refpb_rounds, bool):
+            raise TypeError(
+                f"refpb_rounds={self.refpb_rounds!r} must be a bool")
+
+    @property
+    def has_refpb_rules(self) -> bool:
+        """Whether per-bank refreshes obey either issue rule."""
+        return self.tpbR2pbR > 0 or self.refpb_rounds
 
     @property
     def n_ranks_total(self) -> int:
@@ -120,6 +146,13 @@ class DramTiming:
 _TRFC = {8: (350.0, 150.0), 16: (530.0, 230.0), 32: (890.0, 380.0)}
 
 DENSITIES = tuple(sorted(_TRFC))
+
+
+def spacing_ticks(ns: float, dt_ns: float) -> int:
+    """A least spacing in whole ticks: ``ceil(ns / dt_ns)``, 0 for none.
+    Rounded up, unlike the latencies' nearest tick, since it is a floor
+    the commands must keep (a hair of float error does not add a tick)."""
+    return max(1, math.ceil(ns / dt_ns - 1e-9)) if ns > 0 else 0
 
 
 def timing_for_density(density_gb: int, **kw) -> DramTiming:

@@ -132,13 +132,15 @@ from repro.core.policy import ALL_BANKS, MaintenanceView, resolve_policy
 from repro.core.refresh.scenarios import (ClosedDemand, Trace,
                                           make_closed_demand, make_trace)
 from repro.core.refresh.timing import (DramTiming, refresh_units,
-                                       timing_for_density)
+                                       spacing_ticks, timing_for_density)
 from repro.core.sweep.arbiter import (AGE_CAP, OCC_CAP, W_HIT, W_NOCONF,
                                       W_OCC, W_WRITE, arbiter_scores,
                                       arbiter_scores_masked)
 from repro.core.sweep.policies import (KIND_AB, KIND_CUSTOM, KIND_IDEAL,
                                        KIND_STAG, classify, could_pick,
-                                       per_bank, per_unit, select_batch)
+                                       per_bank, per_unit, refpb_rules_first,
+                                       refpb_rules_next, refpb_rules_ready,
+                                       select_batch)
 
 #: read-latency histogram width (ticks); larger waits clip into the top bin
 MAX_LAT_TICKS = 4095
@@ -157,7 +159,9 @@ class TickTiming:
     bank groups one same-bank set per rank and bank of a group; equal to
     `REFI_PB` without groups). `CCDL` is the tCCD_L - tCCD_S serve adder
     of a start in the bank group of its channel's previous start (0
-    without groups)."""
+    without groups). `PB2PB` is tpbR2pbR rounded up to whole ticks (0:
+    no spacing) and `PB_ROUNDS` the one-per-round rule, the per-bank
+    issue rules over the refresh units of a rank."""
     density_gb: int
     dt_ns: float
     REFI: int
@@ -175,6 +179,8 @@ class TickTiming:
     CCDL: int                    # same-bank-group column delay adder
     SARP_PEN: int
     budget: int
+    PB2PB: int                   # least ticks between a rank's pb starts
+    PB_ROUNDS: bool              # one pb refresh per unit per round
 
     @classmethod
     def from_density(cls, density_gb: int, dt_ns: float = 6.0,
@@ -201,7 +207,9 @@ class TickTiming:
                    RTR=tk(T.tRTR),
                    CCDL=(tk(T.tCCD_L) - tk(T.tCCD_S)
                          if T.n_bank_groups > 1 else 0),
-                   SARP_PEN=tk(T.sarp_penalty), budget=T.refresh_budget)
+                   SARP_PEN=tk(T.sarp_penalty), budget=T.refresh_budget,
+                   PB2PB=spacing_ticks(T.tpbR2pbR, dt_ns),
+                   PB_ROUNDS=T.refpb_rounds)
 
 
 @dataclass(frozen=True)
@@ -500,8 +508,9 @@ class _Grid:
         self.urgent_at = np.ones(G, np.int32)
         self.budget = ints()
         for f in ("REFI", "RFC_PB", "RFC_AB", "TRP", "HIT", "MISS", "WR",
-                  "TURN", "RTR", "CCDL", "SARP_PEN"):
+                  "TURN", "RTR", "CCDL", "SARP_PEN", "PB2PB"):
             setattr(self, f, ints())
+        self.pb_rounds = np.zeros(G, bool)
         # per-(cell, refresh unit) pb debt phase: unit u's first refresh
         # comes due u * tREFI/U in
         self.phase = np.zeros((G, self.U), np.int32)
@@ -537,8 +546,9 @@ class _Grid:
             self.urgent_at[g] = params.get("urgent_at", 1)
             self.budget[g] = tk.budget
             for f in ("REFI", "RFC_PB", "RFC_AB", "TRP", "HIT", "MISS",
-                      "WR", "TURN", "RTR", "CCDL", "SARP_PEN"):
+                      "WR", "TURN", "RTR", "CCDL", "SARP_PEN", "PB2PB"):
                 getattr(self, f)[g] = getattr(tk, f)
+            self.pb_rounds[g] = tk.PB_ROUNDS
             self.phase[g] = np.arange(self.U, dtype=np.int32) * tk.REFI_SB
             self.rank_phase[g] = (np.arange(self.R, dtype=np.int32)
                                   * (tk.REFI // self.R))
@@ -566,6 +576,10 @@ class _Grid:
 
         self.has_stag = bool((self.kind == KIND_STAG).any())
         self.has_hra = bool(self.hra.any())
+        # the per-bank issue rules (tpbR2pbR, refresh rounds) bind in
+        # some cell: only then do the backends carry `pb_next`
+        self.has_pb_rules = bool((self.PB2PB > 0).any()
+                                 or self.pb_rounds.any())
 
         svc = int(self.MISS.max() + self.WR.max() + self.TURN.max() + 2)
         if self.closed:
@@ -622,6 +636,16 @@ def _unit_lists(units, *, demand, ready, idle, next_sub, refreshing,
         active_sub=tuple(active[us[0]] if all(active[b] == active[us[0]]
                                               for b in us) else -1
                          for us in units))
+
+
+def _spaced(decs, units_per_rank: int) -> list:
+    """The per-bank decisions a tpbR2pbR spacing lets start in one tick:
+    on each rank, the one on its lowest unit (the scalar oracle's copy
+    of `policies.refpb_rules_first`), in unit order."""
+    first = {}
+    for d in sorted(decs, key=lambda d: d.bank):
+        first.setdefault(d.bank // units_per_rank, d)
+    return list(first.values())
 
 
 def _p99_ticks(hist_row: np.ndarray, n_reads: int) -> int:
@@ -690,6 +714,7 @@ def _run_batched(grid: _Grid) -> list[CellResult]:
     open_sub = np.full((G, B), -1, np.int32)
     ctr = np.zeros((G, B), np.int32)
     issued = np.zeros((G, grid.U), np.int32)     # per refresh unit
+    pb_next = np.zeros((G, R), np.int32)   # per rank: pb spacing reopens
     n_arrived = np.zeros((G, B), np.int32)
     n_served = np.zeros((G, B), np.int32)
     rr = np.zeros(G, np.int32)
@@ -783,6 +808,11 @@ def _run_batched(grid: _Grid) -> list[CellResult]:
         # without bank groups
         demand_u = per_unit(demand, "sum", R, NBG)
         ready_u = per_unit(ready, "all", R, NBG)
+        if grid.has_pb_rules:
+            # the per-bank issue rules narrow which units are ready
+            ready_u = ready_u & refpb_rules_ready(
+                t=t, issued=issued, pb_next=pb_next,
+                spacing=grid.PB2PB, rounds=grid.pb_rounds, n_ranks_total=R)
         idle_u = per_unit(idle, "all", R, NBG)
         need = could_pick(kind=kind_active, lag=lag, demand=demand_u,
                           write_window=drain, budget=budget_g, wrp=wrp_g)
@@ -855,13 +885,15 @@ def _run_batched(grid: _Grid) -> list[CellResult]:
                     write_window=bool(drain[g]), max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
                     rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
-                    n_subarrays=S, **_unit_lists(
+                    n_subarrays=S, **{**_unit_lists(
                         grid.units, demand=demand[g].tolist(),
                         ready=ready[g].tolist(), idle=idle[g].tolist(),
                         next_sub=[int(x) % S for x in ctr[g]],
                         refreshing=_refreshing_subs(
                             ref_until_s[g].reshape(B, S), t),
-                        active=[int(x) for x in open_sub[g]]))
+                        active=[int(x) for x in open_sub[g]]),
+                        # the units' readiness, the issue rules applied
+                        "ready": ready_u[g].tolist()})
                 for dec in pol.select(view):
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
@@ -888,6 +920,8 @@ def _run_batched(grid: _Grid) -> list[CellResult]:
             refab += start_ab_r.sum(axis=1)
 
         if picks is not None:
+            if grid.has_pb_rules:
+                picks = refpb_rules_first(np, picks, grid.PB2PB, R)
             picks_b = per_bank(np, picks, R, NBG)
             new_sub = (ctr % S).astype(np.int32)
             # HiRA hidden row activation: when the refresh targets a
@@ -898,7 +932,11 @@ def _run_batched(grid: _Grid) -> list[CellResult]:
             start = np.maximum(t, bank_free)
             start = np.where(hra_c & (new_sub != open_sub), t, start)
             # a same-bank set starts once every bank of it can
-            start = per_bank(np, per_unit(start, "max", R, NBG), R, NBG)
+            start_u = per_unit(start, "max", R, NBG)
+            if grid.has_pb_rules:
+                pb_next = refpb_rules_next(np, picks, start_u, pb_next,
+                                           grid.PB2PB, R)
+            start = per_bank(np, start_u, R, NBG)
             mark = (np.repeat(picks_b, S, axis=1)
                     & np.where(sarp_c, np.repeat(new_sub, S, axis=1)
                                == sub_of_col, True))
@@ -1062,6 +1100,7 @@ def _run_batched_closed(grid: _Grid, *, record_commands: bool = False):
     open_sub = np.full((G, B), -1, np.int32)
     ctr = np.zeros((G, B), np.int32)
     issued = np.zeros((G, grid.U), np.int32)     # per refresh unit
+    pb_next = np.zeros((G, R), np.int32)   # per rank: pb spacing reopens
     rr = np.zeros(G, np.int32)
     ab_rr = np.zeros(G, np.int32)          # staggered_ab rank pointer
     wpend = np.zeros(G, np.int32)
@@ -1183,6 +1222,11 @@ def _run_batched_closed(grid: _Grid, *, record_commands: bool = False):
         # without bank groups
         demand_u = per_unit(demand, "sum", R, NBG)
         ready_u = per_unit(ready, "all", R, NBG)
+        if grid.has_pb_rules:
+            # the per-bank issue rules narrow which units are ready
+            ready_u = ready_u & refpb_rules_ready(
+                t=t, issued=issued, pb_next=pb_next,
+                spacing=grid.PB2PB, rounds=grid.pb_rounds, n_ranks_total=R)
         idle_u = per_unit(idle, "all", R, NBG)
         need = could_pick(kind=kind_active, lag=lag, demand=demand_u,
                           write_window=drain, budget=budget_g, wrp=wrp_g)
@@ -1255,13 +1299,15 @@ def _run_batched_closed(grid: _Grid, *, record_commands: bool = False):
                     write_window=bool(drain[g]), max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
                     rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
-                    n_subarrays=S, **_unit_lists(
+                    n_subarrays=S, **{**_unit_lists(
                         grid.units, demand=demand[g].tolist(),
                         ready=ready[g].tolist(), idle=idle[g].tolist(),
                         next_sub=[int(x) % S for x in ctr[g]],
                         refreshing=_refreshing_subs(
                             ref_until_s[g].reshape(B, S), t),
-                        active=[int(x) for x in open_sub[g]]))
+                        active=[int(x) for x in open_sub[g]]),
+                        # the units' readiness, the issue rules applied
+                        "ready": ready_u[g].tolist()})
                 for dec in pol.select(view):
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
@@ -1293,6 +1339,8 @@ def _run_batched_closed(grid: _Grid, *, record_commands: bool = False):
                                        int(r_), data=t)
 
         if picks is not None:
+            if grid.has_pb_rules:
+                picks = refpb_rules_first(np, picks, grid.PB2PB, R)
             picks_b = per_bank(np, picks, R, NBG)
             new_sub = (ctr % S).astype(np.int32)
             # HiRA hidden row activation: when the refresh targets a
@@ -1303,7 +1351,11 @@ def _run_batched_closed(grid: _Grid, *, record_commands: bool = False):
             start = np.maximum(t, bank_free)
             start = np.where(hra_c & (new_sub != open_sub), t, start)
             # a same-bank set starts once every bank of it can
-            start = per_bank(np, per_unit(start, "max", R, NBG), R, NBG)
+            start_u = per_unit(start, "max", R, NBG)
+            if grid.has_pb_rules:
+                pb_next = refpb_rules_next(np, picks, start_u, pb_next,
+                                           grid.PB2PB, R)
+            start = per_bank(np, start_u, R, NBG)
             if recs is not None:
                 # a same-bank set: PRE on each bank, one REF_SB naming
                 # its first bank (bank k of group 0)
@@ -1457,6 +1509,8 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
     open_sub = [-1] * B
     ctr = [0] * B
     issued = [0] * grid.U                 # per refresh unit
+    pb_next = [0] * R                     # per rank: pb spacing reopens
+    SP, ROUNDS, K = tk.PB2PB, tk.PB_ROUNDS, grid.BPG
     n_arrived = [0] * B
     n_served = [0] * B
     wpend = 0
@@ -1499,6 +1553,16 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
         issued[u] += 1
         refpb += 1
         maxlag = max(maxlag, abs(due(u, t) - issued[u]))
+        if SP:
+            pb_next[u // K] = start + SP
+
+    def rules_ok(u: int, t: int) -> bool:
+        """The per-bank issue rules: the rank's spacing has passed, and
+        the unit has had no more refreshes than any other of its rank."""
+        gr = u // K
+        if SP and t < pb_next[gr]:
+            return False
+        return not ROUNDS or issued[u] == min(issued[gr * K:(gr + 1) * K])
 
     def start_ab(gr: int, t: int):
         nonlocal refab
@@ -1576,28 +1640,33 @@ def _run_scalar_cell(grid: _Grid, g: int) -> CellResult:
                 if sum(ab_pending) > 0:
                     apply_ab_decisions(pol.select(ab_view(t)), t)
             else:
+                lists = _unit_lists(
+                    grid.units,
+                    demand=[n_arrived[b] - n_served[b] for b in range(B)],
+                    ready=[all(ru <= t for ru in ref_until_s[b])
+                           for b in range(B)],
+                    idle=[bank_free[b] <= t for b in range(B)],
+                    next_sub=[ctr[b] % S for b in range(B)],
+                    refreshing=[_scalar_refreshing_sub(ref_until_s[b], t)
+                                for b in range(B)],
+                    active=open_sub)
+                if SP or ROUNDS:
+                    lists["ready"] = [r and rules_ok(u, t)
+                                      for u, r in enumerate(lists["ready"])]
                 view = MaintenanceView(
                     now=float(t), n_banks=grid.U, budget=budget,
                     lag=[due(u, t) - issued[u] for u in range(grid.U)],
                     write_window=drain, max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
                     rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
-                    n_subarrays=S, **_unit_lists(
-                        grid.units,
-                        demand=[n_arrived[b] - n_served[b]
-                                for b in range(B)],
-                        ready=[all(ru <= t for ru in ref_until_s[b])
-                               for b in range(B)],
-                        idle=[bank_free[b] <= t for b in range(B)],
-                        next_sub=[ctr[b] % S for b in range(B)],
-                        refreshing=[_scalar_refreshing_sub(ref_until_s[b], t)
-                                    for b in range(B)],
-                        active=open_sub))
-                for dec in pol.select(view):
+                    n_subarrays=S, **lists)
+                decs = pol.select(view)
+                for dec in decs:
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
                             f"policy {pol.name!r} returned ALL_BANKS from "
                             f"a per-bank (level='pb') decision point")
+                for dec in (_spaced(decs, K) if SP else decs):
                     start_pb(dec.bank, t)
         # D: arbitration (one start per channel; drain snapshotted)
         drain_arb = drain
@@ -1704,6 +1773,8 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
     open_sub = [-1] * B
     ctr = [0] * B
     issued = [0] * grid.U                 # per refresh unit
+    pb_next = [0] * R                     # per rank: pb spacing reopens
+    SP, ROUNDS, K = tk.PB2PB, tk.PB_ROUNDS, grid.BPG
     wpend = 0
     drain = False
     last_op = [False] * NC
@@ -1743,6 +1814,16 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
         issued[u] += 1
         refpb += 1
         maxlag = max(maxlag, abs(due(u, t) - issued[u]))
+        if SP:
+            pb_next[u // K] = start + SP
+
+    def rules_ok(u: int, t: int) -> bool:
+        """The per-bank issue rules: the rank's spacing has passed, and
+        the unit has had no more refreshes than any other of its rank."""
+        gr = u // K
+        if SP and t < pb_next[gr]:
+            return False
+        return not ROUNDS or issued[u] == min(issued[gr * K:(gr + 1) * K])
 
     def start_ab(gr: int, t: int):
         nonlocal refab
@@ -1851,26 +1932,32 @@ def _run_scalar_cell_closed(grid: _Grid, g: int) -> CellResult:
                 if sum(ab_pending) > 0:
                     apply_ab_decisions(pol.select(ab_view(t)), t)
             else:
+                lists = _unit_lists(
+                    grid.units, demand=[len(q[b]) for b in range(B)],
+                    ready=[all(ru <= t for ru in ref_until_s[b])
+                           for b in range(B)],
+                    idle=[bank_free[b] <= t for b in range(B)],
+                    next_sub=[ctr[b] % S for b in range(B)],
+                    refreshing=[_scalar_refreshing_sub(ref_until_s[b], t)
+                                for b in range(B)],
+                    active=open_sub)
+                if SP or ROUNDS:
+                    lists["ready"] = [r and rules_ok(u, t)
+                                      for u, r in enumerate(lists["ready"])]
                 view = MaintenanceView(
                     now=float(t), n_banks=grid.U, budget=budget,
                     lag=[due(u, t) - issued[u] for u in range(grid.U)],
                     write_window=drain, max_issues=1,
                     n_ranks=grid.NR, n_channels=NC,
                     rank_of=grid.rank_of_u, channel_of=grid.chan_of_u,
-                    n_subarrays=S, **_unit_lists(
-                        grid.units, demand=[len(q[b]) for b in range(B)],
-                        ready=[all(ru <= t for ru in ref_until_s[b])
-                               for b in range(B)],
-                        idle=[bank_free[b] <= t for b in range(B)],
-                        next_sub=[ctr[b] % S for b in range(B)],
-                        refreshing=[_scalar_refreshing_sub(ref_until_s[b], t)
-                                    for b in range(B)],
-                        active=open_sub))
-                for dec in pol.select(view):
+                    n_subarrays=S, **lists)
+                decs = pol.select(view)
+                for dec in decs:
                     if dec.bank == ALL_BANKS:
                         raise ValueError(
                             f"policy {pol.name!r} returned ALL_BANKS from "
                             f"a per-bank (level='pb') decision point")
+                for dec in (_spaced(decs, K) if SP else decs):
                     start_pb(dec.bank, t)
         # ---- 5: arbitration (occupancy-aware; one start per channel;
         # drain snapshotted before any serve this tick)
@@ -1990,10 +2077,13 @@ def _run_jax(spec: SweepSpec) -> list[CellResult]:
     (`jax.device_get`) and ``sweep.finalize`` (the `CellResult`s), the
     last carrying the counters ``cells``, ``loop_iterations`` (the times
     the while loop ran), ``refresh_units`` (per cell: banks, or same-bank
-    sets with bank groups), ``bank_groups`` (per rank) and
+    sets with bank groups), ``bank_groups`` (per rank),
     ``stream_pick_lanes`` (the N stream lanes over which a closed loop
-    picks each core's next request; 0 in open mode). Without an active
-    profiler a span costs one inactive `TraceMe`."""
+    picks each core's next request; 0 in open mode),
+    ``refpb_spacing_ticks`` (the largest tpbR2pbR spacing of the grid's
+    DRAMs, in ticks; 0 without the rule) and ``refpb_rounds`` (1 where
+    some cell keeps refresh rounds, else 0). Without an active profiler
+    a span costs one inactive `TraceMe`."""
     import jax
     from jax.profiler import TraceAnnotation
 
@@ -2012,7 +2102,9 @@ def _run_jax(spec: SweepSpec) -> list[CellResult]:
     with TraceAnnotation("sweep.finalize", cells=grid.G,
                          loop_iterations=int(out["t"]),
                          refresh_units=grid.U, bank_groups=grid.NBG,
-                         stream_pick_lanes=cfg.N):
+                         stream_pick_lanes=cfg.N,
+                         refpb_spacing_ticks=int(grid.PB2PB.max()),
+                         refpb_rounds=int(grid.pb_rounds.any())):
         if grid.closed:
             finished = (out["remaining"] <= 0).all(axis=1)
             fin = np.where(out["finish"] < 0, int(out["t"]), out["finish"])

@@ -38,7 +38,9 @@ from jax import lax
 
 from repro.core.sweep.engine import MAX_LAT_TICKS, _PAD_ARRIVE
 from repro.core.sweep.policies import (KIND_AB, KIND_IDEAL, KIND_STAG,
-                                       per_bank, per_unit, select_batch)
+                                       per_bank, per_unit, refpb_rules_first,
+                                       refpb_rules_next, refpb_rules_ready,
+                                       select_batch)
 
 
 # ------------------------------------------------------------------ config
@@ -67,6 +69,7 @@ class TickCfg:
     LQ: int = 0             # closed: ring-queue capacity (power of two)
     CAP: int = 0            # closed: shared write-buffer capacity
     NBG: int = 1            # bank groups per rank
+    pb_rules: bool = False  # any cell with a per-bank issue rule
 
     @property
     def BPG(self) -> int:
@@ -85,7 +88,8 @@ def open_cfg(grid) -> TickCfg:
     return TickCfg(closed=False, B=grid.B, S=grid.S, NB=grid.NB,
                    NR=grid.NR, R=grid.R, NC=grid.NC, HI=spec.wbuf_hi,
                    LO=spec.wbuf_lo, has_stag=grid.has_stag,
-                   has_hra=grid.has_hra, L=grid.L, NBG=grid.NBG)
+                   has_hra=grid.has_hra, L=grid.L, NBG=grid.NBG,
+                   pb_rules=grid.has_pb_rules)
 
 
 def closed_cfg(grid) -> TickCfg:
@@ -94,7 +98,8 @@ def closed_cfg(grid) -> TickCfg:
                    NR=grid.NR, R=grid.R, NC=grid.NC, HI=spec.wbuf_hi,
                    LO=spec.wbuf_lo, has_stag=grid.has_stag,
                    has_hra=grid.has_hra, C=grid.C, N=grid.N, K=grid.K,
-                   LQ=grid.LQ, CAP=spec.wbuf_cap, NBG=grid.NBG)
+                   LQ=grid.LQ, CAP=spec.wbuf_cap, NBG=grid.NBG,
+                   pb_rules=grid.has_pb_rules)
 
 
 # ------------------------------------------------------------------ consts
@@ -105,7 +110,9 @@ def _j32(x):
 def _shared_consts(grid) -> dict:
     """Per-cell constant planes common to both modes (all [G] int32/bool
     except the staggered refresh phases, [G, R] and [G, U], and the
-    shared scalar horizon); the tCCD_L term only with bank groups."""
+    shared scalar horizon); the tCCD_L term only with bank groups, the
+    per-bank issue rules' spacing and round flag only where some cell
+    has one."""
     return dict(
         phase=_j32(grid.phase), rank_phase=_j32(grid.rank_phase),
         kind=_j32(grid.kind), level_ab=jnp.asarray(grid.level_ab),
@@ -117,7 +124,10 @@ def _shared_consts(grid) -> dict:
         MISS=_j32(grid.MISS), WR=_j32(grid.WR), TURN=_j32(grid.TURN),
         RTR=_j32(grid.RTR), SARP_PEN=_j32(grid.SARP_PEN),
         horizon=jnp.int32(grid.horizon),
-        **({"CCDL": _j32(grid.CCDL)} if grid.NBG > 1 else {}))
+        **({"CCDL": _j32(grid.CCDL)} if grid.NBG > 1 else {}),
+        **({"PB2PB": _j32(grid.PB2PB),
+            "pb_rounds": jnp.asarray(grid.pb_rounds)}
+           if grid.has_pb_rules else {}))
 
 
 def open_consts(grid) -> dict:
@@ -218,6 +228,10 @@ def open_state0(cfg: TickCfg, cst: dict) -> dict:
         last_bg=jnp.full((G, cfg.NC), -1, jnp.int32),
         ab_pending=jnp.zeros((G, cfg.R), jnp.int32),
         rank_drain=jnp.zeros((G, cfg.R), bool),
+        # per rank: the tick the tpbR2pbR spacing lets a pb start again
+        # (only grids with a per-bank issue rule carry it)
+        **({"pb_next": jnp.zeros((G, cfg.R), jnp.int32)}
+           if cfg.pb_rules else {}),
         next_arrive=jnp.where(live, qa0, _PAD_ARRIVE),
         next_w=jnp.where(live, qw0, False),
         h_arr=qa0,
@@ -277,6 +291,10 @@ def closed_state0(cfg: TickCfg, cst: dict) -> dict:
         last_bg=jnp.full((G, cfg.NC), -1, jnp.int32),
         ab_pending=jnp.zeros((G, cfg.R), jnp.int32),
         rank_drain=jnp.zeros((G, cfg.R), bool),
+        # per rank: the tick the tpbR2pbR spacing lets a pb start again
+        # (only grids with a per-bank issue rule carry it)
+        **({"pb_next": jnp.zeros((G, cfg.R), jnp.int32)}
+           if cfg.pb_rules else {}),
         # stats
         reads=jnp.zeros(G, jnp.int32),
         writes=jnp.zeros(G, jnp.int32),
@@ -299,6 +317,21 @@ def open_cond(cst: dict, s: dict):
 
 def closed_cond(cst: dict, s: dict):
     return (s["t"] < cst["horizon"]) & (s["remaining"].sum() > 0)
+
+
+# ------------------------------------------------------ per-bank issue rules
+def refpb_ready(cfg: TickCfg, cst: dict, s: dict, t, issued, ready_u):
+    """The refresh units' readiness for the policies, ``[G, U]``: with a
+    per-bank issue rule in the grid (static at trace time — other grids
+    trace none of it), narrowed to the units the tpbR2pbR spacing and
+    the refresh rounds let start at `t`."""
+    if not cfg.pb_rules:
+        return ready_u
+    with jax.named_scope("refpb_rules"):
+        return ready_u & refpb_rules_ready(
+            t=t, issued=issued, pb_next=s["pb_next"],
+            spacing=cst["PB2PB"], rounds=cst["pb_rounds"],
+            n_ranks_total=cfg.R)
 
 
 # ------------------------------------------------------- open-loop body
@@ -370,7 +403,8 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         demand = n_arrived - n_served
         picks, rr = select_batch(
             jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
-            ready=per_unit(ready, "all", R, cfg.NBG),
+            ready=refpb_ready(cfg, cst, s, t, issued,
+                              per_unit(ready, "all", R, cfg.NBG)),
             idle=per_unit(idle, "all", R, cfg.NBG),
             demand=per_unit(demand, "sum", R, cfg.NBG), write_window=drain,
             budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
@@ -412,6 +446,9 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         rank_drain = jnp.where(start_ab_r, ab_pending > 0, rank_drain)
         refab = s["refab"] + start_ab_r.sum(axis=1)
 
+        if cfg.pb_rules:
+            with jax.named_scope("refpb_rules"):
+                picks = refpb_rules_first(jnp, picks, cst["PB2PB"], R)
         new_sub = ctr % S
         start = jnp.maximum(t, bank_free)
         if cfg.has_hra:
@@ -422,8 +459,12 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
                               start)
         # a same-bank set starts once every bank of it can (per_unit and
         # per_bank are the identity without bank groups)
-        start = per_bank(jnp, per_unit(start, "max", R, cfg.NBG), R,
-                         cfg.NBG)
+        start_u = per_unit(start, "max", R, cfg.NBG)
+        if cfg.pb_rules:
+            with jax.named_scope("refpb_rules"):
+                pb_next = refpb_rules_next(jnp, picks, start_u,
+                                           s["pb_next"], cst["PB2PB"], R)
+        start = per_bank(jnp, start_u, R, cfg.NBG)
         picks_b = per_bank(jnp, picks, R, cfg.NBG)
         mark = (jnp.repeat(picks_b, S, axis=1)
                 & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
@@ -524,6 +565,7 @@ def open_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         n_served=n_served, rr=rr, ab_rr=ab_rr, wpend=wpend,
         drain=drain, last_op=last_op, last_bg=last_bg,
         ab_pending=ab_pending, rank_drain=rank_drain,
+        **({"pb_next": pb_next} if cfg.pb_rules else {}),
         next_arrive=sub["next_arrive"], next_w=sub["next_w"],
         h_arr=h_arr_s, h_row=h_row_s, h_sub=h_sub_s, h_w=h_w_s,
         reads=reads, writes=writes,
@@ -627,7 +669,8 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         demand = q_tail - s["q_head"]
         picks, rr = select_batch(
             jnp, kind=jnp.where(active, kind, KIND_IDEAL), lag=lag,
-            ready=per_unit(ready, "all", R, cfg.NBG),
+            ready=refpb_ready(cfg, cst, s, t, issued,
+                              per_unit(ready, "all", R, cfg.NBG)),
             idle=per_unit(idle, "all", R, cfg.NBG),
             demand=per_unit(demand, "sum", R, cfg.NBG), write_window=drain,
             budget=budget, wrp=wrp, urgent_at=urgent_at, rr=s["rr"],
@@ -669,6 +712,9 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         rank_drain = jnp.where(start_ab_r, ab_pending > 0, rank_drain)
         refab = s["refab"] + start_ab_r.sum(axis=1)
 
+        if cfg.pb_rules:
+            with jax.named_scope("refpb_rules"):
+                picks = refpb_rules_first(jnp, picks, cst["PB2PB"], R)
         new_sub = ctr % S
         start = jnp.maximum(t, bank_free)
         if cfg.has_hra:
@@ -679,8 +725,12 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
                               start)
         # a same-bank set starts once every bank of it can (per_unit and
         # per_bank are the identity without bank groups)
-        start = per_bank(jnp, per_unit(start, "max", R, cfg.NBG), R,
-                         cfg.NBG)
+        start_u = per_unit(start, "max", R, cfg.NBG)
+        if cfg.pb_rules:
+            with jax.named_scope("refpb_rules"):
+                pb_next = refpb_rules_next(jnp, picks, start_u,
+                                           s["pb_next"], cst["PB2PB"], R)
+        start = per_bank(jnp, start_u, R, cfg.NBG)
         picks_b = per_bank(jnp, picks, R, cfg.NBG)
         mark = (jnp.repeat(picks_b, S, axis=1)
                 & jnp.where(sarp_c, jnp.repeat(new_sub, S, axis=1)
@@ -786,6 +836,7 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         rr=rr, ab_rr=ab_rr, wpend=wpend, drain=drain, last_op=last_op,
         last_bg=last_bg,
         ab_pending=ab_pending, rank_drain=rank_drain,
+        **({"pb_next": pb_next} if cfg.pb_rules else {}),
         reads=reads, writes=writes,
         hits=hits_s, misses=misses_s,
         refpb=refpb, refab=refab,

@@ -121,6 +121,49 @@ def per_bank(xp, u, n_ranks_total: int, n_bank_groups: int):
         (G, n_ranks_total, n_bank_groups, K)).reshape(G, U * n_bank_groups)
 
 
+def refpb_rules_ready(*, t, issued, pb_next, spacing, rounds,
+                      n_ranks_total: int):
+    """``[G, U]``: the refresh units that the per-bank issue rules let
+    start at tick `t` (docs/tick-contract.md section 3), over the units
+    of each rank (`per_unit`'s layout):
+
+      * spacing (where ``spacing[g] > 0``): ``t >= pb_next[g, rank]``,
+        the tick the rank's last start plus the spacing reaches;
+      * rounds (where ``rounds[g]``): the unit has had no more refreshes
+        than any other unit of its rank.
+
+    issued [G, U]; pb_next [G, R]; spacing [G] int; rounds [G] bool."""
+    G, U = issued.shape
+    R = n_ranks_total
+    iss = issued.reshape(G, R, U // R)
+    in_round = ((iss == iss.min(axis=2, keepdims=True))
+                | ~rounds[:, None, None])
+    spaced = (t >= pb_next) | (spacing == 0)[:, None]
+    return (in_round & spaced[:, :, None]).reshape(G, U)
+
+
+def refpb_rules_first(xp, picks, spacing, n_ranks_total: int):
+    """`picks` ``[G, U]`` with, on each rank of a cell whose spacing is
+    on, only the lowest picked unit: the first start closes the rank to
+    the others this tick, which are not issued."""
+    G, U = picks.shape
+    p = picks.reshape(G, n_ranks_total, U // n_ranks_total)
+    first = p & (xp.cumsum(p, axis=2) == 1)
+    return xp.where((spacing > 0)[:, None, None], first, p).reshape(G, U)
+
+
+def refpb_rules_next(xp, picks, start_u, pb_next, spacing,
+                     n_ranks_total: int):
+    """``pb_next`` [G, R] after this tick's starts: a rank that started
+    unit u at tick ``start_u[g, u]`` opens again ``spacing[g]`` ticks
+    later (`picks` holds at most one start a rank where spacing is on)."""
+    G, U = picks.shape
+    R = n_ranks_total
+    p = picks.reshape(G, R, U // R)
+    last = xp.where(p, start_u.reshape(G, R, U // R), 0).max(axis=2)
+    return xp.where(p.any(axis=2), last + spacing[:, None], pb_next)
+
+
 def _pick_one(xp, cand, key, allow):
     """One pick per row: the candidate with the largest key (ties -> lowest
     bank). Rows where `allow` is False or no candidate exists pick nothing."""

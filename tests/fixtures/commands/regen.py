@@ -7,6 +7,12 @@
 validates clean and round-trips bit-identically; each `bad_*.json` is
 the same trace with ONE planted sequencing violation, named after the
 rule it must fire (see tests/test_commands.py::test_golden_fixture).
+
+The per-bank refresh issue rules' fixtures (`bad_refpb_*.json`) start
+from a captured darp run on an LPDDR4-like part that keeps neither rule,
+which validates clean; each states one rule the run did not keep:
+
+    PYTHONPATH=src python tests/fixtures/commands/regen.py --refpb
 """
 import json
 import os
@@ -79,6 +85,53 @@ def mutate(trace: CmdTrace, rule: str) -> CmdTrace:
     return clone(trace, cmds)
 
 
+def refpb_base_trace() -> CmdTrace:
+    """A darp run on ticks of 5 ns without the per-bank issue rules, on 2
+    ranks of 8 banks, with tREFI cut to an eighth of LPDDR4's so that a
+    short run refreshes a bank twice."""
+    from repro.core.refresh.timing import DramTiming
+    T = DramTiming(density_gb=32, n_ranks=2, n_subarrays=4, tRCD=18.0,
+                   tRP=18.0, tCL=17.5, tBL=5.0, tWR=18.0, tWTR=10.0,
+                   tREFI=488.0, tRFC_ab=380.0, tRFC_pb=190.0)
+    wl = make_workload(n_cores=2, reqs_per_core=96, seed=3)
+    return DramSim(T, wl, "darp").run_ticks(dt_ns=5.0,
+                                           record_commands=True).commands
+
+
+def refpb_mutate(trace: CmdTrace, rule: str) -> CmdTrace:
+    meta = dict(trace.meta)
+    if rule == "refpb-spacing":
+        # a tpbR2pbR spacing one tick above the closest two refreshes of
+        # a rank
+        last, gaps = {}, []
+        for c in trace.cmds:
+            if c.op == "REF_PB":
+                if (c.ch, c.rank) in last:
+                    gaps.append(c.tick - last[(c.ch, c.rank)])
+                last[(c.ch, c.rank)] = c.tick
+        meta["PB2PB"] = min(gaps) + 1
+    elif rule == "refpb-round":
+        # the out-of-order run held to refresh rounds
+        meta["REFPB_ROUNDS"] = True
+    else:
+        raise ValueError(rule)
+    return CmdTrace(meta=meta, cmds=list(trace.cmds), demand=None)
+
+
+def refpb_main():
+    trace = refpb_base_trace()
+    vio = validate_trace(trace)
+    assert vio == [], vio
+    for rule in ("refpb-spacing", "refpb-round"):
+        bad = refpb_mutate(trace, rule)
+        fired = validate_trace(bad)
+        assert fired and fired[0].rule == rule, (rule, fired[:3])
+        name = "bad_" + rule.replace("-", "_") + ".json"
+        with open(os.path.join(HERE, name), "w") as f:
+            json.dump(bad.to_json(), f, indent=1, sort_keys=True)
+        print(f"{name}: fires {rule} ({len(fired)} violation(s))")
+
+
 def main():
     trace = base_trace()
     vio = validate_trace(trace)
@@ -98,4 +151,4 @@ def main():
 
 
 if __name__ == "__main__":
-    main()
+    refpb_main() if "--refpb" in sys.argv[1:] else main()

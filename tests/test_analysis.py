@@ -201,6 +201,23 @@ def test_pallas_lint_catches_dropped_state_plane(tmp_path):
     assert "PL505" in rules_of(root, ["pallas-lint"])
 
 
+def test_pallas_lint_reads_planes_only_some_grids_carry():
+    # a plane only some grids carry (`pb_next`, where a DRAM states a
+    # per-bank issue rule) rides in a dict-literal `**` splat: PL505
+    # still sees it, and fires when a body drops it
+    import ast
+
+    from repro.analysis.passes.pallas_lint import check_state_keysets
+    src = (REPO_ROOT / "src/repro/core/sweep/jaxbody.py").read_text()
+    assert check_state_keysets(ast.parse(src), "jaxbody.py") == []
+    splat = '**({"pb_next": pb_next} if cfg.pb_rules else {}),'
+    assert src.count(splat) == 2          # the open and closed bodies
+    fired = check_state_keysets(ast.parse(src.replace(splat, "", 1)),
+                                "jaxbody.py")
+    assert [f.rule for f in fired] == ["PL505"]
+    assert "'pb_next'" in fired[0].message
+
+
 def test_registry_catches_sarp_policy_skipping_subarray_matrix(tmp_path):
     # RC406's reason to exist: a new SARP-trait registration (lambda
     # keyword spelling) that never reaches the subarray matrix

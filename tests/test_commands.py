@@ -26,11 +26,13 @@ try:
 except ImportError:  # deterministic fallback; see _hypothesis_shim
     from _hypothesis_shim import given, settings, strategies as st
 
-from repro.core.commands import (MNEMONICS, TIMING_FIELDS, CmdTrace,
+from conftest import lpddr4
+
+from repro.core.commands import (MNEMONICS, TIMING_FIELDS, Cmd, CmdTrace,
                                  round_trip, traces_equal, validate_trace)
-from repro.core.commands.trace import _key
+from repro.core.commands.trace import REFPB_RULE_FIELDS, _key, tick_meta
 from repro.core.commands.validator import RULES
-from repro.core.policy import list_policies
+from repro.core.policy import list_policies, resolve_policy
 from repro.core.refresh import DramSim, make_closed_workload
 from repro.core.refresh.scenarios import list_closed_scenarios
 from repro.core.refresh.timing import timing_for_density
@@ -301,3 +303,72 @@ def test_batched_sweep_ref_sb_emission_matches_run_ticks():
         tr = res.commands_for(p, "closed_multirank", 32)
         assert validate_trace(tr) == [], p
         assert traces_equal(tr, _grouped_run(p)[1].commands), p
+
+
+# ------------------------------- per-bank refresh issue rules (LPDDR4)
+
+def _lp_trace(starts, **rules):
+    """A hand-built LPDDR4 trace (2 ranks, darp) of per-bank refreshes,
+    one at each ``(decision tick, rank, bank)`` of `starts`: PRE, then
+    REF_PB tRP later."""
+    T = lpddr4(32, n_ranks=2, tREFI=976.0, **rules)
+    meta = tick_meta(T, resolve_policy("darp"), 5.0)
+    cmds = []
+    for t, rank, bank in starts:
+        cmds.append(Cmd(t, "PRE", 0, rank, bank, -1, -1, -1))
+        cmds.append(Cmd(t + meta["TRP"], "REF_PB", 0, rank, bank, -1, -1,
+                        t))
+    return CmdTrace(meta=meta, cmds=sorted(cmds, key=_key))
+
+
+def test_refpb_rules_ride_in_the_meta():
+    assert set(REFPB_RULE_FIELDS) == {"PB2PB", "REFPB_ROUNDS"}
+    meta = _lp_trace([]).meta
+    assert (meta["PB2PB"], meta["REFPB_ROUNDS"]) == (18, True)
+    assert not set(REFPB_RULE_FIELDS) & set(_run(record=True)
+                                            .commands.meta)
+
+
+def test_planted_refpb_too_soon_fires_refpb_spacing():
+    # rank 0: banks 0 and 1 five ticks apart; rank 1 on its own clock
+    fired = validate_trace(_lp_trace([(0, 0, 0), (5, 0, 1), (5, 1, 0)]))
+    assert [v.rule for v in fired] == ["refpb-spacing"]
+    assert fired[0].addr == "ch0.r0.b1"
+    assert validate_trace(_lp_trace([(0, 0, 0), (18, 0, 1)])) == []
+    # without the spacing the same trace is clean
+    assert validate_trace(_lp_trace([(0, 0, 0), (5, 0, 1)],
+                                    tpbR2pbR=0.0)) == []
+
+
+def test_planted_refpb_out_of_round_fires_refpb_round():
+    # bank 0 of rank 0 again before banks 1-7 of it have had one
+    fired = validate_trace(_lp_trace([(0, 0, 0), (60, 0, 0)]))
+    assert [v.rule for v in fired] == ["refpb-round"]
+    assert fired[0].addr == "ch0.r0.b0"
+    rounds = [(18 * k, 0, k) for k in range(8)] + [(144, 0, 0)]
+    assert validate_trace(_lp_trace(rounds)) == []
+    assert validate_trace(_lp_trace([(0, 0, 0), (60, 0, 0)],
+                                    refpb_rounds=False)) == []
+
+
+@pytest.mark.parametrize("policy", ("ref_pb", "darp", "dsarp", "hira",
+                                    "elastic", "ref_ab"))
+def test_recorded_lpddr4_trace_validates_and_replays(policy):
+    """The batched backend's recorded LPDDR4 traces under both rules are
+    JEDEC-clean, the run_ticks trace command for command, and replay
+    bit-identically."""
+    T = lpddr4(32, n_ranks=2, n_channels=2, n_subarrays=4, tREFI=976.0)
+    spec = SweepSpec(policies=(policy,), scenarios=("closed_multirank",),
+                     densities=(32,), reqs=400, seed=3, n_ranks=2,
+                     n_channels=2, n_subarrays=4, mode="closed",
+                     dt_ns=5.0, timing={32: T})
+    tr = sweep(spec, "batched", record_commands=True).commands_for(
+        policy, "closed_multirank", 32)
+    assert tr.meta["PB2PB"] == 18 and tr.meta["REFPB_ROUNDS"] is True
+    assert validate_trace(tr) == [], policy
+    assert (tr.counts()["REF_PB"] > 0) == (policy != "ref_ab")
+    wl = make_closed_workload("closed_multirank", 400, 3)
+    res = DramSim(T, wl, policy).run_ticks(dt_ns=5.0, record_commands=True)
+    assert traces_equal(tr, res.commands)
+    replayed, bit_identical = round_trip(res.commands)
+    assert bit_identical and replayed.makespan == res.makespan

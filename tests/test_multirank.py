@@ -9,6 +9,7 @@ harness lives in tests/test_conformance.py.
 """
 import numpy as np
 import pytest
+from conftest import lpddr4
 
 from repro.core.policy import (ALL_BANKS, Decision, MaintenanceView,
                                get_policy, list_policies, resolve_policy)
@@ -397,3 +398,130 @@ def test_event_mode_refuses_bank_groups():
     with pytest.raises(ValueError, match="bank groups"):
         DramSim(_grouped(2), wl, "darp").run()
     assert DramSim(_grouped(2), wl, "darp").run_ticks().reads_done > 0
+
+
+# ------------------------- LPDDR4: addressed per-bank refresh's issue rules
+#: the per-bank issue rules, (tpbR2pbR ns, refresh rounds): both, the
+#: spacing alone, the rounds alone
+LP_RULES = [(90.0, True), (90.0, False), (0.0, True)]
+#: every registered per-bank-level policy
+PB_POLICIES = tuple(p for p in list_policies()
+                    if not resolve_policy(p).ideal
+                    and resolve_policy(p).level == "pb")
+
+
+def _lp(tpbR2pbR=90.0, refpb_rounds=True, density=DENSITY, **layout):
+    """LPDDR4 with the issue rules, tREFI cut to a quarter so that a
+    short run refreshes every bank."""
+    return lpddr4(density, tREFI=976.0, tpbR2pbR=tpbR2pbR,
+                  refpb_rounds=refpb_rounds, **layout)
+
+
+@pytest.mark.parametrize("rules", LP_RULES, ids=["both", "spacing",
+                                                 "rounds"])
+@pytest.mark.parametrize("n_ranks,n_channels", [(2, 1), (2, 2)])
+def test_lpddr4_rules_all_backends_bit_identical_to_run_ticks(
+        rules, n_ranks, n_channels):
+    """The tpbR2pbR spacing and refresh rounds: scalar, batched and jax
+    stay bit-identical to `DramSim.run_ticks` for every registered
+    policy."""
+    T = _lp(*rules, n_ranks=n_ranks, n_channels=n_channels)
+    policies = tuple(list_policies())
+    spec = SweepSpec(policies=policies, scenarios=("closed_multirank",),
+                     densities=(DENSITY,), reqs=240, seed=SEED,
+                     mode="closed", n_ranks=n_ranks, n_channels=n_channels,
+                     dt_ns=5.0, timing={DENSITY: T})
+    batched = sweep(spec, "batched")
+    _cells_equal(sweep(spec, "scalar"), batched, f"scalar {rules}")
+    _cells_equal(sweep(spec, "jax"), batched, f"jax {rules}")
+    wl = make_closed_workload("closed_multirank", 240, SEED)
+    for p in policies:
+        cell = batched.get(p, "closed_multirank", DENSITY)
+        assert cell.finished, (p, rules)
+        _assert_cell_equals_sim(cell, DramSim(T, wl, p).run_ticks(dt_ns=5.0))
+
+
+def _pb_starts(T, policy, reqs=400):
+    """Each per-bank refresh start of a `run_ticks` run on `T`, in start
+    order, as (tick, global rank, bank)."""
+    wl = make_closed_workload("closed_multirank", reqs, SEED)
+    tl = DramSim(T, wl, policy).run_ticks(dt_ns=5.0,
+                                          record_timeline=True).timeline
+    return sorted({(s0, b // T.n_banks, b) for b, _, s0, _, kind
+                   in tl["refresh"] if kind == "pb"})
+
+
+@pytest.mark.parametrize("policy", PB_POLICIES)
+def test_lpddr4_spacing_binds(policy):
+    """No two per-bank refreshes of a rank start closer than tpbR2pbR (18
+    ticks of 5 ns); without the rule some do."""
+    lay = dict(n_ranks=2, n_channels=2, n_subarrays=4)
+    gaps = {}
+    for sp in (90.0, 0.0):
+        last, out = {}, []
+        for t, gr, _ in _pb_starts(_lp(sp, False, **lay), policy):
+            if gr in last:
+                out.append(t - last[gr])
+            last[gr] = t
+        gaps[sp] = out
+    assert gaps[90.0] and min(gaps[90.0]) >= 18, policy
+    assert min(gaps[0.0]) < 18, policy
+
+
+@pytest.mark.parametrize("policy", PB_POLICIES)
+def test_lpddr4_rounds_bind(policy):
+    """With refresh rounds, no bank of a rank starts a refresh while
+    another bank of that rank has had fewer."""
+    T = _lp(0.0, True, n_ranks=2, n_channels=2, n_subarrays=4)
+    count = [0] * T.n_banks_total
+    starts = _pb_starts(T, policy)
+    assert starts, policy
+    for _, gr, b in starts:
+        rank = count[gr * T.n_banks:(gr + 1) * T.n_banks]
+        assert count[b] == min(rank), (policy, b, rank)
+        count[b] += 1
+
+
+def test_lpddr4_lag_stays_in_budget_under_every_pb_policy():
+    """Under both rules every built-in per-bank policy keeps |lag| within
+    the budget of 8, at every density, over a run of some 15 tREFI in
+    which in-order REFpb, which the spacing holds to one start every 18
+    ticks where a rank's units fall due 6 apart, reaches the budget
+    edge."""
+    T = {d: _lp(density=d, n_ranks=2, n_channels=2) for d in (8, 16, 32)}
+    spec = SweepSpec(policies=PB_POLICIES, scenarios=("closed_multirank",
+                                                      "closed_write_heavy"),
+                     densities=(8, 16, 32), reqs=3000, seed=SEED,
+                     mode="closed", n_ranks=2, n_channels=2, dt_ns=5.0,
+                     timing=T)
+    res = sweep(spec, "batched")
+    for cell in res:
+        assert cell.finished, cell.policy
+        assert cell.max_abs_lag <= 8, (cell.policy, cell.max_abs_lag)
+        assert cell.refreshes_pb > 0, cell.policy
+    assert max(c.max_abs_lag for c in res if c.policy == "ref_pb") >= 6
+
+
+def test_lpddr4_timing_rules_validate():
+    T = _lp()
+    assert T.has_refpb_rules and not timing_for_density(32).has_refpb_rules
+    with pytest.raises(ValueError, match="tpbR2pbR"):
+        _lp(tpbR2pbR=-1.0)
+    with pytest.raises(TypeError, match="refpb_rounds"):
+        _lp(refpb_rounds=1)
+    # 8 banks a rank, 90 ns apart, cannot all refresh within 600 ns
+    with pytest.raises(ValueError, match="exceeds tREFI"):
+        lpddr4(32, tREFI=600.0)
+    from repro.core.sweep import TickTiming
+    tk = TickTiming.from_timing(T, 5.0)
+    assert (tk.PB2PB, tk.PB_ROUNDS) == (18, True)
+    # rounded up: a spacing is a floor the commands keep
+    assert TickTiming.from_timing(_lp(91.0), 5.0).PB2PB == 19
+    assert TickTiming.from_timing(timing_for_density(32)).PB2PB == 0
+
+
+def test_event_mode_refuses_refpb_rules():
+    wl = make_closed_workload("closed_mixed", 40, SEED)
+    with pytest.raises(ValueError, match="issue rules"):
+        DramSim(_lp(), wl, "darp").run()
+    assert DramSim(_lp(), wl, "darp").run_ticks(dt_ns=5.0).reads_done > 0

@@ -24,7 +24,9 @@ import random
 from pathlib import Path
 
 import pytest
+from conftest import lpddr4
 
+from repro.core.commands import validate_trace
 from repro.core.refresh import DramSim, make_closed_workload
 from repro.core.refresh.timing import timing_for_density
 from repro.core.sweep import CellResult, SweepSpec, sweep
@@ -46,6 +48,11 @@ HIERARCHIES = ((1, 1, 1, 1), (2, 1, 1, 1), (1, 2, 1, 1), (1, 1, 4, 1),
                (2, 2, 4, 1), (2, 1, 1, 2))
 #: open-mode n_ranks draws
 OPEN_RANKS = (1, 2)
+#: LPDDR4 (n_ranks, n_channels, n_subarrays) draws, and its per-bank
+#: issue rules (tpbR2pbR ns, refresh rounds): both, the spacing alone,
+#: the rounds alone
+LP_LAYOUTS = ((2, 1, 1), (2, 2, 4), (1, 2, 8))
+LP_RULES = ((90.0, True), (90.0, False), (0.0, True))
 
 
 def _cells_equal(a, b, ctx=""):
@@ -148,6 +155,46 @@ def test_fuzz_open_jax_equals_batched(case):
                      seed=rng.randrange(2 ** 31), reqs=40)
 
 
+@pytest.mark.parametrize("case", range(N_CASES))
+def test_fuzz_lpddr4_rules_all_backends_agree(case):
+    """Random LPDDR4 sweep points under the per-bank issue rules: closed,
+    jax == batched == `DramSim.run_ticks` (stats and command traces, which
+    validate clean); open, jax == batched == scalar."""
+    rng = random.Random(10_000 + case)
+    n_ranks, n_channels, S = LP_LAYOUTS[case % len(LP_LAYOUTS)]
+    sp, rounds = LP_RULES[(case + case // 3) % len(LP_RULES)]
+    policy = rng.choice(POLICIES)
+    density = rng.choice(DENSITIES)
+    seed, reqs = rng.randrange(2 ** 31), rng.choice((40, 64))
+    # tREFI cut to a quarter, so that a short run refreshes every bank
+    T = lpddr4(density, n_ranks=n_ranks, n_channels=n_channels,
+               n_subarrays=S, tREFI=976.0, tpbR2pbR=sp, refpb_rounds=rounds)
+    lay = dict(densities=(density,), reqs=reqs, seed=seed, dt_ns=5.0,
+               n_ranks=n_ranks, n_channels=n_channels, n_subarrays=S,
+               timing={density: T})
+    scenario = rng.choice(CLOSED_SCENARIOS)
+    spec = SweepSpec(policies=(policy, "ideal"), scenarios=(scenario,),
+                     mode="closed", **lay)
+    dev = sweep(spec, "jax")
+    batched = sweep(spec, "batched", record_commands=True)
+    _cells_equal(dev, batched, f"lpddr4 jax/batched {policy}/{scenario}")
+    wl = make_closed_workload(scenario, reqs, seed)
+    for p in (policy, "ideal"):
+        cell = dev.get(p, scenario, density)
+        assert cell.finished and cell.max_abs_lag <= T.refresh_budget, p
+        sim = DramSim(T, wl, p).run_ticks(dt_ns=5.0, record_commands=True)
+        _assert_cell_equals_sim(cell, sim)
+        tr = batched.commands_for(p, scenario, density)
+        assert tr.cmds == sim.commands.cmds, (p, scenario, density, case)
+        assert validate_trace(tr) == [], (p, scenario, density, case)
+    scenario = rng.choice(OPEN_SCENARIOS)
+    spec = SweepSpec(policies=(policy, "ideal"), scenarios=(scenario,),
+                     **lay)
+    batched = sweep(spec, "batched")
+    _cells_equal(sweep(spec, "jax"), batched, f"lpddr4 open {policy}")
+    _cells_equal(sweep(spec, "scalar"), batched, f"lpddr4 open {policy}")
+
+
 # -------------------------------------------------------- golden replays
 def _fixture_cases():
     return sorted(FIXTURES.glob("*.json"))
@@ -159,13 +206,17 @@ def test_golden_fixture_cases_stay_bit_identical(path):
     """Replay the pinned edge cases (a single-cell grid, a density-mixed
     open grid, the full closed hierarchy)."""
     case = json.loads(path.read_text())
+    lay = {k: case.get(k, 1) for k in ("n_ranks", "n_channels",
+                                        "n_subarrays")}
+    # an LPDDR4 case states the values it changes from `conftest.lpddr4`
+    dram = ({} if "lpddr4" not in case else dict(
+        dt_ns=5.0, timing={d: lpddr4(d, **lay, **case["lpddr4"])
+                           for d in case["densities"]}))
     spec = SweepSpec(policies=tuple(case["policies"]),
                      scenarios=tuple(case["scenarios"]),
                      densities=tuple(case["densities"]),
                      reqs=case["reqs"], seed=case["seed"],
-                     mode=case["mode"], n_ranks=case.get("n_ranks", 1),
-                     n_channels=case.get("n_channels", 1),
-                     n_subarrays=case.get("n_subarrays", 1))
+                     mode=case["mode"], **lay, **dram)
     closed = case["mode"] == "closed"
     batched = sweep(spec, "batched", record_commands=closed)
     if closed:

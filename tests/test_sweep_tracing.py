@@ -11,6 +11,7 @@ import re
 
 import jax
 import pytest
+from conftest import lpddr4
 
 from repro.core.refresh.timing import timing_for_density
 from repro.core.sweep import SweepSpec, jaxbody, sweep
@@ -34,13 +35,24 @@ SPECS = {
         densities=(32,), reqs=160, seed=3, mode="closed", n_ranks=2,
         n_bank_groups=4, timing={32: timing_for_density(
             32, n_ranks=2, n_bank_groups=4, tCCD_L=9.0, tCCD_S=6.0)}),
+    # 2 channels x 2 ranks of LPDDR4 with the per-bank issue rules
+    "lpddr4": SweepSpec(
+        policies=("ref_pb", "darp"), scenarios=("closed_multirank",),
+        densities=(32,), reqs=160, seed=3, mode="closed", dt_ns=5.0,
+        n_ranks=2, n_channels=2,
+        timing={32: lpddr4(32, n_ranks=2, n_channels=2)}),
 }
 #: refresh units and bank groups each spec's cells have
-UNITS = {"closed": (8, 1), "open": (8, 1), "groups": (4, 4)}
+UNITS = {"closed": (8, 1), "open": (8, 1), "groups": (4, 4),
+         "lpddr4": (32, 1)}
 #: the stream lanes each spec's loop picks a core's next request over:
 #: the closed specs' N (160 requests over 4 and 8 cores); open mode has
 #: no streams
-PICK_LANES = {"closed": 40, "open": 0, "groups": 20}
+PICK_LANES = {"closed": 40, "open": 0, "groups": 20, "lpddr4": 20}
+#: the per-bank issue rules: tpbR2pbR in ticks (90 ns of 5 ns ticks) and
+#: refresh rounds (1) where the spec's DRAM states them
+REFPB_RULES = {"closed": (0, 0), "open": (0, 0), "groups": (0, 0),
+               "lpddr4": (18, 1)}
 
 
 def _final_t(spec) -> int:
@@ -86,12 +98,15 @@ def test_finalize_carries_cells_and_loop_iterations(traced):
     assert all(not stats[n] for n in SPANS[:-1])
     counters = stats["sweep.finalize"]
     assert set(counters) == {"cells", "loop_iterations", "refresh_units",
-                             "bank_groups", "stream_pick_lanes"}
+                             "bank_groups", "stream_pick_lanes",
+                             "refpb_spacing_ticks", "refpb_rounds"}
     assert counters["cells"] == len(res.cells) == len(SPECS[name].cells())
     assert counters["loop_iterations"] == _final_t(SPECS[name])
     assert (counters["refresh_units"],
             counters["bank_groups"]) == UNITS[name]
     assert counters["stream_pick_lanes"] == PICK_LANES[name]
+    assert (counters["refpb_spacing_ticks"],
+            counters["refpb_rounds"]) == REFPB_RULES[name]
     if SPECS[name].mode == "closed":
         assert PICK_LANES[name] == _Grid(SPECS[name]).N
     ticks = [round(c.makespan / SPECS[name].dt_ns) for c in res.cells]

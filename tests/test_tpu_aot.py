@@ -5,7 +5,9 @@ The TPU compiler is installed with JAX and compiles for a described
 program or kernel that the chip's compiler refuses. Nothing runs. They
 cover the sweep's device path (the `jax` backend's while loop at the
 paper's closed grid and the open-loop grid that `chip_smoke.py` runs,
-and at a DDR5 layout with bank groups and same-bank refresh).
+at a DDR5 layout with bank groups and same-bank refresh, and at an
+LPDDR4 layout whose per-bank refreshes keep tpbR2pbR and refresh
+rounds).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and this
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import lpddr4
 from jax.sharding import SingleDeviceSharding
 
 from chip_smoke import CLOSED_GRID, OPEN_GRID
@@ -35,7 +38,15 @@ GROUPS_GRID = SweepSpec(
         density_gb=32, tRCD=16.25, tRP=16.25, tCL=16.67, tBL=10 / 3, tWR=30.0,
         tWTR=10.0, tCCD_L=5.0, tCCD_S=10 / 3, tREFI=1950.0,
         tRFC_ab=220.0, tRFC_pb=190.0, **_DDR5)})
-GRIDS = {"closed": CLOSED_GRID, "open": OPEN_GRID, "groups": GROUPS_GRID}
+#: 4 x16 channels x 2 ranks x 8 banks of LPDDR4-3200, ticks of 5 ns
+_LPDDR4 = dict(n_channels=4, n_ranks=2)
+LPDDR4_GRID = SweepSpec(
+    policies=("ref_ab", "ref_pb", "darp", "sarp_pb", "dsarp", "ideal"),
+    scenarios=("closed_multirank",), densities=(32,), reqs=320, seed=1,
+    mode="closed", dt_ns=5.0, **_LPDDR4,
+    timing={32: lpddr4(32, **_LPDDR4)})
+GRIDS = {"closed": CLOSED_GRID, "open": OPEN_GRID, "groups": GROUPS_GRID,
+         "lpddr4": LPDDR4_GRID}
 
 
 @pytest.fixture(scope="module")
@@ -66,7 +77,7 @@ def _on(sharding, tree):
         np.shape(a), jnp.asarray(a).dtype, sharding=sharding), tree)
 
 
-@pytest.mark.parametrize("mode", ["closed", "open", "groups"])
+@pytest.mark.parametrize("mode", ["closed", "open", "groups", "lpddr4"])
 def test_jax_backend_loop_compiles_for_v5e(one_chip, mode):
     """The program `sweep(..., backend="jax")` runs, at the grid
     `chip_smoke.py` runs it on."""
