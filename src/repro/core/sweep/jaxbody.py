@@ -181,6 +181,34 @@ def pick(plane, idx):
     return jnp.where(hot, plane, 0).sum(axis=-1, dtype=plane.dtype)
 
 
+def put(plane, idx, val, when):
+    """`pick`'s dual: `plane` with ``plane[..., idx] = val`` wherever
+    `when` holds, as a one-hot compare and a select along the last axis.
+    On a TPU this is a dense pass over `plane`, where a scatter serialises
+    its indices. It equals ``plane.at[..., idx].set(where(when, val,
+    plane[..., idx]))`` bit for bit; where `when` is False, or `idx` is
+    out of ``[0, plane.shape[-1])``, the row is left as it was."""
+    hot = ((lax.broadcasted_iota(jnp.int32, plane.shape, plane.ndim - 1)
+            == idx[..., None]) & when[..., None])
+    return jnp.where(hot, val[..., None], plane)
+
+
+def park(comp_t, core, done, when):
+    """The closed body's MLP windows `comp_t` [G, C, K] with `done` [G]
+    parked, where `when`, in the first free slot (`_PAD_ARRIVE`) of core
+    ``core[g]``'s window; in slot 0 where that window has none, as
+    ``argmax`` over an all-false row gives. By one-hot selects over the
+    C and K lanes, it equals the gather of ``comp_t[g, core]`` and the
+    scatter at ``[g, core, k]`` that it replaced, bit for bit."""
+    G, C, _ = comp_t.shape
+    at_core = lax.broadcasted_iota(jnp.int32, (G, C), 1) == core[:, None]
+    free_k = jnp.argmax(((comp_t == _PAD_ARRIVE)
+                         & at_core[:, :, None]).any(axis=1), axis=1)
+    return put(comp_t, jnp.broadcast_to(free_k[:, None], (G, C)),
+               jnp.broadcast_to(done[:, None], (G, C)),
+               when[:, None] & at_core)
+
+
 def next_requests(cfg: TickCfg, cst: dict, next_idx):
     """Each core's request at its stream position ``next_idx`` [G, C]
     (clamped to the stream's last entry), unpacked from the [G*C, N]
@@ -755,7 +783,7 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
             return pick(q.reshape(G, B, LQ), hslot)
 
         h_row, h_sub, h_arr, hc = head(qr), head(qs_), head(qa), head(qc)
-        h_core, h_w = hc >> 1, (hc & 1) == 1
+        h_w = (hc & 1) == 1
         has_req = (demand > 0) & active[:, None]
         ru3 = ref_until_s.reshape(G, B, S)
         head_ru = pick(ru3, h_sub)
@@ -775,38 +803,35 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
         lat_sum, hist = s["lat_sum"], s["hist"]
         last_done = s["last_done"]
         for ch in range(NC):
+            # the channel's winner `bs` is read and written by one-hot
+            # selects over the lanes (`pick`, `put`), not by gathers and
+            # scatters, which the chip serialises index by index
             sc_ch = score[:, ch * RBC:(ch + 1) * RBC]
             bs = jnp.argmax(sc_ch, axis=1) + ch * RBC
-            ok = score[arG, bs] >= 0
-            row, sub_ = h_row[arG, bs], h_sub[arG, bs]
-            arr, isw = h_arr[arG, bs], h_w[arG, bs]
-            core = h_core[arG, bs]
-            hit = row == head_or[arG, bs]
+            ok = sc_ch.max(axis=1) >= 0        # the winner's own score
+            row, sub_ = pick(h_row, bs), pick(h_sub, bs)
+            arr, hcb = pick(h_arr, bs), pick(hc, bs)
+            core, isw = hcb >> 1, (hcb & 1) == 1
+            hit = row == pick(head_or, bs)
             gr_b = bs // NB
             bg_b = gr_b if cfg.NBG == 1 else bs // cfg.BPG
             lbg = last_bg[:, ch]
             lr = lbg if cfg.NBG == 1 else lbg // cfg.NBG
             lat = (jnp.where(hit, HIT, MISS)
-                   + jnp.where(sarp & bank_mid[arG, bs],
-                               SARP_PEN, 0)
+                   + jnp.where(sarp & pick(bank_mid, bs), SARP_PEN, 0)
                    + jnp.where(isw != last_op[:, ch], TURN, 0)
                    + jnp.where((lr >= 0) & (lr != gr_b), RTR, 0))
             if cfg.NBG > 1:
                 # a start in its channel's last bank group: tCCD_L
                 lat = lat + jnp.where(lbg == bg_b, cst["CCDL"], 0)
             done = t + lat
-            bank_free = bank_free.at[arG, bs].set(
-                jnp.where(ok, done + jnp.where(isw, WR, 0),
-                          bank_free[arG, bs]))
+            bank_free = put(bank_free, bs, done + jnp.where(isw, WR, 0), ok)
             last_op = last_op.at[:, ch].set(
                 jnp.where(ok, isw, last_op[:, ch]))
             last_bg = last_bg.at[:, ch].set(jnp.where(ok, bg_b, lbg))
-            gsub = bs * S + sub_
-            open_row_s = open_row_s.at[arG, gsub].set(
-                jnp.where(ok, row, open_row_s[arG, gsub]))
-            open_sub = open_sub.at[arG, bs].set(
-                jnp.where(ok, sub_, open_sub[arG, bs]))
-            q_head = q_head.at[arG, bs].add(ok)
+            open_row_s = put(open_row_s, bs * S + sub_, row, ok)
+            open_sub = put(open_sub, bs, sub_, ok)
+            q_head = q_head + ((arB[None, :] == bs[:, None]) & ok[:, None])
             served_w = ok & isw
             wpend = wpend - served_w
             drain = drain & ~(served_w & (wpend <= LO))
@@ -820,10 +845,9 @@ def closed_body(cfg: TickCfg, cst: dict, scores, s: dict) -> dict:
             misses_s = misses_s + (ok & ~hit)
             last_done = jnp.where(ok, jnp.maximum(last_done, done),
                                   last_done)
-            # reads: park the data return in the core's MLP window slot
-            free_k = jnp.argmax(comp_t[arG, core] == _PAD_ARRIVE, axis=1)
-            comp_t = comp_t.at[arG, core, free_k].set(
-                jnp.where(rmask, done, comp_t[arG, core, free_k]))
+            # reads: park the data return in the core's MLP window (the
+            # next channel sees this channel's parked read)
+            comp_t = park(comp_t, core, done, rmask)
 
     return dict(
         t=t + 1, qa=qa, qr=qr, qs=qs_, qc=qc,

@@ -5,15 +5,18 @@ store (-1, 0, `_PAD_ARRIVE`), on bool planes, at every axis length a
 ring queue or a bank's subarrays can have, and on a plane broadcast over
 the middle axis (the closed front end's bank tails), eager and jitted.
 And each core's next request from the packed stream record
-(`jaxbody.next_requests`) against the five gathers it replaced."""
+(`jaxbody.next_requests`) against the five gathers it replaced. And
+`jaxbody.put`, the one-hot write the closed serve phase updates each
+channel's winning bank with, and `jaxbody.park`, its MLP-window write,
+against the scatters they replaced."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.sweep.engine import _PAD_ARRIVE
-from repro.core.sweep.jaxbody import (TickCfg, next_requests, pick,
-                                      stream_record)
+from repro.core.sweep.jaxbody import (TickCfg, next_requests, park, pick,
+                                      put, stream_record)
 
 LENGTHS = (2, 8, 128, 512)
 G, B = 3, 5
@@ -152,3 +155,101 @@ def test_next_requests_equal_the_five_stream_gathers(n):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
     assert "gather" not in str(jax.make_jaxpr(
         lambda c, i: next_requests(cfg, c, i))(cst, next_idx))
+
+
+# ---------------------------------------------------- closed serve writes
+WHENS = ("all", "none", "mixed")
+
+
+def _when(rng, kind, shape):
+    """A `when` mask: all True, all False, or mixed with both present."""
+    if kind != "mixed":
+        return np.full(shape, kind == "all")
+    when = rng.random(shape) < 0.5
+    when.flat[0], when.flat[-1] = True, False
+    return when
+
+
+@pytest.mark.parametrize("when", WHENS)
+@pytest.mark.parametrize("op", ["set_int32", "set_bool", "add_int32"])
+@pytest.mark.parametrize("n", LENGTHS)
+def test_put_equals_the_scatter_it_replaced(n, op, when):
+    """`put` over a [G, B, n] plane writes lane ``idx`` of each row where
+    `when` holds, as ``.at[g, b, idx].set(where(when, val, old))`` did
+    (int32 holding the bodies' padding values, and bool); and a `put`
+    of the picked lane plus an increment is the scatter-add
+    ``.at[g, b, idx].add(where(when, inc, 0))`` (the serve's `q_head`),
+    eager and jitted."""
+    rng = np.random.default_rng(2000 + n)
+    dtype = jnp.bool_ if op == "set_bool" else jnp.int32
+    plane, idx = _plane(rng, n, dtype), _indices(rng, n)
+    val = _plane(rng, 1, dtype)[..., 0]
+    w = _when(rng, when, (G, B))
+    gi, bi = np.meshgrid(np.arange(G), np.arange(B), indexing="ij")
+    ref = jnp.asarray(plane)
+    if op == "add_int32":
+        inc = rng.integers(-3, 4, (G, B)).astype(np.int32)
+        want = ref.at[gi, bi, idx].add(np.where(w, inc, 0))
+
+        def f(p, i, v, m):
+            return put(p, i, pick(p, i) + v, m)
+
+        args = (plane, idx, inc, w)
+    else:
+        want = ref.at[gi, bi, idx].set(
+            jnp.where(w, val, ref[gi, bi, idx]))
+        f, args = put, (plane, idx, val, w)
+    for got in (f(*map(jnp.asarray, args)), jax.jit(f)(*args)):
+        assert got.dtype == want.dtype and got.shape == plane.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if when == "none":
+        np.testing.assert_array_equal(np.asarray(want), plane)
+
+
+def _windows(rng, c, k):
+    """[G, c, k] MLP windows: a mix of free slots (`_PAD_ARRIVE`) and
+    parked completion ticks; core 0 of cell 0 has no free slot, and core
+    1 of cell 1 only its last."""
+    comp = np.where(rng.random((G, c, k)) < 0.5, int(_PAD_ARRIVE),
+                    rng.integers(0, 1 << 20, (G, c, k))).astype(np.int32)
+    comp[0, 0, :] = rng.integers(0, 1 << 20, k)
+    comp[1, 1, :] = 7
+    comp[1, 1, -1] = _PAD_ARRIVE
+    return comp
+
+
+@pytest.mark.parametrize("when", WHENS)
+@pytest.mark.parametrize("c,k", [(2, 1), (4, 4), (8, 8), (4, 16)])
+def test_park_equals_the_window_gather_and_scatter(c, k, when):
+    """`park` writes each cell's completion tick into the first free slot
+    of its core's MLP window where `when` holds, and into slot 0 where
+    the window is full, as the gather ``comp_t[g, core]`` and the
+    scatter at ``[g, core, free_k]`` did; twice in a row (two channels
+    serving reads of one core in one tick), the second finds the slot
+    after the first's."""
+    rng = np.random.default_rng(3000 + c * k)
+    comp = _windows(rng, c, k)
+    core = rng.integers(0, c, G).astype(np.int32)
+    core[:2] = 0, 1                    # no free slot; only the last
+    done = rng.integers(1, 1 << 20, G).astype(np.int32)
+    w = _when(rng, when, (G,))
+    gi = np.arange(G)
+
+    def scattered(ct):
+        free_k = jnp.argmax(ct[gi, core] == _PAD_ARRIVE, axis=1)
+        return ct.at[gi, core, free_k].set(
+            jnp.where(w, done, ct[gi, core, free_k]))
+
+    want = scattered(jnp.asarray(comp))
+    want2 = scattered(want)
+    jitted = jax.jit(park)
+    for f in (park, jitted):
+        got = f(jnp.asarray(comp), jnp.asarray(core), jnp.asarray(done),
+                jnp.asarray(w))
+        assert got.dtype == want.dtype and got.shape == comp.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        got2 = f(got, jnp.asarray(core), jnp.asarray(done), jnp.asarray(w))
+        np.testing.assert_array_equal(np.asarray(got2), np.asarray(want2))
+    if w[0]:
+        # the full window's slot 0 was overwritten, as the gather path did
+        assert int(np.asarray(want)[0, 0, 0]) == done[0]

@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from chip_smoke import CLOSED_GRID, OPEN_GRID
 from repro.core.refresh.timing import DramTiming
 from repro.core.sweep import SweepSpec, jaxbody
-from repro.core.sweep.engine import _Grid, _jax_arbiter
+from repro.core.sweep.engine import MAX_LAT_TICKS, _Grid, _jax_arbiter
 
 #: 2 subchannels x 2 ranks x 8 bank groups x 4 banks, DDR5-4800-like
 #: timing on ticks of tBL = 10/3 ns
@@ -114,3 +114,14 @@ def test_jax_backend_loop_compiles_for_v5e(one_chip, mode):
         front_gathers = [ln for ln in hlo.splitlines()
                          if " gather(" in ln and "/tick.front_end/" in ln]
         assert not front_gathers, front_gathers[:3]
+        # each channel's winner is read and written by one-hot lane
+        # selects (`jaxbody.pick`, `jaxbody.put`): the serve phase's only
+        # scatter is each channel's latency-histogram scatter-add
+        serve = [ln for ln in hlo.splitlines() if "/tick.serve/" in ln]
+        serve_gathers = [ln for ln in serve if " gather(" in ln]
+        assert not serve_gathers, serve_gathers[:3]
+        scatters = [ln for ln in serve if " scatter(" in ln]
+        hist = f"= s32[{cst['kind'].shape[0]},{MAX_LAT_TICKS + 1}]"
+        assert len(scatters) == cfg.NC, scatters[:3]
+        assert all(hist in ln and "scatter-add" in ln
+                   for ln in scatters), scatters[:3]
